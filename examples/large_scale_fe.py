@@ -42,11 +42,6 @@ def main():
     args = ap.parse_args()
 
     import jax
-
-    # some TPU plugins override JAX_PLATFORMS at import time; an explicit
-    # CPU request must win (same workaround as tests/conftest.py)
-    if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
     from photon_ml_tpu.evaluation.evaluators import area_under_roc_curve

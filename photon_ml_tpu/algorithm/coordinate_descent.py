@@ -72,9 +72,7 @@ SCORE_PLANES = ("device", "host")
 def _plane_programs():
     """Jitted score-plane algebra, cached per process. ``apply`` donates the
     running total so each incremental update writes in place instead of
-    copying a row-length buffer (CPU ignores donation and warns, so it is
-    only requested on accelerators)."""
-    donate = () if jax.default_backend() == "cpu" else (0,)
+    copying a row-length buffer."""
 
     def _apply(total, new_own, old_own):
         note_jit_trace("cd_plane", "apply")  # fires only on (re)trace
@@ -84,7 +82,7 @@ def _plane_programs():
         note_jit_trace("cd_plane", "residual")
         return total - own
 
-    apply_ = jax.jit(_apply, donate_argnums=donate)
+    apply_ = jax.jit(_apply, donate_argnums=(0,))
     residual_ = jax.jit(_residual)
     return apply_, residual_
 

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
+from photon_ml_tpu.algorithm.coordinate import Coordinate
 from photon_ml_tpu.data.random_effect import RandomEffectDataset, ReBucket
 from photon_ml_tpu.estimators.random_effect import train_random_effects
 from photon_ml_tpu.losses.objective import make_glm_objective
@@ -204,10 +205,10 @@ def _latent_dataset(
 
 
 @dataclasses.dataclass
-class FactoredRandomEffectCoordinate:
+class FactoredRandomEffectCoordinate(Coordinate):
     """Alternating MF-style coordinate (reference
-    FactoredRandomEffectCoordinate.scala:40). Implements the Coordinate
-    protocol (update_model / score) used by CoordinateDescent."""
+    FactoredRandomEffectCoordinate.scala:40). It has no device-plane path:
+    CoordinateDescent reaches it through Coordinate's host round trips."""
 
     dataset: RandomEffectDataset       # INDEX_MAP/IDENTITY projected blocks
     task: TaskType

@@ -716,46 +716,22 @@ def _serve_stream(
 ) -> Optional[dict]:
     snapshot: Optional[dict] = None
     if args.data_dirs:
-        from photon_ml_tpu.io.data_reader import (
-            FeatureShardConfiguration,
-            read_game_data,
-        )
         from photon_ml_tpu.serving import GameScorer, replay_requests
         from photon_ml_tpu.serving.replay import (
             max_nnz_of,
+            read_request_data,
             requests_from_game_data,
         )
 
-        shard_bags = {}
-        for sid, s in (
-            (artifact.configurations.get("feature_shards") or {}).items()
-        ):
-            shard_bags[sid] = FeatureShardConfiguration(
-                feature_bags=s["feature_bags"],
-                add_intercept=bool(s.get("add_intercept", True)),
-            )
-        for sid in artifact.shard_dims():
-            shard_bags.setdefault(
-                sid, FeatureShardConfiguration(feature_bags=[sid])
-            )
-        index_maps = dict(artifact.feature_index) or None
-        if index_maps is None:
+        if not artifact.feature_index:
             logger.warning(
                 "artifact carries no feature index maps; indexes will be "
                 "rebuilt from the request data and may not match the model"
             )
-        col_names = parse_input_columns(args.input_columns_names)
         with timer.time("read data"):
-            data, _, uids = read_game_data(
-                args.data_dirs,
-                {
-                    sid: cfg for sid, cfg in shard_bags.items()
-                    if sid in artifact.shard_dims()
-                },
-                index_maps,
-                id_tags=artifact.random_effect_types(),
-                is_response_required=False,
-                **col_names,
+            data, uids = read_request_data(
+                artifact, args.data_dirs,
+                **parse_input_columns(args.input_columns_names),
             )
         with timer.time("build requests"):
             requests = requests_from_game_data(
@@ -970,6 +946,9 @@ def main(argv: Optional[List[str]] = None) -> int:
             file=sys.stderr,
         )
         return 2
+    from photon_ml_tpu.utils.cachedir import enable_compilation_cache
+
+    enable_compilation_cache()
     run(args)
     return 0
 

@@ -246,7 +246,11 @@ def _run_update(args, logger, timer, emitter, t_start) -> dict:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    run(parse_args(argv))
+    from photon_ml_tpu.utils.cachedir import enable_compilation_cache
+
+    args = parse_args(argv)
+    enable_compilation_cache()
+    run(args)
     return 0
 
 

@@ -131,16 +131,17 @@ class GameData:
 
         engine:
         - "ell"   — padded row-sparse gather/scatter layout (XLA).
-        - "benes" — permutation-routed engine (ops/sparse_perm.py): vector-
-          speed matvec/rmatvec on TPU, with a one-time host routing cost.
+        - "benes" — permutation-routed engine (ops/sparse_perm.py): dense
+          vector work instead of gather/scatter on TPU, with a one-time host
+          routing cost.
         - "fused" — same routing executed as fused Pallas kernels
-          (ops/fused_perm.py): ~3x less HBM traffic per linear map on TPU
-          by byte accounting. Opt-in until an on-hardware A/B records a
-          win (bench.py --engine fused / dev-scripts/tpu_validate_fused.py);
-          "auto" only prefers measured engines.
-        - "auto"  — "benes" on a TPU backend with a shard large enough for
-          the routing prep to pay for itself (measured 26.2M example-
-          passes/s vs ELL's 2.2M in round 2); "ell" everywhere else.
+          (ops/fused_perm.py): fewer HBM passes per linear map.
+        - "auto"  — chosen from what can be observed: "fused" on a TPU
+          backend for a shard of at least 2^20 nonzeros (the host routing
+          prep is meant to be repaid above that; no benchmark cell sits on
+          either side of the threshold yet), "ell" everywhere else. On a
+          TPU a fused kernel that fails to compile is an error; nothing
+          falls back to another engine.
         """
         if engine not in ("auto", "ell", "benes", "fused"):
             raise ValueError(
@@ -154,17 +155,9 @@ class GameData:
         if engine == "auto":
             import jax
 
-            on_accel = jax.default_backend() != "cpu"
-            if on_accel and shard.rows.size >= (1 << 20):
-                # the measured on-hardware winner (TPU_MEASUREMENTS.json /
-                # dev-scripts/tpu_validate_fused.py: fused ~2x benes at the
-                # headline workload); the probe degrades to stage-by-stage
-                # if the fused kernels fail to lower on this backend
-                from photon_ml_tpu.ops.fused_perm import fused_engine_works
-
-                engine = "fused" if fused_engine_works() else "benes"
-            else:
-                engine = "ell"
+            on_tpu = jax.default_backend() == "tpu"
+            big = shard.rows.size >= (1 << 20)
+            engine = "fused" if on_tpu and big else "ell"
         key = (shard_name, engine)
         if key not in cache:
             if engine in ("benes", "fused"):

@@ -91,12 +91,7 @@ def _next_pow2(n: int) -> int:
 
 def _is_multi_device(x) -> bool:
     sharding = getattr(x, "sharding", None)
-    if sharding is None:
-        return False
-    try:
-        return len(sharding.device_set) > 1
-    except Exception:  # noqa: BLE001 - sharding APIs vary across jax versions
-        return False
+    return sharding is not None and len(sharding.device_set) > 1
 
 
 class _RePrograms(NamedTuple):
@@ -181,15 +176,13 @@ def _re_programs(
         return jax.tree.map(lambda a: a[idx], tree)
 
     # Donate the carried solver state so each round updates in place instead
-    # of copying the (w, memory, history) buffers; CPU ignores donation (and
-    # warns), so only request it on accelerators.
-    donate = () if jax.default_backend() == "cpu" else (0,)
+    # of copying the (w, memory, history) buffers.
     return _RePrograms(
         kind=kind,
         chunk_iters=K,
         oneshot=jax.jit(_oneshot),
         init=jax.jit(_init),
-        chunk=jax.jit(_chunk, donate_argnums=donate),
+        chunk=jax.jit(_chunk, donate_argnums=(0,)),
         extract=jax.jit(_extract),
         compact=jax.jit(_compact),
     )

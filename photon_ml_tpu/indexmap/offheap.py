@@ -35,7 +35,6 @@ from photon_ml_tpu.indexmap import IndexMap
 
 _NATIVE_DIR = pathlib.Path(__file__).resolve().parent.parent / "native"
 _SRC = _NATIVE_DIR / "indexstore.cpp"
-_LIB = _NATIVE_DIR / "_indexstore.so"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
@@ -52,42 +51,41 @@ _EMPTY = np.uint64(0xFFFFFFFFFFFFFFFF)
 
 
 def _load_native() -> Optional[ctypes.CDLL]:
-    """Compile (once) and load the native store; None if unavailable."""
+    """Compile (once) and load the native store; None on a host without
+    g++ (a compiler that fails on the committed source raises)."""
     global _lib, _lib_failed
     with _lock:
         if _lib is not None or _lib_failed:
             return _lib
-        try:
-            from photon_ml_tpu.utils.nativelib import build_and_load
+        from photon_ml_tpu.utils.nativelib import build_and_load
 
-            lib = build_and_load(_SRC, _LIB)
-            if lib is None:
-                raise RuntimeError("native index store unavailable")
-            lib.phix_build.restype = ctypes.c_int
-            lib.phix_build.argtypes = [
-                ctypes.c_char_p, ctypes.c_char_p, ctypes.c_void_p,
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64,
-            ]
-            lib.phix_open.restype = ctypes.c_void_p
-            lib.phix_open.argtypes = [ctypes.c_char_p]
-            lib.phix_get.restype = ctypes.c_int64
-            lib.phix_get.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32]
-            lib.phix_get_batch.restype = None
-            lib.phix_get_batch.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_void_p,
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64,
-            ]
-            lib.phix_name_at.restype = ctypes.c_int64
-            lib.phix_name_at.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint32, ctypes.c_char_p, ctypes.c_uint32,
-            ]
-            lib.phix_num_entries.restype = ctypes.c_uint64
-            lib.phix_num_entries.argtypes = [ctypes.c_void_p]
-            lib.phix_close.restype = None
-            lib.phix_close.argtypes = [ctypes.c_void_p]
-            _lib = lib
-        except Exception:
+        lib = build_and_load(_SRC)
+        if lib is None:
             _lib_failed = True
+            return None
+        lib.phix_build.restype = ctypes.c_int
+        lib.phix_build.argtypes = [
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64,
+        ]
+        lib.phix_open.restype = ctypes.c_void_p
+        lib.phix_open.argtypes = [ctypes.c_char_p]
+        lib.phix_get.restype = ctypes.c_int64
+        lib.phix_get.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32]
+        lib.phix_get_batch.restype = None
+        lib.phix_get_batch.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64,
+        ]
+        lib.phix_name_at.restype = ctypes.c_int64
+        lib.phix_name_at.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint32, ctypes.c_char_p, ctypes.c_uint32,
+        ]
+        lib.phix_num_entries.restype = ctypes.c_uint64
+        lib.phix_num_entries.argtypes = [ctypes.c_void_p]
+        lib.phix_close.restype = None
+        lib.phix_close.argtypes = [ctypes.c_void_p]
+        _lib = lib
         return _lib
 
 

@@ -32,7 +32,6 @@ logger = logging.getLogger("photon_ml_tpu")
 
 _NATIVE_DIR = Path(__file__).resolve().parent.parent / "native"
 _SRC = _NATIVE_DIR / "avrodecode.cpp"
-_LIB = _NATIVE_DIR / "_avrodecode.so"
 
 _lib = None
 _lib_tried = False
@@ -60,74 +59,69 @@ def _load_native():
     if _lib_tried:
         return _lib
     _lib_tried = True
-    try:
-        from photon_ml_tpu.utils.nativelib import build_and_load
+    from photon_ml_tpu.utils.nativelib import build_and_load
 
-        lib = build_and_load(_SRC, _LIB, ldflags=("-lz",))
-        if lib is None:
-            raise RuntimeError("native avro decoder unavailable")
-        u8p = ctypes.POINTER(ctypes.c_uint8)
-        i32p = ctypes.POINTER(_c_i32)
-        i64p = ctypes.POINTER(_c_i64)
-        lib.avro_decode.restype = _c_p
-        lib.avro_decode.argtypes = [
-            u8p, _c_i64, _c_i64, i32p, _c_i32, _c_i32, _c_i32, _c_i32,
+    lib = build_and_load(_SRC, ldflags=("-lz",))
+    if lib is None:
+        return None
+    u8p = ctypes.POINTER(ctypes.c_uint8)
+    i32p = ctypes.POINTER(_c_i32)
+    i64p = ctypes.POINTER(_c_i64)
+    lib.avro_decode.restype = _c_p
+    lib.avro_decode.argtypes = [
+        u8p, _c_i64, _c_i64, i32p, _c_i32, _c_i32, _c_i32, _c_i32,
+        u8p, i32p, _c_i32, _c_i32,
+    ]
+    try:
+        # one GIL-released inflate+decode call per file (see .cpp)
+        lib.avro_decode_packed.restype = _c_p
+        lib.avro_decode_packed.argtypes = [
+            u8p, _c_i64, i64p, i64p, i64p, _c_i32, _c_i32,
+            i32p, _c_i32, _c_i32, _c_i32, _c_i32,
             u8p, i32p, _c_i32, _c_i32,
         ]
-        try:
-            # one GIL-released inflate+decode call per file (see .cpp); a
-            # stale .so without the symbol degrades to the per-payload path
-            lib.avro_decode_packed.restype = _c_p
-            lib.avro_decode_packed.argtypes = [
-                u8p, _c_i64, i64p, i64p, i64p, _c_i32, _c_i32,
-                i32p, _c_i32, _c_i32, _c_i32, _c_i32,
-                u8p, i32p, _c_i32, _c_i32,
-            ]
-            lib.has_packed = True
-        except AttributeError:  # pragma: no cover - stale prebuilt .so
-            lib.has_packed = False
-        lib.res_n_rows.restype = _c_i64
-        lib.res_n_rows.argtypes = [_c_p]
-        lib.res_num_col.restype = ctypes.POINTER(ctypes.c_double)
-        lib.res_num_col.argtypes = [_c_p, _c_i32]
-        lib.res_num_present.restype = u8p
-        lib.res_num_present.argtypes = [_c_p, _c_i32]
-        lib.res_str_arena.restype = u8p
-        lib.res_str_arena.argtypes = [_c_p, i64p]
-        lib.res_str_off.restype = i64p
-        lib.res_str_off.argtypes = [_c_p, _c_i32]
-        lib.res_str_len.restype = i32p
-        lib.res_str_len.argtypes = [_c_p, _c_i32]
-        lib.res_bag_count.restype = _c_i64
-        lib.res_bag_count.argtypes = [_c_p, _c_i32]
-        lib.res_bag_rec.restype = i32p
-        lib.res_bag_rec.argtypes = [_c_p, _c_i32]
-        lib.res_bag_val.restype = ctypes.POINTER(ctypes.c_float)
-        lib.res_bag_val.argtypes = [_c_p, _c_i32]
-        lib.res_bag_key_off.restype = i64p
-        lib.res_bag_key_off.argtypes = [_c_p, _c_i32]
-        lib.res_bag_key_len.restype = i32p
-        lib.res_bag_key_len.argtypes = [_c_p, _c_i32]
-        lib.res_key_arena.restype = u8p
-        lib.res_key_arena.argtypes = [_c_p, i64p]
-        lib.res_free.restype = None
-        lib.res_free.argtypes = [_c_p]
-        lib.key_dedup.restype = _c_p
-        lib.key_dedup.argtypes = [u8p, i64p, i32p, _c_i64]
-        lib.dedup_n_unique.restype = _c_i64
-        lib.dedup_n_unique.argtypes = [_c_p]
-        lib.dedup_ids.restype = i32p
-        lib.dedup_ids.argtypes = [_c_p]
-        lib.dedup_u_off.restype = i64p
-        lib.dedup_u_off.argtypes = [_c_p]
-        lib.dedup_u_len.restype = i32p
-        lib.dedup_u_len.argtypes = [_c_p]
-        lib.dedup_free.restype = None
-        lib.dedup_free.argtypes = [_c_p]
-        _lib = lib
-    except Exception as e:  # pragma: no cover - toolchain-dependent
-        logger.info("avrodecode native build unavailable (%s)", e)
-        _lib = None
+        lib.has_packed = True
+    except AttributeError:  # pragma: no cover - stale prebuilt .so
+        lib.has_packed = False
+    lib.res_n_rows.restype = _c_i64
+    lib.res_n_rows.argtypes = [_c_p]
+    lib.res_num_col.restype = ctypes.POINTER(ctypes.c_double)
+    lib.res_num_col.argtypes = [_c_p, _c_i32]
+    lib.res_num_present.restype = u8p
+    lib.res_num_present.argtypes = [_c_p, _c_i32]
+    lib.res_str_arena.restype = u8p
+    lib.res_str_arena.argtypes = [_c_p, i64p]
+    lib.res_str_off.restype = i64p
+    lib.res_str_off.argtypes = [_c_p, _c_i32]
+    lib.res_str_len.restype = i32p
+    lib.res_str_len.argtypes = [_c_p, _c_i32]
+    lib.res_bag_count.restype = _c_i64
+    lib.res_bag_count.argtypes = [_c_p, _c_i32]
+    lib.res_bag_rec.restype = i32p
+    lib.res_bag_rec.argtypes = [_c_p, _c_i32]
+    lib.res_bag_val.restype = ctypes.POINTER(ctypes.c_float)
+    lib.res_bag_val.argtypes = [_c_p, _c_i32]
+    lib.res_bag_key_off.restype = i64p
+    lib.res_bag_key_off.argtypes = [_c_p, _c_i32]
+    lib.res_bag_key_len.restype = i32p
+    lib.res_bag_key_len.argtypes = [_c_p, _c_i32]
+    lib.res_key_arena.restype = u8p
+    lib.res_key_arena.argtypes = [_c_p, i64p]
+    lib.res_free.restype = None
+    lib.res_free.argtypes = [_c_p]
+    lib.key_dedup.restype = _c_p
+    lib.key_dedup.argtypes = [u8p, i64p, i32p, _c_i64]
+    lib.dedup_n_unique.restype = _c_i64
+    lib.dedup_n_unique.argtypes = [_c_p]
+    lib.dedup_ids.restype = i32p
+    lib.dedup_ids.argtypes = [_c_p]
+    lib.dedup_u_off.restype = i64p
+    lib.dedup_u_off.argtypes = [_c_p]
+    lib.dedup_u_len.restype = i32p
+    lib.dedup_u_len.argtypes = [_c_p]
+    lib.dedup_free.restype = None
+    lib.dedup_free.argtypes = [_c_p]
+    _lib = lib
     return _lib
 
 

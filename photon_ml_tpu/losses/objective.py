@@ -90,7 +90,7 @@ def make_glm_objective(
             and norm.is_identity
         ):
             # fused MXU kernel: one HBM pass over X for value + gradient
-            # (None => problem too large for the chip-local kernel; use XLA)
+            # (None => problem too large for one VMEM block; use XLA)
             from photon_ml_tpu.ops.pallas_kernels import fused_value_grad_auto
 
             fused = fused_value_grad_auto(

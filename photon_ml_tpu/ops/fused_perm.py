@@ -34,9 +34,13 @@ up to powers of two so slot groups tile the 128-lane axis evenly (group <=
 128) or span whole rows (group = 128q): both make the prologue/epilogue a
 dense in-kernel reshape/matmul instead of a gather.
 
-Off TPU the class runs an unfused XLA fallback (broadcast -> apply_plan ->
-reduce) with identical semantics; the Pallas kernels themselves are covered
-on CPU through the interpreter (tests set ``_INTERPRET``).
+The executor is chosen from the backend: on a TPU the Pallas kernels, always
+— one that Mosaic refuses is an error, not a reason to run something else.
+Elsewhere (the CPU tests) the class runs the same plan through plain XLA
+(broadcast -> apply_plan -> reduce) with identical semantics; the kernels
+themselves are covered on CPU through the interpreter (tests set
+``_INTERPRET``) and compiled for the TPU without a chip in
+``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -68,6 +72,10 @@ _MAX_BASE_BLOCK = 1024  # rows per base-kernel block (VMEM budget)
 # Mosaic failure at production shapes (a row/column with more than
 # LANES*LANES nonzeros after hot-column splitting). Guarded in ``assemble``.
 MAX_FUSED_GROUP = LANES * LANES
+
+# Smallest plan with a recursion level. The fused kernels fold that level's
+# relayouts, so every fused plan is padded to at least this many slots.
+MIN_FUSED_SIZE = LANES * LANES
 
 
 class FusedGroupTooLarge(ValueError):
@@ -510,8 +518,8 @@ def fused_execute(
 
 
 def unfused_execute(dplan: DevicePlan, pro, epi, payload_dtype=jnp.float32) -> jax.Array:
-    """Same semantics via plain XLA (stage-by-stage apply_plan): the CPU /
-    fallback path and the reference for the fused kernels (including the
+    """Same semantics via plain XLA (stage-by-stage apply_plan): the path off
+    the TPU and the reference for the fused kernels (including the
     payload-dtype entry rounding)."""
     S = dplan.size
     if isinstance(pro, Broadcast):
@@ -535,64 +543,6 @@ def unfused_execute(dplan: DevicePlan, pro, epi, payload_dtype=jnp.float32) -> j
 
 def _next_pow2(x: int) -> int:
     return 1 << max(int(x) - 1, 0).bit_length()
-
-
-def fused_engine_works() -> bool:
-    """One-time probe (cached per process): compile and run a tiny fused
-    matvec/rmatvec on the current backend and check it against dense math.
-    The estimator's "auto" engine choice consults this so a Mosaic lowering
-    regression degrades to the stage-by-stage engine instead of crashing."""
-    global _PROBE_RESULT
-    if _PROBE_RESULT is None:
-        _PROBE_RESULT = _run_probe()
-    return _PROBE_RESULT
-
-
-_PROBE_RESULT: Optional[bool] = None
-
-
-def _run_probe() -> bool:
-    if not pallas_available():
-        return False
-    try:
-        rng = np.random.default_rng(0)
-        n, d, nnz = 256, 200, 2000
-        rows = rng.integers(0, n, nnz)
-        cols = rng.integers(0, d, nnz)
-        vals = rng.standard_normal(nnz).astype(np.float32)
-        dense = np.zeros((n, d), np.float32)
-        np.add.at(dense, (rows, cols), vals)
-        feats = from_coo(
-            rows, cols, vals, (n, d), max_hot_cols=0,
-            size_floor=LANES * LANES, plan_cache="",
-        )
-        w = rng.standard_normal(d).astype(np.float32)
-        z = np.asarray(jax.jit(feats.matvec)(jnp.asarray(w)))
-        c = rng.standard_normal(n).astype(np.float32)
-        g = np.asarray(jax.jit(feats.rmatvec)(jnp.asarray(c)))
-        # tight tolerance on purpose: the kernels force Precision.HIGHEST,
-        # so anything beyond f32 accumulation noise (e.g. a lowering that
-        # silently drops to one-pass bf16 MXU matmuls, ~1e-3 error here but
-        # ~1e-2 at production scale) must fail the probe and fall back
-        ok = np.allclose(z, dense @ w, atol=3e-4) and np.allclose(
-            g, dense.T @ c, atol=3e-4
-        )
-        if not ok:
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "fused permutation engine probe produced wrong values; "
-                "falling back to the stage-by-stage engine"
-            )
-        return ok
-    except Exception as e:  # pragma: no cover - backend-specific lowering
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "fused permutation engine unavailable on this backend (%s); "
-            "falling back to the stage-by-stage engine", e
-        )
-        return False
 
 
 @struct.dataclass
@@ -638,8 +588,8 @@ class FusedBenesFeatures:
         return self.plan.size
 
     def _fused_ok(self) -> bool:
-        if not parse_plan(self.plan).descents:
-            return False  # plan too small to have a recursion level
+        """Pallas kernels on a TPU (and under the tests' interpreter hook);
+        plain XLA on any other backend."""
         return _INTERPRET or pallas_available()
 
     def _run(self, dplan, pro, epi) -> jax.Array:
@@ -756,6 +706,7 @@ def from_coo(
             "(drop the pins or the explicit layout)"
         )
     n, d = shape
+    size_floor = max(size_floor, MIN_FUSED_SIZE)
     rows, cols, vals, hot_matrix, hot_ids, row_counts, col_counts = (
         prepare_cold_entries(
             rows, cols, vals, shape, max_nnz_row, hot_col_threshold, max_hot_cols
@@ -830,6 +781,7 @@ def assemble(
     paddings — the fused twin of ``sparse_perm._assemble`` (the grid builder
     stacks identically-shaped tiles built through this)."""
     assert K & (K - 1) == 0 and KP & (KP - 1) == 0, "group sizes must be pow2"
+    size_floor = max(size_floor, MIN_FUSED_SIZE)
     for name, group in (("K", K), ("KP", KP)):
         if group > MAX_FUSED_GROUP:
             raise FusedGroupTooLarge(

@@ -2,14 +2,14 @@
 
 The reference's per-partition compute kernel (ValueAndGradientAggregator
 .scala:33: one pass accumulating Σ w·l(z,y) and Σ w·l′·x) maps to TPU as a
-fused MXU kernel: per row-block, z = X·w rides the MXU, the pointwise loss
-and its derivative ride the VPU, and gradᵀ += dzᵀ·X rides the MXU again —
-ONE pass over X in HBM instead of the two XLA makes for matvec + rmatvec.
+fused MXU kernel: z = X·w rides the MXU, the pointwise loss and its
+derivative ride the VPU, and gradᵀ = dzᵀ·X rides the MXU again — ONE pass
+over X in HBM instead of the two XLA makes for matvec + rmatvec.
 
-Dense row-blocks only (the TPU has no efficient arbitrary gather/scatter, so
-the ELL sparse path stays on XLA; per-entity random-effect blocks are dense
-by construction via index-map projection). Grid iterations on TPU execute
-sequentially, so the kernel accumulates into its output block across steps.
+Dense blocks only (the TPU has no efficient arbitrary gather/scatter, so the
+ELL sparse path stays on XLA; per-entity random-effect blocks are dense by
+construction via index-map projection). The whole problem is one VMEM block,
+so ``jax.vmap`` batches the kernel over entities.
 
 See /opt/skills/guides/pallas_guide.md for the programming model.
 """
@@ -21,17 +21,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
-
-ROW_BLOCK = 256
 LANE = 128
 
 
@@ -40,41 +31,6 @@ def _loss_terms(kind, z, y):
     value/d1 are pure elementwise jnp, valid inside a kernel) — one source
     of truth with the XLA objective."""
     return kind.value(z, y), kind.d1(z, y)
-
-
-def _kernel(kind: str, x_ref, y_ref, off_ref, wt_ref, w_ref,
-            val_ref, grad_ref, csum_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        val_ref[...] = jnp.zeros_like(val_ref)
-        grad_ref[...] = jnp.zeros_like(grad_ref)
-        csum_ref[...] = jnp.zeros_like(csum_ref)
-
-    x = x_ref[...]                       # [BN, D]
-    z = jax.lax.dot_general(
-        x, w_ref[...],                   # [BN, D] x [1, D]
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )[:, 0] + off_ref[:, 0]              # [BN]
-    y = y_ref[:, 0]
-    wt = wt_ref[:, 0]
-    l, d1 = _loss_terms(kind, z, y)
-    # weight-0 padding rows must be exact no-ops even when the unweighted
-    # term overflows (0 * inf -> NaN would poison the sums)
-    lw = jnp.where(wt > 0, wt * l, 0.0)
-    dz = jnp.where(wt > 0, wt * d1, 0.0)  # [BN]
-    # Mosaic forbids scalar stores to VMEM: accumulate (1,1)-shaped arrays
-    val_ref[...] += jnp.sum(lw)[None, None]
-    csum_ref[...] += jnp.sum(dz)[None, None]
-    grad_ref[...] += jax.lax.dot_general(
-        dz[None, :], x,                  # [1, BN] x [BN, D]
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
 
 
 def _pad_to(a: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -87,61 +43,11 @@ def _pad_to(a: jax.Array, axis: int, multiple: int) -> jax.Array:
     return jnp.pad(a, pad)
 
 
-@functools.partial(jax.jit, static_argnames=("kind", "interpret"))
-def fused_value_grad(
-    matrix: jax.Array,    # [n, d] dense features
-    labels: jax.Array,    # [n]
-    offsets: jax.Array,   # [n]
-    weights: jax.Array,   # [n]
-    w: jax.Array,         # [d]
-    kind=None,  # PointwiseLoss class (static); required
-    interpret: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-pass (Σ wᵢ·l, Σ wᵢ·l′·xᵢ, Σ wᵢ·l′) — loss sum, gradient, and the
-    coefficient sum the normalization shift path needs."""
-    if kind is None:
-        raise ValueError("kind (a PointwiseLoss class) is required")
-    n, d = matrix.shape
-    x = _pad_to(_pad_to(matrix, 0, ROW_BLOCK), 1, LANE)
-    np_, dp = x.shape
-    nb = np_ // ROW_BLOCK
-    # padding rows carry weight 0 (exact no-ops in every sum); vectors are
-    # [np_, 1] columns so the (ROW_BLOCK, 1) blocks satisfy Mosaic's tile
-    # rule (sublane divisible by 8, trailing dim equal to the array's)
-    col = lambda v: _pad_to(v.astype(jnp.float32), 0, ROW_BLOCK)[:, None]
-    yv, off, wt = col(labels), col(offsets), col(weights)
-    wv = _pad_to(w.astype(jnp.float32)[None, :], 1, LANE)
-
-    val, grad, csum = pl.pallas_call(
-        functools.partial(_kernel, kind),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((ROW_BLOCK, dp), lambda i: (i, 0)),
-            pl.BlockSpec((ROW_BLOCK, 1), lambda i: (i, 0)),
-            pl.BlockSpec((ROW_BLOCK, 1), lambda i: (i, 0)),
-            pl.BlockSpec((ROW_BLOCK, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, dp), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, yv, off, wt, wv)
-    return val[0, 0], grad[0, :d], csum[0, 0]
-
-
 def _single_kernel(kind: str, x_ref, y_ref, off_ref, wt_ref, w_ref,
                    val_ref, grad_ref, csum_ref):
-    """Grid-free variant: whole problem in one VMEM block. No cross-step
-    accumulation, so jax.vmap batches it cleanly (the batch axis becomes the
-    grid) — this is the per-entity random-effect inner-loop kernel."""
+    """Whole problem in one VMEM block, no grid: jax.vmap batches it cleanly
+    (the batch axis becomes the grid) — the per-entity random-effect
+    inner-loop kernel."""
     x = x_ref[...]                       # [S, D]
     z = jax.lax.dot_general(
         x, w_ref[...], (((1,), (1,)), ((), ())),
@@ -196,46 +102,34 @@ def fused_value_grad_single(
 
 
 # At most this many elements go through the single-block kernel (must fit
-# VMEM comfortably); larger dense problems use the blocked grid kernel.
+# VMEM comfortably); larger dense problems stay on XLA.
 SINGLE_BLOCK_MAX_ELEMENTS = 2_000_000
 
 
 def fused_value_grad_auto(matrix, labels, offsets, weights, w, kind):
-    """The objective's entry: ONLY the single-block (vmappable, chip-local)
-    variant auto-routes — large dense problems return None and the caller
-    stays on XLA, which GSPMD can partition (pallas_call has no partitioning
-    rule, so routing a mesh-sharded FE matrix here would replicate it).
-    Off-TPU (the 'force' debug mode) the interpreter runs the kernel."""
+    """The objective's entry: problems too large for one VMEM block return
+    None and the caller stays on XLA, which GSPMD can partition
+    (pallas_call has no partitioning rule, so routing a mesh-sharded FE
+    matrix here would replicate it)."""
     s, d = matrix.shape
     if s * d > SINGLE_BLOCK_MAX_ELEMENTS:
         return None
     return fused_value_grad_single(
-        matrix, labels, offsets, weights, w, kind=kind,
-        interpret=not pallas_available(),
+        matrix, labels, offsets, weights, w, kind=kind
     )
 
 
 def pallas_available() -> bool:
-    """True when a TPU backend can run the kernels natively."""
-    if not _HAS_PLTPU:
-        return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """True on a TPU backend: the only place the kernels run outside the
+    tests' interpret mode. A kernel Mosaic refuses there is an error, never
+    a reason to take another path."""
+    return jax.default_backend() == "tpu"
 
 
 @functools.cache
 def enabled() -> bool:
-    """Fused kernels are opt-in: PHOTON_ML_TPU_PALLAS=1 enables them (on a
-    TPU backend), =0/unset disables, and =force enables even off-TPU via
-    the pallas interpreter (slow; correctness drives only). The objective
-    checks this once at trace time."""
+    """The fused RE kernel is opt-in: PHOTON_ML_TPU_PALLAS=1 enables it on a
+    TPU backend. The objective checks this once at trace time."""
     import os
 
-    flag = os.environ.get("PHOTON_ML_TPU_PALLAS", "")
-    if flag == "1":
-        return pallas_available()
-    if flag == "force":
-        return _HAS_PLTPU
-    return False
+    return os.environ.get("PHOTON_ML_TPU_PALLAS", "") == "1" and pallas_available()

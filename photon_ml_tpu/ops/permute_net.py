@@ -7,10 +7,10 @@ GLM gather/scatter at vector speed instead of XLA's scalar ~10ns/element
 loop (the TPU replacement for the reference's per-partition sparse axpy,
 ValueAndGradientAggregator.scala:132-153).
 
-Execution modes:
+Execution modes, chosen from the backend:
 - TPU: Pallas kernels (one program launch amortized over the whole solve).
-- CPU/tests: XLA ``take_along_axis`` fallback — identical semantics, used
-  by the 8-virtual-device harness where Pallas TPU kernels can't run.
+- elsewhere (the tests' 8-virtual-device CPU harness): XLA
+  ``take_along_axis`` with identical semantics.
 """
 
 from __future__ import annotations
@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from photon_ml_tpu.ops.pallas_kernels import pallas_available
 from photon_ml_tpu.ops.routing import (
@@ -32,13 +33,6 @@ from photon_ml_tpu.ops.routing import (
     PermPlan,
     SublaneShuffle,
 )
-
-try:  # pragma: no cover - absent on CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
 
 
 @struct.dataclass
@@ -150,16 +144,13 @@ def _sublane_shuffle_xla(v: jax.Array, idx: jax.Array, rows: int) -> jax.Array:
     return jnp.take_along_axis(blk, sel, axis=1).reshape(m, LANES)
 
 
-def _use_pallas(m: int, rows: int | None = None) -> bool:
-    if not (_HAS_PLTPU and pallas_available()):
-        return False
-    if m < 32:
-        return False  # tiny plans: XLA handles them; int8 tiles need >=32 rows
-    if _row_block(m) % 32 != 0:
-        return False  # int8 index blocks must respect the (32, 128) tile
-    if rows is not None and _row_block(m) % rows != 0:
-        return False
-    return True
+def _use_pallas(m: int) -> bool:
+    """Pallas on a TPU for every plan of at least 32 rows. A smaller plan
+    (<= 1024 slots) is one sublane tile, too short for the (32, 128) tile an
+    int8 index block needs, and runs as an XLA gather. Every larger
+    ``routing.valid_size`` is c*128^k rows, so its row block is a multiple
+    of 32 and of every sublane group size."""
+    return pallas_available() and m >= 32
 
 
 def apply_plan(dplan: DevicePlan, x: jax.Array) -> jax.Array:
@@ -185,7 +176,7 @@ def apply_plan(dplan: DevicePlan, x: jax.Array) -> jax.Array:
             rows = kind[1]
             if rows == 1:
                 continue  # single-row groups: identity movement
-            if _use_pallas(v.shape[0], rows):
+            if _use_pallas(v.shape[0]):
                 v = _sublane_shuffle_pallas(v, idx, rows)
             else:
                 v = _sublane_shuffle_xla(v, idx, rows)

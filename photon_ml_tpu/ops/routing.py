@@ -26,22 +26,22 @@ reference ``host_apply`` used by tests. Device execution lives in
 from __future__ import annotations
 
 import ctypes
-import logging
-import subprocess
 from dataclasses import dataclass
 from pathlib import Path
 from typing import List, Optional, Sequence, Union
 
 import numpy as np
 
-logger = logging.getLogger(__name__)
-
 LANES = 128
 MAX_SUBLANES = 8  # hardware sublane-gather window (tpu.dynamic_gather dim 0)
 
 _NATIVE_DIR = Path(__file__).resolve().parent.parent / "native"
 _SRC = _NATIVE_DIR / "eulercolor.cpp"
-_LIB = _NATIVE_DIR / "_eulercolor.so"
+
+# The numpy colorer walks every pairing cycle in Python: fine for the plans
+# tests build on a host without g++, out of the question for a production
+# plan (16.7M slots per block of the 2^24-column tile).
+_NUMPY_COLOR_MAX_EDGES = 1 << 18
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
@@ -54,7 +54,7 @@ def _load_native():
     _lib_tried = True
     from photon_ml_tpu.utils.nativelib import build_and_load
 
-    lib = build_and_load(_SRC, _LIB)
+    lib = build_and_load(_SRC)
     if lib is not None:
         lib.euler_color.restype = ctypes.c_int
         lib.euler_color.argtypes = [
@@ -131,21 +131,30 @@ def euler_color(src: np.ndarray, dst: np.ndarray, deg: int, n_src: int,
     assert deg > 0 and (deg & (deg - 1)) == 0, "deg must be a power of two"
     assert n_edges == n_src * deg == n_dst * deg
     lib = _load_native()
-    if lib is not None:
-        color = np.zeros(n_edges, dtype=np.int32)
-        rc = lib.euler_color(
-            ctypes.c_int64(n_edges),
-            ctypes.c_int32(deg),
-            src.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-            dst.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-            ctypes.c_int32(n_src),
-            ctypes.c_int32(n_dst),
-            color.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+    if lib is None:
+        if n_edges > _NUMPY_COLOR_MAX_EDGES:
+            raise RuntimeError(
+                f"routing a plan of {n_edges} slots needs the native Euler "
+                "colorer (native/eulercolor.cpp), and this host has no g++ "
+                "to build it"
+            )
+        return _euler_color_numpy(src, dst, deg, n_src, n_dst)
+    color = np.zeros(n_edges, dtype=np.int32)
+    rc = lib.euler_color(
+        ctypes.c_int64(n_edges),
+        ctypes.c_int32(deg),
+        src.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        dst.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        ctypes.c_int32(n_src),
+        ctypes.c_int32(n_dst),
+        color.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+    )
+    if rc != 0:
+        raise RuntimeError(
+            f"native euler_color failed (rc={rc}) on a {deg}-regular graph "
+            f"of {n_edges} edges"
         )
-        if rc == 0:
-            return color
-        logger.warning("native euler_color rc=%d; numpy fallback", rc)
-    return _euler_color_numpy(src, dst, deg, n_src, n_dst)
+    return color
 
 
 # --------------------------------------------------------------------------

@@ -30,22 +30,12 @@ from photon_ml_tpu.ops.features import DenseFeatures, EllFeatures
 
 DATA_AXIS = "data"
 
-try:
-    from jax import shard_map as _shard_map_impl
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        """shard_map across jax versions (replication checking off: the
-        feature engines mix Pallas calls and psums the checker can't type)."""
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off: the feature engines
+    mix Pallas calls and psums the checker can't type."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def data_parallel_mesh(

@@ -24,6 +24,41 @@ from photon_ml_tpu.serving.scorer import GameScorer, ScoreRequest, ScoreResult
 from photon_ml_tpu.telemetry import span
 
 
+def read_request_data(
+    artifact: ServingArtifact, data_dirs: Sequence[str], **column_names
+):
+    """Read scoring data for replay against ``artifact``: its feature shards
+    (bags and intercepts as saved with the model, else one bag per shard
+    name), its feature index maps and its random-effect id tags. Returns
+    ``(GameData, uids)`` for :func:`requests_from_game_data`."""
+    from photon_ml_tpu.io.data_reader import (
+        FeatureShardConfiguration,
+        read_game_data,
+    )
+
+    saved = artifact.configurations.get("feature_shards") or {}
+    shards = {
+        sid: (
+            FeatureShardConfiguration(
+                feature_bags=saved[sid]["feature_bags"],
+                add_intercept=bool(saved[sid].get("add_intercept", True)),
+            )
+            if sid in saved
+            else FeatureShardConfiguration(feature_bags=[sid])
+        )
+        for sid in artifact.shard_dims()
+    }
+    data, _, uids = read_game_data(
+        data_dirs,
+        shards,
+        dict(artifact.feature_index) or None,
+        id_tags=artifact.random_effect_types(),
+        is_response_required=False,
+        **column_names,
+    )
+    return data, uids
+
+
 def requests_from_game_data(
     data: GameData,
     artifact: ServingArtifact,

@@ -534,8 +534,8 @@ register_knob(KnobSpec(
     ),
     candidates=("auto", "ell", "benes", "fused"),
     description=(
-        "Fixed-effect matvec engine. BENCH_LASTGOOD.json records a 19x "
-        "spread across engines on the same shard shape, so this is the "
-        "single highest-leverage train-side knob."
+        "Fixed-effect matvec engine ('auto' picks from the backend and "
+        "the shard's nonzero count). The spread across engines on the "
+        "chip is not measured."
     ),
 ))

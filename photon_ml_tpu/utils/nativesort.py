@@ -22,7 +22,6 @@ logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = Path(__file__).resolve().parent.parent / "native"
 _SRC = _NATIVE_DIR / "sortperm.cpp"
-_LIB = _NATIVE_DIR / "_sortperm.so"
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
@@ -36,7 +35,7 @@ def _load_native():
     if _lib_tried:
         return _lib
     _lib_tried = True
-    lib = build_and_load(_SRC, _LIB)
+    lib = build_and_load(_SRC)
     if lib is not None:
         lib.argsort_pairs.restype = ctypes.c_int
         lib.argsort_pairs.argtypes = [

@@ -20,7 +20,6 @@ os.environ["XLA_FLAGS"] = (
     + f" --xla_force_host_platform_device_count={8 // n_procs}"
 ).strip()
 os.environ["PHOTON_ML_TPU_PLAN_CACHE"] = ""
-os.environ["PHOTON_ML_TPU_COMPILE_CACHE"] = ""
 
 import jax
 

@@ -3,8 +3,8 @@
 The driver runs ``python bench.py`` at the end of every round and parses
 exactly one JSON line; this gate keeps that contract honest (keys, types,
 the north-star grid tile as the headline, pinned-vs-fresh baseline
-reporting, engine A/B recording incl. the quality-gated bf16 entry, and
-the stale-fallback failure path) without TPU hardware.
+reporting, engine A/B recording incl. the quality-gated bf16 entry) without
+TPU hardware, and that a non-smoke run without a TPU prints no number.
 """
 
 import json
@@ -25,7 +25,6 @@ def _smoke_env(**extra):
         BENCH_SMOKE="1",
         JAX_PLATFORMS="cpu",
         BENCH_PLAN_CACHE="",
-        PHOTON_ML_TPU_COMPILE_CACHE="",
     )
     env.update(extra)
     return env
@@ -78,66 +77,17 @@ def test_bench_smoke_contract():
     assert payload["smalldim_vs_baseline"] > 0
 
 
-def test_bench_failure_emits_stale_lastgood(tmp_path):
-    """When the backend is unreachable and nothing was measured, the bench
-    replays the repo's last good record marked stale (exit 3) instead of
-    zeroing the round — the r4 failure mode (VERDICT r4 weak #1)."""
-    # stage a bench.py copy next to a fabricated last-good record so the
-    # test cannot touch the real repo files
-    import shutil
-
-    shutil.copy(os.path.join(REPO, "bench.py"), tmp_path / "bench.py")
-    lastgood = {
-        "metric": "glmix_logistic_train_throughput",
-        "value": 12345.6,
-        "unit": "example_passes/sec/chip",
-        "vs_baseline": 11.5,
-        "headline_workload": "grid_2^24_coef_chip_tile_of_1B_layout",
-        "measured_at_unix": 1785490000.0,
-        "host": "testhost",
-    }
-    (tmp_path / "BENCH_LASTGOOD.json").write_text(json.dumps(lastgood))
-    # not smoke (so the fallback path is live), but force an unreachable
-    # backend: the preflight child import must fail fast
-    env = _smoke_env(
-        BENCH_SMOKE="0",
-        JAX_PLATFORMS="nonexistent-backend",
-        BENCH_PREFLIGHT_S="60",
-        PYTHONPATH=REPO,
-    )
+def test_bench_without_tpu_prints_no_number():
+    """Off smoke mode the training bench measures the chip or nothing: on a
+    host where JAX finds only the CPU it exits non-zero with empty stdout."""
     proc = subprocess.run(
-        [sys.executable, str(tmp_path / "bench.py")],
-        capture_output=True, text=True, timeout=300, env=env, cwd=tmp_path,
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        capture_output=True, text=True, timeout=300,
+        env=_smoke_env(BENCH_SMOKE="0"), cwd=REPO,
     )
-    assert proc.returncode == 3, (proc.returncode, proc.stderr[-2000:])
-    line = proc.stdout.strip().splitlines()[-1]
-    payload = json.loads(line)
-    assert payload["value"] == 12345.6
-    assert payload["stale"] is True
-    assert payload["error"]
-    assert payload["measured_at_unix"] == 1785490000.0
-
-
-def test_bench_failure_without_lastgood_is_zero(tmp_path):
-    """No partial, no last-good record -> the zeros line with exit 2 (the
-    caller must be able to tell 'nothing known' from 'stale known')."""
-    import shutil
-
-    shutil.copy(os.path.join(REPO, "bench.py"), tmp_path / "bench.py")
-    env = _smoke_env(
-        BENCH_SMOKE="0",
-        JAX_PLATFORMS="nonexistent-backend",
-        BENCH_PREFLIGHT_S="60",
-        PYTHONPATH=REPO,
-    )
-    proc = subprocess.run(
-        [sys.executable, str(tmp_path / "bench.py")],
-        capture_output=True, text=True, timeout=300, env=env, cwd=tmp_path,
-    )
-    assert proc.returncode == 2, (proc.returncode, proc.stderr[-2000:])
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert payload["value"] == 0.0
-    assert payload["error"]
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "no TPU" in proc.stderr
 
 
 def _artifact_fingerprint(path):
@@ -545,7 +495,7 @@ def test_bench_serving_validates_own_telemetry(tmp_path):
 
 def test_bench_history_append_when_opted_in(tmp_path):
     """BENCH_HISTORY_WRITE opts a smoke run into the perf-trajectory
-    append; the record carries the fields check_perf_trajectory.py reads."""
+    append."""
     import shutil
 
     shutil.copy(os.path.join(REPO, "bench.py"), tmp_path / "bench.py")

@@ -719,7 +719,7 @@ def test_bench_incremental_smoke_contract():
     nearline metrics (same contract as the training/serving benches)."""
     env = dict(
         os.environ, BENCH_SMOKE="1", JAX_PLATFORMS="cpu",
-        BENCH_PLAN_CACHE="", PHOTON_ML_TPU_COMPILE_CACHE="",
+        BENCH_PLAN_CACHE="",
     )
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"), "--incremental"],

@@ -42,7 +42,6 @@ def _worker_env(n_local_devices: int) -> dict:
         f"--xla_force_host_platform_device_count={n_local_devices}"
     )
     env["PHOTON_ML_TPU_PLAN_CACHE"] = ""
-    env["PHOTON_ML_TPU_COMPILE_CACHE"] = ""
     return env
 
 

@@ -209,9 +209,7 @@ class TestMultihostDegeneratePaths:
         )
         np.testing.assert_array_equal(np.asarray(got), rows)
 
-    def test_initialize_after_backend_up_is_false(self):
-        # the test process has long since initialized its CPU backend:
-        # auto-detect init must degrade to single-process, not raise
+    def test_initialize_without_cluster_flags_is_single_process(self):
         assert initialize_distributed() is False
 
     def test_explicit_cluster_request_after_backend_up_raises(self):

@@ -1,0 +1,186 @@
+"""Every Pallas kernel in photon_ml_tpu/ops compiles for a TPU v5e — through
+Mosaic, on a host that has no chip.
+
+``jax.experimental.topologies`` describes a v5e without one being attached,
+and ``jit(...).lower(<shapes placed on its devices>).compile()`` runs the real
+TPU compiler, Mosaic included. (``jax.export`` stops at MLIR emission, before
+the compiler that actually refuses kernels.) The interpreter tests elsewhere
+check what the kernels compute; this file checks that the chip will take
+them. ``chip_smoke.py`` then runs them on the chip at full width.
+
+libtpu allows one process per host to load it: do not run this file beside
+another process that does (a second pytest session, a chip run).
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+pytest.importorskip("libtpu")
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from photon_ml_tpu.losses.objective import make_glm_objective
+from photon_ml_tpu.losses.pointwise import LogisticLoss
+from photon_ml_tpu.ops import fused_perm, permute_net, sparse_perm
+from photon_ml_tpu.ops.data import LabeledData
+from photon_ml_tpu.ops.pallas_kernels import fused_value_grad_single
+from photon_ml_tpu.opt.config import (
+    GlmOptimizationConfiguration,
+    OptimizerConfig,
+)
+from photon_ml_tpu.opt.solve import solve
+
+ENGINES = {"fused": fused_perm, "benes": sparse_perm}
+OPS = ("matvec", "rmatvec", "rmatvec_sq")
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on device 0 of a described (not attached) v5e 2x2 host."""
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Make the engines choose what they choose on a TPU backend."""
+    monkeypatch.setattr(fused_perm, "pallas_available", lambda: True)
+    monkeypatch.setattr(permute_net, "pallas_available", lambda: True)
+
+
+def compile_for_tpu(fn, sharding, *args):
+    """AOT-compile ``fn`` for the TPU at the shapes of ``args``; returns the
+    number of Mosaic kernels in the program."""
+    structs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), args
+    )
+    lowered = jax.jit(fn).lower(*structs)
+    kernels = lowered.as_text().count("tpu_custom_call")
+    lowered.compile()
+    return kernels
+
+
+def _uniform_coo(rng, n, d, k):
+    rows = np.repeat(np.arange(n, dtype=np.int64), k)
+    cols = rng.integers(0, d, n * k).astype(np.int64)
+    return rows, cols, rng.standard_normal(n * k).astype(np.float32)
+
+
+def _plan_case(name):
+    """(shape, coo, from_coo kwargs, check) for each plan shape the kernels
+    must tile. Small: routing prep stays within seconds."""
+    rng = np.random.default_rng(0)
+    flat = dict(max_hot_cols=0, kp_cap=None, col_split=1)
+    if name == "one_level":      # 128^2 slots: descend, base, ascend
+        return (256, 200), _uniform_coo(rng, 256, 200, 8), flat
+    if name == "two_level":      # 128^3 slots: two descends and ascends
+        return (16384, 4096), _uniform_coo(rng, 16384, 4096, 16), flat
+    if name == "column_split":   # thin column tail: auto layout splits + spills
+        return (
+            (4096, 65536), _uniform_coo(rng, 4096, 65536, 16),
+            dict(max_hot_cols=0),
+        )
+    if name == "wide_groups":    # K = KP = 256 > 128 lanes: the q-path
+        r = np.repeat(np.arange(256, dtype=np.int64), 256)
+        c = np.tile(np.arange(256, dtype=np.int64), 256)
+        return (256, 256), (r, c, rng.standard_normal(r.size).astype(np.float32)), flat
+    raise KeyError(name)
+
+
+PLANS = ("one_level", "two_level", "column_split", "wide_groups")
+_BUILT = {}
+
+
+def _features(engine, plan):
+    if (engine, plan) not in _BUILT:
+        shape, (rows, cols, vals), kw = _plan_case(plan)
+        _BUILT[engine, plan] = ENGINES[engine].from_coo(
+            rows, cols, vals, shape, plan_cache="", **kw
+        )
+    return _BUILT[engine, plan]
+
+
+def _routed_blocks(feats):
+    if isinstance(feats, sparse_perm.ColumnSplitFeatures):
+        return [b for b in feats.blocks if hasattr(b, "plan")]
+    return [feats]
+
+
+@pytest.mark.parametrize("plan", PLANS)
+def test_plan_cases_have_the_shape_they_claim(plan):
+    """The cases above only cover the kernels if routing gives them the
+    structure their names say."""
+    feats = _features("fused", plan)
+    blocks = _routed_blocks(feats)
+    levels = {len(fused_perm.parse_plan(b.plan).descents) for b in blocks}
+    if plan == "two_level":
+        assert levels == {2}
+    else:
+        assert levels == {1}
+    if plan == "column_split":
+        assert len(blocks) > 1
+        assert any(b.spill_rows is not None for b in blocks)
+    if plan == "wide_groups":
+        assert blocks[0].ell_k > 128 and blocks[0].csc_k > 128
+
+
+@pytest.mark.parametrize("op", OPS)
+@pytest.mark.parametrize("plan", PLANS)
+@pytest.mark.parametrize("engine", list(ENGINES))
+def test_linear_maps_compile(v5e, on_tpu, engine, plan, op):
+    feats = _features(engine, plan)
+    vec = jnp.zeros(
+        feats.dim if op == "matvec" else feats.num_rows, jnp.float32
+    )
+    kernels = compile_for_tpu(lambda f, v: getattr(f, op)(v), v5e, feats, vec)
+    blocks = _routed_blocks(feats)
+    if engine == "fused":
+        # 2m+1 kernels per block: m descends, the base, m ascends
+        m = len(fused_perm.parse_plan(blocks[0].plan).descents)
+        assert kernels == len(blocks) * (2 * m + 1)
+    else:
+        # one kernel per shuffle stage (sublane stages of one row move nothing)
+        stages = sum(
+            1 for k in blocks[0].plan.kinds
+            if k[0] == "lane" or (k[0] == "sublane" and k[1] > 1)
+        )
+        assert kernels == len(blocks) * stages
+
+
+def test_lbfgs_solve_over_fused_features_compiles(v5e, on_tpu):
+    """What the chip runs is the kernels inside the optimizer's loops."""
+    feats = _features("fused", "one_level")
+    data = LabeledData.create(feats, jnp.zeros(feats.num_rows, jnp.float32))
+    objective = make_glm_objective(LogisticLoss)
+    cfg = GlmOptimizationConfiguration(
+        optimizer_config=OptimizerConfig.lbfgs(max_iterations=5),
+        regularization_weight=1.0,
+    )
+    kernels = compile_for_tpu(
+        lambda w0, dd: solve(objective, w0, dd, cfg).w,
+        v5e, jnp.zeros(feats.dim, jnp.float32), data,
+    )
+    assert kernels >= 6  # at least one matvec and one rmatvec
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["plain", "vmapped"])
+def test_random_effect_kernel_compiles(v5e, batched):
+    """``fused_value_grad_single``, alone and under the vmap of the
+    per-entity random-effect solve."""
+    fn = functools.partial(
+        fused_value_grad_single, kind=LogisticLoss, interpret=False
+    )
+    s, d = 24, 10
+    lead = (4,) if batched else ()
+    args = tuple(
+        jnp.zeros(lead + shape, jnp.float32)
+        for shape in ((s, d), (s,), (s,), (s,), (d,))
+    )
+    assert compile_for_tpu(jax.vmap(fn) if batched else fn, v5e, *args) == 1
