@@ -87,46 +87,29 @@ def say(message: str) -> None:
 
 
 # --------------------------------------------------------------------------
-# Measurement: wall clocks, and JAX's own compile events.
+# Measurement: wall clocks, and the tracer's spans of JAX's compile phases.
 # --------------------------------------------------------------------------
 
 
-class CompileMeter:
-    """Trace, lowering and backend-compile seconds as JAX reports them
-    (jax.monitoring), stamped so they can be cut by time window, plus the
-    persistent cache's hits and misses."""
+def compile_window(start: float, end: float):
+    """(compile seconds, programs compiled) inside [start, end], from the
+    compile spans of the program's own tracer (telemetry/compile_spans.py):
+    the union of the trace, lowering and backend-compile intervals cut to
+    the window, and the backend-compile spans that ended inside it."""
+    from photon_ml_tpu.telemetry import get_tracer, union_seconds
 
-    _DURATIONS = (
-        "/jax/core/compile/jaxpr_trace_duration",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    )
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.events = []  # (perf_counter at end, seconds, is_backend_compile)
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, seconds, **_):
-        if event in self._DURATIONS:
-            self.events.append(
-                (time.perf_counter(), seconds, event == self._DURATIONS[2])
-            )
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
-
-    def window(self, start: float, end: float):
-        """(compile seconds, programs compiled) inside [start, end]."""
-        inside = [e for e in self.events if start <= e[0] <= end]
-        return sum(e[1] for e in inside), sum(1 for e in inside if e[2])
+    tracer = get_tracer()
+    intervals, programs = [], 0
+    for s in tracer.spans():
+        if s.name not in ("jit/trace", "jit/lower", "jit/backend"):
+            continue
+        a = tracer.origin_perf + s.start_s
+        b = a + s.duration_s
+        if s.name == "jit/backend" and start <= b <= end:
+            programs += 1
+        if min(b, end) > max(a, start):
+            intervals.append((max(a, start), min(b, end)))
+    return union_seconds(intervals), programs
 
 
 def timed_calls(fn, *args, steady: int = 3):
@@ -259,11 +242,10 @@ def glmix_estimator(size: dict, sparse_engine: str, parallel=None, emitter=None)
 class Run:
     """What the phases share: sizes, the rehearsal switch, lazily built data."""
 
-    def __init__(self, size_name: str, rehearsal: bool, seed: int, meter):
+    def __init__(self, size_name: str, rehearsal: bool, seed: int):
         self.size = SIZES[size_name]
         self.rehearsal = rehearsal
         self.seed = seed
-        self.meter = meter
         # off the TPU "auto" is ELL by design, so a rehearsal asks for the
         # fused engine by name (and runs its kernels in the interpreter)
         self.sparse_engine = "fused" if rehearsal else "auto"
@@ -319,6 +301,44 @@ def phase_native(run: Run) -> None:
     check(color.min() == 0 and color.max() == 127, "euler_color misbehaves")
 
 
+def barrier_waits_for_compute_stream(run: Run) -> None:
+    """The tracer's device barrier (telemetry/span.py), behind every
+    ``device_sync`` span: dispatched after a device program of about half a
+    second, it must not return before that program has retired."""
+    import jax
+    import jax.numpy as jnp
+
+    from photon_ml_tpu.telemetry.span import _device_barrier
+
+    side = 256 if run.rehearsal else 4096
+
+    @jax.jit
+    def long_program(x, n):
+        return jax.lax.fori_loop(
+            0, n, lambda i, a: (a @ a) * jnp.bfloat16(1.0 / side), x
+        )
+
+    x = jnp.ones((side, side), jnp.bfloat16)
+    jax.block_until_ready(long_program(x, 8))
+    t0 = time.perf_counter()
+    jax.block_until_ready(long_program(x, 64))
+    per_matmul = (time.perf_counter() - t0) / 64
+    n = max(8, int(0.5 / per_matmul))
+    _device_barrier()
+    t0 = time.perf_counter()
+    busy = long_program(x, n)
+    _device_barrier()
+    t1 = time.perf_counter()
+    retired = busy.is_ready()
+    jax.block_until_ready(busy)
+    t2 = time.perf_counter()
+    say(
+        f"  span barrier: returned {(t1 - t0) * 1e3:.1f}ms after a device program "
+        f"that retired after {(t2 - t0) * 1e3:.1f}ms was dispatched"
+    )
+    check(retired, "the span barrier returned before the program ahead of it retired")
+
+
 def phase_engine(run: Run) -> None:
     """Route the fixed-effect tile; fused engine vs ELL on the same device."""
     import jax
@@ -366,6 +386,8 @@ def phase_engine(run: Run) -> None:
     if xla_plans and not run.rehearsal:
         say(f"  NOTE: {xla_plans} plan(s) would run permute_net's XLA gather")
 
+    barrier_waits_for_compute_stream(run)
+
     ell = train.ell_features("global")
     rng = np.random.default_rng(run.seed + 1)
     w = jnp.asarray(rng.standard_normal(feats.dim).astype(np.float32))
@@ -390,16 +412,17 @@ def phase_engine(run: Run) -> None:
         )
 
 
-def _span_windows(name: str, **attrs):
+def _span_windows(name: str, since: float, **attrs):
     """(start, end) in perf_counter time of every finished span ``name``
-    whose attributes include ``attrs``."""
+    that began at or after ``since`` and whose attributes include ``attrs``."""
     from photon_ml_tpu.telemetry import get_tracer
 
     tracer = get_tracer()
     out = []
     for s in tracer.spans():
-        if s.name == name and all(s.attrs.get(k) == v for k, v in attrs.items()):
-            start = tracer.origin_perf + s.start_s
+        start = tracer.origin_perf + s.start_s
+        if (s.name == name and start >= since
+                and all(s.attrs.get(k) == v for k, v in attrs.items())):
             out.append((start, start + s.duration_s))
     return out
 
@@ -419,32 +442,28 @@ class _SolverStatsListener:
 
 
 def _fit_and_report(run: Run, estimator, fit_fn):
-    """Run ``fit_fn`` under the tracer; print where its time went; check the
-    loss fell and the held-out AUC means something."""
+    """Run ``fit_fn`` (the tracer is on for the whole smoke); print where its
+    time went; check the loss fell and the held-out AUC means something."""
     import numpy as np
 
-    from photon_ml_tpu.telemetry import disable_tracing, enable_tracing
-
     train, _ = run.data()
-    enable_tracing(device_sync=True)
     t0 = time.perf_counter()
     fit = fit_fn()
     t1 = time.perf_counter()
-    disable_tracing()
 
     for cid in estimator.coordinate_configs:
-        for a, b in _span_windows("game/build_coordinate", coordinate=cid):
+        for a, b in _span_windows("game/build_coordinate", t0, coordinate=cid):
             say(f"  build coordinate {cid}: {b - a:.1f}s (host grouping/routing + upload)")
     for outer in range(estimator.num_outer_iterations):
-        for a, b in _span_windows("cd/outer_iter", outer=outer):
-            compile_s, programs = run.meter.window(a, b)
+        for a, b in _span_windows("cd/outer_iter", t0, outer=outer):
+            compile_s, programs = compile_window(a, b)
             label = "first call" if outer == 0 else "steady"
             say(
                 f"  outer iteration {outer} ({label}): {b - a:.1f}s, of which "
                 f"compile {compile_s:.1f}s in {programs} program(s)"
             )
             for cid in estimator.update_order:
-                for ca, cb in _span_windows("cd/coordinate", coordinate=cid, outer=outer):
+                for ca, cb in _span_windows("cd/coordinate", t0, coordinate=cid, outer=outer):
                     say(f"    {cid}: {cb - ca:.2f}s")
     say(f"  fit wall {t1 - t0:.1f}s")
 
@@ -856,8 +875,12 @@ def main(argv=None) -> int:
             "JAX_COMPILATION_CACHE_DIR") else "default, in the checkout"
         say(f"compile cache: {cache_dir} ({placed}); {entries} entries at start")
 
-    meter = CompileMeter()
-    run = Run(args.size, rehearsal, args.seed, meter)
+    # the program's own tracer, on for the whole smoke: its compile spans
+    # are the compile seconds and program counts printed below
+    from photon_ml_tpu.telemetry import enable_tracing, get_registry
+
+    enable_tracing(device_sync=True)
+    run = Run(args.size, rehearsal, args.seed)
     walls = {}
     t_start = time.perf_counter()
     for name in phases:
@@ -865,17 +888,18 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         PHASE_FUNCTIONS[name](run)
         t1 = time.perf_counter()
-        compile_s, programs = meter.window(t0, t1)
+        compile_s, programs = compile_window(t0, t1)
         walls[name] = round(t1 - t0, 1)
         say(
             f"phase {name}: ok in {t1 - t0:.1f}s (compile {compile_s:.1f}s in "
             f"{programs} program(s)); peak_bytes_in_use {peak_bytes()}"
         )
     # only programs that take over 0.5 s to compile go through the cache
-    warm = meter.cache_hits > meter.cache_misses
+    hits = int(get_registry().counter_value("jit.cache.hits"))
+    misses = int(get_registry().counter_value("jit.cache.misses"))
     cache_line = "off" if cache_dir is None else (
-        f"was {'warm' if warm else 'cold'}: "
-        f"{meter.cache_hits} hit(s) / {meter.cache_misses} miss(es)"
+        f"was {'warm' if hits > misses else 'cold'}: "
+        f"{hits} hit(s) / {misses} miss(es)"
     )
     say(
         f"total {time.perf_counter() - t_start:.1f}s; phase walls {walls}; "

@@ -364,8 +364,9 @@ class CoordinateDescent:
             return coord.score_device(model)
 
         # initial scoring for warm-started models
-        for cid, model in models.items():
-            scores[cid] = _score(cid, model)
+        with span("cd/initial_scores", coordinates=len(models)):
+            for cid, model in models.items():
+                scores[cid] = _score(cid, model)
 
         # Both planes maintain a RUNNING total (the legacy driver re-summed
         # all C coordinates TWICE per update — once for the residual, once
@@ -423,7 +424,8 @@ class CoordinateDescent:
                                     models.get(cid), np.asarray(residual)
                                 )
                             models[cid] = model
-                            new_own = _score(cid, model)
+                            with span("cd/score", coordinate=cid):
+                                new_own = _score(cid, model)
                             # incremental running total: O(N) per update
                             # instead of a C-way re-sum; the old total's
                             # buffer is donated
@@ -445,7 +447,8 @@ class CoordinateDescent:
                             stats.record_h2d()
                             model = coord.update_model(models.get(cid), residual)
                             models[cid] = model
-                            new_own = _score(cid, model)
+                            with span("cd/score", coordinate=cid):
+                                new_own = _score(cid, model)
                             # same incremental algebra as the device plane,
                             # in numpy
                             total_np = (
@@ -539,7 +542,7 @@ class CoordinateDescent:
         )
 
     # ------------------------------------------------------------- async
-    def _solve_in_flight(self, coord, model0, residual, stats, lock):
+    def _solve_in_flight(self, cid, coord, model0, residual, stats, lock):
         """Worker-thread body of one dispatched coordinate solve: train
         against the (possibly stale) residual and rescore. Runs inside the
         executor's ``cd/overlap`` span; touches no driver-owned state —
@@ -547,7 +550,6 @@ class CoordinateDescent:
         driver's lock with the same accounting as the sync device path."""
         if coord.supports_device_plane:
             model = coord.update_model_device(model0, residual)
-            new_own = coord.score_device(model)
         else:
             with lock:
                 stats.record_d2h()
@@ -555,6 +557,7 @@ class CoordinateDescent:
             with lock:
                 stats.record_d2h()
                 stats.record_h2d()
+        with span("cd/score", coordinate=cid):
             new_own = coord.score_device(model)
         return model, new_own
 
@@ -591,13 +594,14 @@ class CoordinateDescent:
         total = jnp.zeros_like(zeros)
 
         # initial scoring for warm-started models (same path as sync)
-        for cid, model in models.items():
-            coord = self.coordinates[cid]
-            if not coord.supports_device_plane:
-                stats.record_d2h()
-                stats.record_h2d()
-            scores[cid] = coord.score_device(model)
-            total = total + scores[cid]
+        with span("cd/initial_scores", coordinates=len(models)):
+            for cid, model in models.items():
+                coord = self.coordinates[cid]
+                if not coord.supports_device_plane:
+                    stats.record_d2h()
+                    stats.record_h2d()
+                scores[cid] = coord.score_device(model)
+                total = total + scores[cid]
 
         objective_history: List[Tuple[str, float]] = []
         validation_history: List[Tuple[str, float]] = []
@@ -691,6 +695,7 @@ class CoordinateDescent:
                             cid,
                             functools.partial(
                                 self._solve_in_flight,
+                                cid,
                                 coord,
                                 models.get(cid),
                                 residual,

@@ -846,141 +846,145 @@ class GameEstimator:
         initial_models: Optional[Dict[str, object]],
         progress: Optional[object] = None,
     ) -> GameFit:
-        meta = self._meta()
+        # everything a fit sets up before coordinate descent runs: the label,
+        # weight and offset uploads, the objective / regulariser / validation
+        # closures, a new CoordinateDescent, the checkpoint to resume from
+        with span("game/prepare_fit", coordinates=len(coordinates)):
+            meta = self._meta()
 
-        loss = loss_for_task(self.task)
-        labels = jnp.asarray(data.labels)
-        weights = jnp.asarray(data.weights)
-        offsets = jnp.asarray(data.offsets)
+            loss = loss_for_task(self.task)
+            labels = jnp.asarray(data.labels)
+            weights = jnp.asarray(data.weights)
+            offsets = jnp.asarray(data.offsets)
 
-        def training_objective(total_scores) -> float:
-            # accepts the device plane's running total (jax.Array) or the
-            # host plane's numpy sum; exactly ONE scalar crosses to the host
-            z = offsets + jnp.asarray(total_scores)
-            terms = loss.value(z, labels)
-            return float(jnp.sum(jnp.where(weights > 0, weights * terms, 0.0)))
+            def training_objective(total_scores) -> float:
+                # accepts the device plane's running total (jax.Array) or the
+                # host plane's numpy sum; exactly ONE scalar crosses to the host
+                z = offsets + jnp.asarray(total_scores)
+                terms = loss.value(z, labels)
+                return float(jnp.sum(jnp.where(weights > 0, weights * terms, 0.0)))
 
-        # per-coordinate cache keyed by model identity (strong ref, so an id
-        # is never reused while cached): only the coordinate that just
-        # updated recomputes its term
-        reg_cache: Dict[str, Tuple[object, float]] = {}
+            # per-coordinate cache keyed by model identity (strong ref, so an id
+            # is never reused while cached): only the coordinate that just
+            # updated recomputes its term
+            reg_cache: Dict[str, Tuple[object, float]] = {}
 
-        def regularization_term(models: Dict[str, object]) -> float:
-            """Σ per-coordinate 0.5*l2*||w||^2 + l1*||w||_1 over the current
-            models (reference getRegularizationTermValue, logged per update
-            CoordinateDescent.scala:247-258). Weights come from the built
-            Coordinate objects, which carry sweep/tuning overrides."""
-            total = 0.0
-            for cid, m in models.items():
-                coord = coordinates.get(cid)
-                if coord is None:
-                    continue
-                cached = reg_cache.get(cid)
-                if cached is None or cached[0] is not m:
-                    reg_cache[cid] = (m, _coordinate_regularization(m, coord))
-                total += reg_cache[cid][1]
-            return total
+            def regularization_term(models: Dict[str, object]) -> float:
+                """Σ per-coordinate 0.5*l2*||w||^2 + l1*||w||_1 over the current
+                models (reference getRegularizationTermValue, logged per update
+                CoordinateDescent.scala:247-258). Weights come from the built
+                Coordinate objects, which carry sweep/tuning overrides."""
+                total = 0.0
+                for cid, m in models.items():
+                    coord = coordinates.get(cid)
+                    if coord is None:
+                        continue
+                    cached = reg_cache.get(cid)
+                    if cached is None or cached[0] is not m:
+                        reg_cache[cid] = (m, _coordinate_regularization(m, coord))
+                    total += reg_cache[cid][1]
+                return total
 
-        validate = None
-        if validation_data is not None:
-            def validate(models: Dict[str, object]) -> float:
-                gm = GameModel(models=dict(models), meta=meta, task=self.task)
-                scores = gm.score(validation_data) + validation_data.offsets
-                primary = self.evaluator.evaluate(
-                    scores, validation_data.labels, validation_data.weights
-                )
-                if self.extra_evaluators:
-                    # reference CoordinateDescent.scala:283-293: every
-                    # configured evaluator is computed and logged per
-                    # coordinate update; only the first drives selection
-                    extras = {
-                        ev.name: ev.evaluate(
-                            scores,
-                            validation_data.labels,
-                            validation_data.weights,
-                        )
-                        for ev in self.extra_evaluators
-                    }
-                    logger.info(
-                        "validation metrics: %s=%.6f %s",
-                        self.evaluator.name, primary,
-                        " ".join(f"{k}={v:.6f}" for k, v in extras.items()),
+            validate = None
+            if validation_data is not None:
+                def validate(models: Dict[str, object]) -> float:
+                    gm = GameModel(models=dict(models), meta=meta, task=self.task)
+                    scores = gm.score(validation_data) + validation_data.offsets
+                    primary = self.evaluator.evaluate(
+                        scores, validation_data.labels, validation_data.weights
                     )
-                return primary
+                    if self.extra_evaluators:
+                        # reference CoordinateDescent.scala:283-293: every
+                        # configured evaluator is computed and logged per
+                        # coordinate update; only the first drives selection
+                        extras = {
+                            ev.name: ev.evaluate(
+                                scores,
+                                validation_data.labels,
+                                validation_data.weights,
+                            )
+                            for ev in self.extra_evaluators
+                        }
+                        logger.info(
+                            "validation metrics: %s=%.6f %s",
+                            self.evaluator.name, primary,
+                            " ".join(f"{k}={v:.6f}" for k, v in extras.items()),
+                        )
+                    return primary
 
-        schedule = self._effective_schedule()
-        # the async schedule's RE leg: overlap bucket solves inside each
-        # random-effect coordinate (0 restores the sequential, bitwise-
-        # identical path — set every run so shared built coordinates are
-        # correct for whichever schedule this fit uses)
-        for coord in coordinates.values():
-            if hasattr(coord, "overlap_buckets"):
-                coord.overlap_buckets = 2 if schedule == "async" else 0
+            schedule = self._effective_schedule()
+            # the async schedule's RE leg: overlap bucket solves inside each
+            # random-effect coordinate (0 restores the sequential, bitwise-
+            # identical path — set every run so shared built coordinates are
+            # correct for whichever schedule this fit uses)
+            for coord in coordinates.values():
+                if hasattr(coord, "overlap_buckets"):
+                    coord.overlap_buckets = 2 if schedule == "async" else 0
 
-        cd = CoordinateDescent(
-            coordinates,
-            num_rows=data.num_rows,
-            update_order=self.update_order,
-            training_objective=training_objective,
-            regularization_term=regularization_term,
-            validate=validate,
-            validation_better_than=self.evaluator.better_than,
-            emitter=self.emitter,
-            score_plane=self._effective_score_plane(),
-            schedule=schedule,
-            staleness=self.staleness,
-            progress=progress,
-        )
-
-        start_iteration = 0
-        initial_best = None
-        on_iteration_end = None
-        prior_objective_history: List[Tuple[str, float]] = []
-        prior_validation_history: List[Tuple[str, float]] = []
-        if initial_models is not None:
-            # warm start may cover a subset of coordinates
-            self._check_resume_compatible(
-                initial_models, coordinates, require_all=False
+            cd = CoordinateDescent(
+                coordinates,
+                num_rows=data.num_rows,
+                update_order=self.update_order,
+                training_objective=training_objective,
+                regularization_term=regularization_term,
+                validate=validate,
+                validation_better_than=self.evaluator.better_than,
+                emitter=self.emitter,
+                score_plane=self._effective_score_plane(),
+                schedule=schedule,
+                staleness=self.staleness,
+                progress=progress,
             )
-        if checkpoint_dir is not None:
-            from photon_ml_tpu import checkpoint as ckpt
 
-            if ckpt.has_checkpoint(checkpoint_dir):
-                initial_models, state, best = ckpt.load_training_checkpoint(
-                    checkpoint_dir
+            start_iteration = 0
+            initial_best = None
+            on_iteration_end = None
+            prior_objective_history: List[Tuple[str, float]] = []
+            prior_validation_history: List[Tuple[str, float]] = []
+            if initial_models is not None:
+                # warm start may cover a subset of coordinates
+                self._check_resume_compatible(
+                    initial_models, coordinates, require_all=False
                 )
-                self._check_resume_compatible(initial_models, coordinates)
-                start_iteration = int(state["completed_iterations"])
-                if best is not None and state.get("best_metric") is not None:
-                    initial_best = (best, float(state["best_metric"]))
-                prior_objective_history = [
-                    tuple(x) for x in state.get("objective_history", [])
-                ]
-                prior_validation_history = [
-                    tuple(x) for x in state.get("validation_history", [])
-                ]
-                logger.info(
-                    "resuming from checkpoint %s at outer iteration %d",
-                    checkpoint_dir, start_iteration,
-                )
+            if checkpoint_dir is not None:
+                from photon_ml_tpu import checkpoint as ckpt
 
-            def on_iteration_end(outer: int, running) -> None:
-                ckpt.save_training_checkpoint(
-                    checkpoint_dir,
-                    running.models,
-                    state={
-                        "completed_iterations": outer + 1,
-                        "best_metric": running.best_metric,
-                        # full histories so a second resume stays complete
-                        "objective_history": prior_objective_history
-                        + running.objective_history,
-                        "validation_history": prior_validation_history
-                        + running.validation_history,
-                    },
-                    best_models=(
-                        running.best_models if validate is not None else None
-                    ),
-                )
+                if ckpt.has_checkpoint(checkpoint_dir):
+                    initial_models, state, best = ckpt.load_training_checkpoint(
+                        checkpoint_dir
+                    )
+                    self._check_resume_compatible(initial_models, coordinates)
+                    start_iteration = int(state["completed_iterations"])
+                    if best is not None and state.get("best_metric") is not None:
+                        initial_best = (best, float(state["best_metric"]))
+                    prior_objective_history = [
+                        tuple(x) for x in state.get("objective_history", [])
+                    ]
+                    prior_validation_history = [
+                        tuple(x) for x in state.get("validation_history", [])
+                    ]
+                    logger.info(
+                        "resuming from checkpoint %s at outer iteration %d",
+                        checkpoint_dir, start_iteration,
+                    )
+
+                def on_iteration_end(outer: int, running) -> None:
+                    ckpt.save_training_checkpoint(
+                        checkpoint_dir,
+                        running.models,
+                        state={
+                            "completed_iterations": outer + 1,
+                            "best_metric": running.best_metric,
+                            # full histories so a second resume stays complete
+                            "objective_history": prior_objective_history
+                            + running.objective_history,
+                            "validation_history": prior_validation_history
+                            + running.validation_history,
+                        },
+                        best_models=(
+                            running.best_models if validate is not None else None
+                        ),
+                    )
 
         with span(
             "game/fit",

@@ -27,6 +27,7 @@ from photon_ml_tpu.opt.config import GlmOptimizationConfiguration
 from photon_ml_tpu.opt.solve import solve
 from photon_ml_tpu.opt.state import SolveResult
 from photon_ml_tpu.ops.data import LabeledData
+from photon_ml_tpu.telemetry.span import get_tracer, span
 from photon_ml_tpu.types import TaskType
 
 
@@ -77,114 +78,128 @@ def train_glm(
     is set, training runs in normalized space and the optimum is mapped back
     (reference NormalizationContext.transformModelCoefficients / Driver flow).
     """
-    objective = make_glm_objective(loss_for_task(task))
-    if regularization_weights is None:
-        regularization_weights = [configuration.regularization_weight]
-    if track_models:
-        configuration = dataclasses.replace(
-            configuration,
-            optimizer_config=dataclasses.replace(
-                configuration.optimizer_config, track_coefficients=True
-            ),
-        )
+    with span(
+        "glm/train",
+        optimizer=configuration.optimizer_config.optimizer.name,
+        weights=1 if regularization_weights is None else len(regularization_weights),
+    ):
+        objective = make_glm_objective(loss_for_task(task))
+        if regularization_weights is None:
+            regularization_weights = [configuration.regularization_weight]
+        if track_models:
+            configuration = dataclasses.replace(
+                configuration,
+                optimizer_config=dataclasses.replace(
+                    configuration.optimizer_config, track_coefficients=True
+                ),
+            )
 
-    dim = data.dim
-    if initial_model is not None:
-        # initial_model carries ORIGINAL-space coefficients; map into the
-        # normalized training space before warm-starting.
-        w = initial_model.coefficients.means
-        if data.norm is not None:
-            w = data.norm.inverse_transform_model_coefficients(w, intercept_index)
-    else:
-        w = jnp.zeros((dim,), dtype=jnp.float32)
+        dim = data.dim
+        if initial_model is not None:
+            # initial_model carries ORIGINAL-space coefficients; map into the
+            # normalized training space before warm-starting.
+            w = initial_model.coefficients.means
+            if data.norm is not None:
+                w = data.norm.inverse_transform_model_coefficients(w, intercept_index)
+        else:
+            w = jnp.zeros((dim,), dtype=jnp.float32)
 
-    reg = configuration.regularization
-    use_l1 = any(reg.l1_weight(lw) > 0 for lw in regularization_weights)
+        reg = configuration.regularization
+        use_l1 = any(reg.l1_weight(lw) > 0 for lw in regularization_weights)
 
-    # An explicit 0.0 l1_weight pins the solver to LBFGS/TRON even when the
-    # configuration's own regularization_weight would imply L1 (the sweep
-    # weights are authoritative).
-    # box_constraints arrive in the ORIGINAL feature space (the reference's
-    # per-feature constraint map, GLMSuite); training may run in normalized
-    # space, where w_orig = factor .* w_norm (componentwise, factor > 0), so
-    # the bounds map by the same positive diagonal. Shift normalization
-    # mixes the intercept non-componentwise — an explicitly-bounded
-    # intercept cannot be honored there and is rejected.
-    if box_constraints is not None and data.norm is not None:
-        lo, hi = box_constraints
-        if data.norm.shift is not None and intercept_index is not None:
-            import numpy as np
+        # An explicit 0.0 l1_weight pins the solver to LBFGS/TRON even when the
+        # configuration's own regularization_weight would imply L1 (the sweep
+        # weights are authoritative).
+        # box_constraints arrive in the ORIGINAL feature space (the reference's
+        # per-feature constraint map, GLMSuite); training may run in normalized
+        # space, where w_orig = factor .* w_norm (componentwise, factor > 0), so
+        # the bounds map by the same positive diagonal. Shift normalization
+        # mixes the intercept non-componentwise — an explicitly-bounded
+        # intercept cannot be honored there and is rejected.
+        if box_constraints is not None and data.norm is not None:
+            lo, hi = box_constraints
+            if data.norm.shift is not None and intercept_index is not None:
+                import numpy as np
 
-            if (np.isfinite(np.asarray(lo)[intercept_index])
-                    or np.isfinite(np.asarray(hi)[intercept_index])):
-                raise ValueError(
-                    "an intercept box constraint cannot be combined with "
-                    "shift normalization (the intercept mixes all "
-                    "coefficients there); constrain only non-intercept "
-                    "features or use a factor-only normalization"
-                )
-        factor = data.norm.factor
-        if factor is not None:
-            lo = jnp.asarray(lo) / factor
-            hi = jnp.asarray(hi) / factor
-        box_constraints = (lo, hi)
-    solver = jax.jit(
-        lambda w0, dd, l2, l1: solve(
-            objective,
-            w0,
-            dd,
-            configuration,
-            l2_weight=l2,
-            l1_weight=l1 if use_l1 else 0.0,
-            box=box_constraints,
-        )
-    )
-    hess_diag = jax.jit(objective.hessian_diag) if compute_variances else None
-
-    # high -> low so each warm start begins from a smoother problem
-    # (reference ModelTraining.scala:160-206)
-    sweep = sorted(regularization_weights, reverse=True)
-    fits: dict[float, GlmFit] = {}
-    for lam in sweep:
-        l2 = jnp.float32(reg.l2_weight(lam))
-        l1 = jnp.float32(reg.l1_weight(lam))
-        result = solver(w, data, l2, l1)
-        if warm_start:
-            w = result.w
-
-        variances = None
-        if compute_variances:
-            # var_j ~= 1 / (H_jj + eps) (reference
-            # DistributedOptimizationProblem.scala:80-94)
-            diag = hess_diag(result.w, data, l2)
-            variances = 1.0 / (diag + 1e-12)
-
-        w_out = result.w
-        if data.norm is not None:
-            w_out = data.norm.transform_model_coefficients(w_out, intercept_index)
-            if variances is not None:
-                variances = data.norm.transform_model_variances(variances, intercept_index)
-        model = GeneralizedLinearModel(
-            coefficients=Coefficients(means=w_out, variances=variances), task=task
-        )
-
-        tracked = None
-        if track_models and result.w_history is not None:
-            tracked = []
-            iters = int(result.iterations)
-            for w_i in result.w_history[: iters + 1]:
-                if data.norm is not None:
-                    w_i = data.norm.transform_model_coefficients(
-                        w_i, intercept_index
+                if (np.isfinite(np.asarray(lo)[intercept_index])
+                        or np.isfinite(np.asarray(hi)[intercept_index])):
+                    raise ValueError(
+                        "an intercept box constraint cannot be combined with "
+                        "shift normalization (the intercept mixes all "
+                        "coefficients there); constrain only non-intercept "
+                        "features or use a factor-only normalization"
                     )
-                tracked.append(
-                    GeneralizedLinearModel(
-                        coefficients=Coefficients(means=w_i), task=task
-                    )
-                )
-        fits[lam] = GlmFit(
-            regularization_weight=lam, model=model, result=result,
-            tracked_models=tracked,
+            factor = data.norm.factor
+            if factor is not None:
+                lo = jnp.asarray(lo) / factor
+                hi = jnp.asarray(hi) / factor
+            box_constraints = (lo, hi)
+        solver = jax.jit(
+            lambda w0, dd, l2, l1: solve(
+                objective,
+                w0,
+                dd,
+                configuration,
+                l2_weight=l2,
+                l1_weight=l1 if use_l1 else 0.0,
+                box=box_constraints,
+            )
         )
+        hess_diag = jax.jit(objective.hessian_diag) if compute_variances else None
 
-    return [fits[lam] for lam in regularization_weights]
+        # high -> low so each warm start begins from a smoother problem
+        # (reference ModelTraining.scala:160-206)
+        sweep = sorted(regularization_weights, reverse=True)
+        fits: dict[float, GlmFit] = {}
+        for lam in sweep:
+            l2 = jnp.float32(reg.l2_weight(lam))
+            l1 = jnp.float32(reg.l1_weight(lam))
+            with span("glm/solve", regularization_weight=float(lam)) as solving:
+                result = solver(w, data, l2, l1)
+                if get_tracer().enabled:
+                    # a traced run waits for the solve here, so that the span
+                    # holds the device's work and can say what it counted
+                    jax.block_until_ready(result)
+                    solving.set_attrs(
+                        iterations=int(result.iterations),
+                        evaluations=int(result.evaluations),
+                    )
+            if warm_start:
+                w = result.w
+
+            variances = None
+            if compute_variances:
+                # var_j ~= 1 / (H_jj + eps) (reference
+                # DistributedOptimizationProblem.scala:80-94)
+                diag = hess_diag(result.w, data, l2)
+                variances = 1.0 / (diag + 1e-12)
+
+            w_out = result.w
+            if data.norm is not None:
+                w_out = data.norm.transform_model_coefficients(w_out, intercept_index)
+                if variances is not None:
+                    variances = data.norm.transform_model_variances(variances, intercept_index)
+            model = GeneralizedLinearModel(
+                coefficients=Coefficients(means=w_out, variances=variances), task=task
+            )
+
+            tracked = None
+            if track_models and result.w_history is not None:
+                tracked = []
+                iters = int(result.iterations)
+                for w_i in result.w_history[: iters + 1]:
+                    if data.norm is not None:
+                        w_i = data.norm.transform_model_coefficients(
+                            w_i, intercept_index
+                        )
+                    tracked.append(
+                        GeneralizedLinearModel(
+                            coefficients=Coefficients(means=w_i), task=task
+                        )
+                    )
+            fits[lam] = GlmFit(
+                regularization_weight=lam, model=model, result=result,
+                tracked_models=tracked,
+            )
+
+        return [fits[lam] for lam in regularization_weights]

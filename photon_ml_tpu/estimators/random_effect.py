@@ -245,9 +245,11 @@ def _solve_bucket_adaptive(
         ):
             state = progs.chunk(state, data, l2)
             widths.append(width)
-            # host-side bookkeeping below overlaps the async device dispatch
-            its_after = np.asarray(jax.device_get(state.it)).astype(np.int64)
-            reasons = np.asarray(jax.device_get(state.reason))
+            # the host's wait for the device, once a round: the two pulls
+            # block until the chunk just dispatched has retired
+            with span("re/round_wait"):
+                its_after = np.asarray(jax.device_get(state.it)).astype(np.int64)
+                reasons = np.asarray(jax.device_get(state.reason))
             executed += width * int(np.max(its_after - its_before)) if width else 0
             done = (reasons != _NOT_CONVERGED) | (its_after >= max_iterations)
             n_live = int(np.sum(~done))

@@ -337,6 +337,7 @@ def _descend_call(
         out_specs=pl.BlockSpec((LANES, u, LANES), lambda b, g: (b, g, 0)),
         out_shape=jax.ShapeDtypeStruct((B * LANES, R1, LANES), payload_dtype),
         interpret=interpret,
+        name="fused_descend",
     )(*inputs)
 
 
@@ -433,6 +434,7 @@ def _ascend_call(v3, idx, B: int, R: int, epi, interpret: bool):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_ascend",
     )(*inputs)
     if epi is None:
         return out
@@ -478,6 +480,7 @@ def _base_call(v, idx_a, idx_s, rows: int, idx_b, interpret: bool) -> jax.Array:
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((M, LANES), v.dtype),
         interpret=interpret,
+        name="fused_base",
     )(*inputs)
 
 

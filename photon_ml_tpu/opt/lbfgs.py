@@ -45,6 +45,7 @@ class _LbfgsState(NamedTuple):
     rho: jax.Array        # [m] 1/(s.y)
     count: jax.Array      # int32 number of valid history pairs
     it: jax.Array         # int32 outer iteration
+    evals: jax.Array      # int32 objective.value_and_grad calls so far
     reason: jax.Array     # int32 ConvergenceReason
     history: jax.Array    # [max_iter+1] objective values
     w_hist: jax.Array     # [max_iter+1, d] coefficients (or [0] when off)
@@ -181,6 +182,7 @@ def lbfgs_init(
         rho=jnp.zeros((m,), dtype=dtype),
         count=jnp.int32(0),
         it=jnp.int32(0),
+        evals=jnp.int32(1),
         reason=jnp.int32(ConvergenceReason.NOT_CONVERGED.value),
         history=history0,
         w_hist=w_hist0,
@@ -238,6 +240,7 @@ def lbfgs_chunk(
         ls = strong_wolfe_search(
             eval_step, s.f, s.g, dphi0, t_init, config.max_line_search_iterations
         )
+        evals = s.evals + ls.evaluations
 
         w_new = s.w + ls.t * d
         w_new = _project_box(w_new, box_lo, box_hi)
@@ -245,6 +248,7 @@ def lbfgs_chunk(
         # is configured (static branch — no cost otherwise).
         if has_box:
             f_new, g_new = objective.value_and_grad(w_new, data, l2_weight)
+            evals = evals + 1
         else:
             f_new, g_new = ls.f, ls.g
 
@@ -289,6 +293,7 @@ def lbfgs_chunk(
             rho=rho,
             count=count,
             it=it,
+            evals=evals,
             reason=reason,
             history=s.history.at[it].set(f_new),
             w_hist=(
@@ -319,6 +324,7 @@ def lbfgs_finalize(
         value=state.f,
         grad_norm=jnp.linalg.norm(state.g),
         iterations=state.it,
+        evaluations=state.evals,
         reason=reason,
         value_history=state.history,
         w_history=state.w_hist if config.track_coefficients else None,

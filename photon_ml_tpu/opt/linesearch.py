@@ -27,6 +27,7 @@ class LineSearchResult(NamedTuple):
     f: jax.Array        # phi(t)
     g: jax.Array        # full gradient at w + t*d
     success: jax.Array  # bool: Wolfe conditions met
+    evaluations: jax.Array  # int32 eval_step calls made
 
 
 def strong_wolfe_search(
@@ -171,4 +172,5 @@ def strong_wolfe_search(
         f=jnp.where(use_acc, o.f_acc, jnp.where(o.has_best, o.f_best, f0)),
         g=jnp.where(use_acc, o.g_acc, o.g_best),
         success=use_acc | o.has_best,
+        evaluations=o.i,
     )

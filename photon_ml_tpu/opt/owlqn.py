@@ -73,6 +73,7 @@ class _OwlqnState(NamedTuple):
     rho: jax.Array
     count: jax.Array
     it: jax.Array
+    evals: jax.Array      # int32 objective.value_and_grad calls so far
     reason: jax.Array
     history: jax.Array
     w_hist: jax.Array     # [max_iter+1, d] coefficients (or [0] when off)
@@ -118,6 +119,7 @@ def owlqn_init(
         rho=jnp.zeros((m,), dtype=dtype),
         count=jnp.int32(0),
         it=jnp.int32(0),
+        evals=jnp.int32(1),
         reason=jnp.int32(ConvergenceReason.NOT_CONVERGED.value),
         history=history0,
         w_hist=w_hist0,
@@ -202,6 +204,7 @@ def owlqn_chunk(
             ok=jnp.bool_(False),
         )
         ls = jax.lax.while_loop(ls_cond, ls_body, ls0)
+        evals = s.evals + ls.i
 
         w_new = jnp.where(ls.ok, ls.w_t, s.w)
         f_new = jnp.where(ls.ok, ls.f_t, s.f)
@@ -225,6 +228,7 @@ def owlqn_chunk(
                 return f_new, g_new, F_new
 
             f_new, g_new, F_new = jax.lax.cond(clipped, _recompute, _reuse, None)
+            evals = evals + clipped.astype(jnp.int32)
             w_new = w_proj
 
         s_vec = w_new - s.w
@@ -266,6 +270,7 @@ def owlqn_chunk(
             rho=rho,
             count=count,
             it=it,
+            evals=evals,
             reason=reason,
             history=s.history.at[it].set(F_new),
             w_hist=(
@@ -296,6 +301,7 @@ def owlqn_finalize(
         value=state.F,
         grad_norm=jnp.linalg.norm(pg_final),
         iterations=state.it,
+        evaluations=state.evals,
         reason=reason,
         value_history=state.history,
         w_history=state.w_hist if config.track_coefficients else None,

@@ -28,6 +28,10 @@ class SolveResult:
     value: jax.Array          # scalar final objective (incl. L2; incl. L1 for OWL-QN)
     grad_norm: jax.Array      # scalar ||grad|| (pseudo-gradient for OWL-QN)
     iterations: jax.Array     # int32 number of outer iterations performed
+    # int32 objective.value_and_grad calls, counted in the loop carry where
+    # they happen: the initial point, every line-search trial, every
+    # recomputation after a box projection
+    evaluations: jax.Array
     reason: jax.Array         # int32 ConvergenceReason code
     value_history: jax.Array  # [max_iterations+1] objective per iteration, NaN-padded
     # [max_iterations+1, d] per-iteration coefficients, NaN-padded — only

@@ -110,6 +110,7 @@ class _TronState(NamedTuple):
     g: jax.Array
     delta: jax.Array
     it: jax.Array
+    evals: jax.Array      # int32 objective.value_and_grad calls so far
     failures: jax.Array
     reason: jax.Array
     history: jax.Array
@@ -149,6 +150,7 @@ def tron_init(
         g=g0,
         delta=g0_norm,  # initial radius = ||g0|| (reference TRON.scala:112)
         it=jnp.int32(0),
+        evals=jnp.int32(1),
         failures=jnp.int32(0),
         reason=jnp.where(
             g0_norm <= abs_g_tol,
@@ -255,6 +257,7 @@ def tron_chunk(
             g=g_new,
             delta=delta,
             it=it,
+            evals=s.evals + 1,
             failures=failures,
             reason=reason,
             history=s.history.at[it].set(f_new),
@@ -284,6 +287,7 @@ def tron_finalize(
         value=state.f,
         grad_norm=jnp.linalg.norm(state.g),
         iterations=state.it,
+        evaluations=state.evals,
         reason=reason,
         value_history=state.history,
         w_history=state.w_hist if config.track_coefficients else None,

@@ -320,7 +320,11 @@ def solve_streaming(
     )
     programs = StreamPrograms.for_objective(objective)
 
+    evaluations = 0
+
     def _pass(w_at):
+        nonlocal evaluations
+        evaluations += 1
         if pass_fn is not None:
             info.passes += 1
             return pass_fn(w_at, l2)
@@ -384,6 +388,7 @@ def solve_streaming(
         value=f,
         grad_norm=g_norm,
         iterations=jnp.int32(it),
+        evaluations=jnp.int32(evaluations),
         reason=jnp.int32(reason.value),
         value_history=jnp.asarray(value_history),
     )

@@ -24,6 +24,7 @@ from photon_ml_tpu.telemetry.span import (
     enable_tracing,
     get_tracer,
     span,
+    union_seconds,
 )
 from photon_ml_tpu.telemetry.metrics import (
     MetricsRegistry,
@@ -74,6 +75,7 @@ __all__ = [
     "enable_tracing",
     "get_tracer",
     "span",
+    "union_seconds",
     "MetricsRegistry",
     "ScopedMetrics",
     "get_registry",

@@ -44,7 +44,10 @@ class TelemetryRun:
         )
         self._emitters: List[Any] = []
         self._finished = False
-        self._spans_flushed = 0
+        # ids of the spans already written: between two flushes the tracer
+        # may drop a span it kept earlier (Tracer.add_interval), so a count
+        # is no cursor. What has been written is sealed and never dropped.
+        self._flushed_ids: set = set()
         self._extra_trace_events: List[Dict[str, Any]] = []
         if self.ledger is not None:
             self.ledger.write("meta", phase="start", label=label)
@@ -78,15 +81,20 @@ class TelemetryRun:
         has flushed and ``finish`` continues from there."""
         if self.ledger is None or self._finished:
             return
-        spans = self.tracer.spans()
-        for rec in spans[self._spans_flushed:]:
-            self.ledger.write_span(rec, self.tracer.origin_unix)
-        self._spans_flushed = len(spans)
+        # the ledger is append-only: what it is handed stays in the tracer
+        spans = self.tracer.spans(seal=True)
+        self._write_unflushed(spans)
         self.ledger.write(
             "meta", phase="checkpoint", label=label or self.label,
-            num_spans=self._spans_flushed,
+            num_spans=len(self._flushed_ids),
         )
         self.ledger.flush()
+
+    def _write_unflushed(self, spans) -> None:
+        for rec in spans:
+            if rec.span_id not in self._flushed_ids:
+                self.ledger.write_span(rec, self.tracer.origin_unix)
+                self._flushed_ids.add(rec.span_id)
 
     def finish(self, extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
         """Drain spans into the sinks; returns the summary dict. Safe to
@@ -95,7 +103,7 @@ class TelemetryRun:
             return self._summary
         self._finished = True
         _metrics.record_memory_watermarks(self.registry)
-        spans = self.tracer.spans()
+        spans = self.tracer.spans(seal=self.ledger is not None)
         metrics_snapshot = self.registry.snapshot()
         listener_errors = self.listener_errors()
         summary: Dict[str, Any] = {
@@ -119,9 +127,7 @@ class TelemetryRun:
             )
             _log.info("wrote chrome trace (%d events) to %s", n, self.trace_path)
         if self.ledger is not None:
-            for rec in spans[self._spans_flushed:]:
-                self.ledger.write_span(rec, self.tracer.origin_unix)
-            self._spans_flushed = len(spans)
+            self._write_unflushed(spans)
             self.ledger.write("metrics", snapshot=metrics_snapshot)
             self.ledger.write(
                 "meta",

@@ -16,11 +16,19 @@ Optionally a span can request *device-sync* timing: the enter/exit clock
 reads are preceded by a barrier that drains the async XLA dispatch queue,
 so the measured wall time covers device work issued inside the block
 instead of just the Python time spent enqueueing it.
+
+While the tracer is on, a live span also sits inside a
+``jax.profiler.TraceAnnotation`` named by its path: when a profiler session
+is active the program's spans lie in the trace's host plane, on the device
+operations' timeline. Finished intervals measured elsewhere (JAX's compile
+phases, ``telemetry/compile_spans.py``) enter through
+:meth:`Tracer.add_interval`.
 """
 from __future__ import annotations
 
 import contextvars
 import dataclasses
+import functools
 import itertools
 import threading
 import time
@@ -32,6 +40,7 @@ __all__ = [
     "get_tracer",
     "span",
     "timed_span",
+    "union_seconds",
     "enable_tracing",
     "disable_tracing",
 ]
@@ -80,15 +89,30 @@ _CURRENT: contextvars.ContextVar[Optional["_LiveSpan"]] = contextvars.ContextVar
 )
 
 
-def _device_barrier() -> None:
-    """Best-effort barrier: block until previously dispatched device work
-    (on the default backend) has retired. Used for device-sync spans."""
-    try:  # pragma: no cover - exercised only with jax present (always here)
-        import jax
+@functools.lru_cache(maxsize=1)
+def _barrier_program():
+    """(a trivial jitted program, its operand on the default device),
+    compiled here so that no span ever holds its compile."""
+    import jax
+    import jax.numpy as jnp
 
-        jax.block_until_ready(jax.device_put(0.0))
-    except Exception:
-        pass
+    program = jax.jit(lambda x: x + 1)
+    operand = jnp.zeros((), jnp.float32)
+    jax.block_until_ready(program(operand))
+    return program, operand
+
+
+def _device_barrier() -> None:
+    """Block until the work dispatched so far to the default device has
+    retired. The device's compute stream runs programs in order, so a
+    trivial program dispatched now ends after them. (A host-to-device copy
+    does not wait for the compute stream: ``device_put(0.0)`` returned 1-6 ms
+    after a 496 ms program was dispatched, this barrier after 495.9 ms; chip,
+    PR 26, log 1. ``chip_smoke.py``'s engine phase repeats the check.)"""
+    import jax
+
+    program, operand = _barrier_program()
+    jax.block_until_ready(program(operand))
 
 
 class _LiveSpan:
@@ -106,6 +130,7 @@ class _LiveSpan:
         "_token",
         "_start",
         "_sync",
+        "_annotation",
     )
 
     def __init__(self, tracer: "Tracer", name: str, sync: bool, attrs: Dict[str, Any]):
@@ -122,6 +147,7 @@ class _LiveSpan:
         self.error: Optional[str] = None
         self._token: Optional[contextvars.Token] = None
         self._start = 0.0
+        self._annotation = None
 
     def set_attrs(self, **attrs) -> "_LiveSpan":
         self.attrs.update(attrs)
@@ -134,6 +160,11 @@ class _LiveSpan:
             self.path = f"{parent.path}/{self.name}"
             self.depth = parent.depth + 1
         self._token = _CURRENT.set(self)
+        if self._tracer.enabled:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation(self.path)
+            self._annotation.__enter__()
         if self._sync:
             _device_barrier()
         self._start = time.perf_counter()
@@ -143,6 +174,8 @@ class _LiveSpan:
         if self._sync:
             _device_barrier()
         end = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if self._token is not None:
             _CURRENT.reset(self._token)
         self.duration_s = end - self._start
@@ -170,6 +203,18 @@ class _LiveSpan:
         return False  # never swallow exceptions
 
 
+def union_seconds(intervals) -> float:
+    """Seconds covered by ``(start, end)`` pairs that may nest and overlap:
+    the time they hold, which their sum is not."""
+    total, reach = 0.0, None
+    for a, b in sorted(intervals):
+        if reach is None or a > reach:
+            total, reach = total + (b - a), b
+        elif b > reach:
+            total, reach = total + (b - reach), b
+    return total
+
+
 class Tracer:
     """Thread-safe collector of finished spans.
 
@@ -183,7 +228,11 @@ class Tracer:
         self.enabled = enabled
         self.device_sync = device_sync
         self._lock = threading.Lock()
-        self._spans: List[SpanRecord] = []
+        # by span id, in the order recorded: add_interval drops by id
+        self._spans: Dict[int, SpanRecord] = {}
+        # (thread id, name) -> that thread's kept absorbing intervals of that
+        # name, by start: what a later, enclosing one of the same name drops
+        self._kept: Dict[tuple, List[SpanRecord]] = {}
         self._ids = itertools.count(1)
         # Anchor for converting perf_counter readings to wall-clock time.
         self.origin_perf = time.perf_counter()
@@ -195,18 +244,60 @@ class Tracer:
             return NOOP_SPAN
         return _LiveSpan(self, name, device_sync and self.device_sync, attrs)
 
+    def add_interval(
+        self, name: str, start: float, end: float, absorb_nested: bool = False, **attrs
+    ) -> None:
+        """Record a finished interval measured elsewhere (``start``/``end``
+        are ``perf_counter`` readings) as a child of the calling thread's
+        live span. ``absorb_nested`` drops the same thread's earlier
+        absorbing intervals *of the same name* that lie wholly inside this
+        one. It is flood control and no more: JAX reports a trace of every
+        function traced inside another, and those leave one span. Intervals
+        of different names still nest (a kernel body traced inside a
+        lowering, a compile inside a trace) and threads overlap, so whoever
+        wants a time takes :func:`union_seconds` of what it reads."""
+        if not self.enabled:
+            return
+        parent = _CURRENT.get()
+        thread = threading.current_thread()
+        record = SpanRecord(
+            span_id=next(self._ids),
+            parent_id=None if parent is None else parent.span_id,
+            name=name,
+            path=name if parent is None else f"{parent.path}/{name}",
+            depth=1 if parent is None else parent.depth + 1,
+            start_s=start - self.origin_perf,
+            duration_s=end - start,
+            thread_id=thread.ident or 0,
+            thread_name=thread.name,
+            attrs=attrs,
+        )
+        with self._lock:
+            if absorb_nested:
+                kept = self._kept.setdefault((record.thread_id, name), [])
+                while kept and kept[-1].start_s >= record.start_s:
+                    self._spans.pop(kept.pop().span_id, None)
+                kept.append(record)
+            self._spans[record.span_id] = record
+
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
+            self._kept.clear()
 
     # ------------------------------------------------------------- access
     def _record(self, record: SpanRecord) -> None:
         with self._lock:
-            self._spans.append(record)
+            self._spans[record.span_id] = record
 
-    def spans(self) -> List[SpanRecord]:
+    def spans(self, seal: bool = False) -> List[SpanRecord]:
+        """The spans recorded so far. ``seal`` makes them final: no later
+        interval drops one of them (for a sink that writes them where they
+        cannot be taken back)."""
         with self._lock:
-            return list(self._spans)
+            if seal:
+                self._kept.clear()
+            return list(self._spans.values())
 
     def __len__(self) -> int:
         with self._lock:
@@ -239,7 +330,14 @@ def timed_span(name: str, **attrs) -> _LiveSpan:
 
 
 def enable_tracing(device_sync: bool = True, clear: bool = True) -> Tracer:
-    """Turn on the global tracer (optionally clearing prior spans)."""
+    """Turn on the global tracer (optionally clearing prior spans). The
+    first call also registers the listeners that turn JAX's compile phases
+    into spans (``compile_spans``)."""
+    from photon_ml_tpu.telemetry import compile_spans
+
+    compile_spans.register()
+    if device_sync:
+        _barrier_program()
     if clear:
         _TRACER.clear()
     _TRACER.device_sync = device_sync

@@ -214,6 +214,7 @@ class TestTrackers:
                 value=jnp.ones(e),
                 grad_norm=jnp.zeros(e),
                 iterations=jnp.asarray(iters, jnp.int32),
+                evaluations=jnp.asarray(iters, jnp.int32) + 1,
                 reason=jnp.asarray(reasons, jnp.int32),
                 value_history=jnp.zeros((e, 3)),
             )

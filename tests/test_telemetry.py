@@ -6,6 +6,7 @@ as the telemetry smoke gate)."""
 
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -30,6 +31,7 @@ from photon_ml_tpu.telemetry.span import (
     enable_tracing,
     span,
     timed_span,
+    union_seconds,
 )
 
 
@@ -520,6 +522,292 @@ class TestRegistryLifecycle:
             validate_ledger(str(path))
 
 
+class TestCompileSpans:
+    """JAX's compile phases as spans under the work that caused them
+    (telemetry/compile_spans.py), and Tracer.add_interval behind them."""
+
+    @staticmethod
+    def _compile_records(tracer, fun="<lambda>"):
+        return [
+            r for r in tracer.spans()
+            if r.name.startswith("jit/") and fun in r.attrs.get("fun_name", "")
+        ]
+
+    @pytest.mark.parametrize("phase", ["trace", "lower", "backend"])
+    def test_fresh_jit_leaves_phase_span_under_open_span(self, tracer, phase):
+        import jax
+        import jax.numpy as jnp
+
+        x = jnp.ones(3)
+        with span("outer") as outer:
+            jax.jit(lambda v: jnp.sin(v) * 2 + jnp.cos(v))(x).block_until_ready()
+        recs = [r for r in self._compile_records(tracer) if r.name == f"jit/{phase}"]
+        assert len(recs) == 1
+        rec = recs[0]
+        assert rec.attrs["under"] == "outer"
+        assert rec.attrs["phase"] == phase
+        assert rec.attrs["fun_name"]
+        assert rec.parent_id == outer.span_id
+        assert rec.path == f"outer/jit/{phase}"
+        assert rec.depth == 2
+        assert rec.duration_s > 0
+        # on the tracer's clock, inside the span that was open
+        out = [r for r in tracer.spans() if r.name == "outer"][0]
+        assert out.start_s <= rec.start_s + 1e-3
+        assert rec.start_s + rec.duration_s <= out.start_s + out.duration_s + 1e-3
+
+    def test_nested_trace_events_leave_one_span(self, tracer):
+        """A jit traced inside another, and every jnp function called during
+        a trace, reports its own trace event; only the outermost is kept."""
+        import jax
+        import jax.numpy as jnp
+
+        inner = jax.jit(lambda v: jnp.sin(v) * 3)
+        x = jnp.ones(4)
+        with span("outer"):
+            jax.jit(lambda v: inner(v) + inner(2 * v))(x).block_until_ready()
+        traces = [r for r in tracer.spans()
+                  if r.name == "jit/trace" and r.attrs["under"] == "outer"]
+        assert [r.attrs["fun_name"] for r in traces] == ["<lambda>"]
+        # of one phase no two spans of the thread overlap (flood control);
+        # phases may nest in each other, so a time is a union
+        for phase in ("jit/trace", "jit/lower", "jit/backend"):
+            recs = sorted(
+                (r for r in tracer.spans() if r.name == phase),
+                key=lambda r: r.start_s,
+            )
+            for a, b in zip(recs, recs[1:]):
+                assert a.start_s + a.duration_s <= b.start_s + 1e-6
+        out = [r for r in tracer.spans() if r.name == "outer"][0]
+        compiles = [
+            (r.start_s, r.start_s + r.duration_s)
+            for r in tracer.spans()
+            if r.name.startswith("jit/") and r.attrs["under"] == "outer"
+        ]
+        assert 0 < union_seconds(compiles) <= out.duration_s + 1e-3
+
+    def test_trace_inside_a_lowering_is_kept(self, tracer):
+        """What JAX traces while it lowers (a kernel body, a custom rule)
+        reports after the lowering began and before it ends: the lowering
+        must not swallow it, or nobody can tell Python tracing from the
+        lowering proper."""
+        import jax.monitoring
+
+        trace = "/jax/core/compile/jaxpr_trace_duration"
+        lower = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+        with span("outer"):
+            time.sleep(0.02)
+            jax.monitoring.record_event_duration_secs(trace, 0.004, fun_name="body")
+            jax.monitoring.record_event_duration_secs(trace, 0.012, fun_name="kernel")
+            jax.monitoring.record_event_duration_secs(lower, 0.018, fun_name="solve")
+        recs = [(r.name, r.attrs["fun_name"]) for r in tracer.spans() if r.name != "outer"]
+        assert recs == [("jit/trace", "kernel"), ("jit/lower", "solve")]
+        spans = {r.attrs["fun_name"]: r for r in tracer.spans() if r.name != "outer"}
+        assert spans["solve"].start_s < spans["kernel"].start_s
+        both = [(r.start_s, r.start_s + r.duration_s) for r in spans.values()]
+        assert union_seconds(both) == pytest.approx(spans["solve"].duration_s, abs=2e-3)
+
+    @pytest.mark.parametrize(
+        "intervals, seconds",
+        [
+            ([], 0.0),
+            ([(1.0, 2.0)], 1.0),
+            ([(1.0, 2.0), (3.0, 3.5)], 1.5),                 # disjoint
+            ([(3.0, 3.5), (1.0, 4.0), (1.5, 2.0)], 3.0),     # nested, unsorted
+            ([(1.0, 2.0), (1.5, 3.0), (3.0, 4.0)], 3.0),     # overlapping, touching
+        ],
+    )
+    def test_union_seconds(self, intervals, seconds):
+        assert union_seconds(intervals) == pytest.approx(seconds)
+
+    @pytest.mark.parametrize("hit", [True, False])
+    def test_cache_event_becomes_span_and_counter(self, tracer, hit):
+        import jax.monitoring
+
+        kind = "hits" if hit else "misses"
+        with span("outer"):
+            jax.monitoring.record_event(f"/jax/compilation_cache/cache_{kind}")
+        recs = [r for r in tracer.spans() if r.name == "jit/cache"]
+        assert len(recs) == 1
+        assert recs[0].attrs == {"hit": hit, "under": "outer"}
+        assert recs[0].duration_s == 0.0
+        assert get_registry().counter_value(f"jit.cache.{kind}") == 1
+
+    def test_listeners_record_nothing_when_off(self, tracer):
+        import jax
+        import jax.monitoring
+        import jax.numpy as jnp
+
+        disable_tracing()
+        tracer.clear()
+        jax.jit(lambda v: v * 5 - 1)(jnp.ones(2)).block_until_ready()
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+        assert len(tracer) == 0
+        assert get_registry().counter_value("jit.cache.misses") == 0
+
+    def test_add_interval_absorbs_nested_of_its_name_and_thread_only(self):
+        from photon_ml_tpu.telemetry.span import Tracer
+
+        t = Tracer(enabled=True)
+        base = t.origin_perf
+        t.add_interval("x", base + 1.0, base + 2.0, absorb_nested=True, tag="first")
+        t.add_interval("x", base + 3.0, base + 3.2, absorb_nested=True, tag="a")
+        t.add_interval("mark", base + 3.1, base + 3.1, tag="kept")  # not absorbing
+        t.add_interval("y", base + 3.25, base + 3.3, absorb_nested=True, tag="other name")
+        t.add_interval("x", base + 3.3, base + 3.9, absorb_nested=True, tag="b")
+        t.add_interval("x", base + 2.5, base + 4.0, absorb_nested=True, tag="outer")
+        assert [(r.name, r.attrs["tag"]) for r in t.spans()] == [
+            ("x", "first"), ("mark", "kept"), ("y", "other name"), ("x", "outer"),
+        ]
+        got = t.spans()[-1]
+        assert got.start_s == pytest.approx(2.5)
+        assert got.duration_s == pytest.approx(1.5)
+        assert got.parent_id is None and got.path == "x"
+
+        other = threading.Thread(
+            target=lambda: t.add_interval("x", base + 0.0, base + 9.0, absorb_nested=True, tag="t2")
+        )
+        other.start()
+        other.join(timeout=10)
+        assert not other.is_alive()
+        assert len(t) == 5  # another thread's enclosing interval absorbs nothing here
+        t.enabled = False
+        t.add_interval("x", base + 5.0, base + 6.0)
+        assert len(t) == 5
+
+    def test_spans_written_to_the_ledger_are_never_dropped(self, tmp_path):
+        """A checkpoint seals what it wrote: an enclosing compile event that
+        ends later leaves the written span in the tracer too, so ledger and
+        tracer hold the same spans."""
+        import jax.monitoring
+
+        from photon_ml_tpu.telemetry import start_run
+
+        trace = "/jax/core/compile/jaxpr_trace_duration"
+        get_registry().reset()
+        path = tmp_path / "ledger.jsonl"
+        run = start_run("sealed", ledger_path=str(path), device_sync=False)
+        try:
+            time.sleep(0.03)
+            jax.monitoring.record_event_duration_secs(trace, 0.002, fun_name="dropped")
+            jax.monitoring.record_event_duration_secs(trace, 0.004, fun_name="inner")
+            run.checkpoint("mid-trace")
+            jax.monitoring.record_event_duration_secs(trace, 0.02, fun_name="outer")
+            in_tracer = sorted(r.attrs["fun_name"] for r in run.tracer.spans())
+            run.finish()
+        finally:
+            disable_tracing()
+        in_ledger = sorted(
+            r["attrs"]["fun_name"] for r in validate_ledger(str(path)) if r["type"] == "span"
+        )
+        assert in_tracer == in_ledger == ["inner", "outer"]
+
+
+def _tiny_glmix(seed=0, n_users=18, rows_per_user=12, d_fe=6, d_re=3):
+    from photon_ml_tpu.data.game_data import FeatureShard, GameData
+
+    rng = np.random.default_rng(seed)
+    n = n_users * rows_per_user
+    Xg = rng.normal(size=(n, d_fe)).astype(np.float32)
+    Xu = rng.normal(size=(n, d_re)).astype(np.float32)
+    users = np.repeat([f"u{i:03d}" for i in range(n_users)], rows_per_user)
+    z = Xg @ rng.normal(size=d_fe) + (Xu * rng.normal(size=(n_users, d_re))[
+        np.repeat(np.arange(n_users), rows_per_user)]).sum(-1)
+    y = (rng.random(n) < 1 / (1 + np.exp(-z))).astype(np.float32)
+
+    def coo(X):
+        rows, cols = np.nonzero(X)
+        return FeatureShard(rows=rows, cols=cols, vals=X[rows, cols], dim=X.shape[1])
+
+    return GameData(
+        labels=y,
+        feature_shards={"global": coo(Xg), "per_user": coo(Xu)},
+        id_tags={"userId": users},
+    )
+
+
+def _tiny_glmix_estimator():
+    from photon_ml_tpu.data.random_effect import RandomEffectDataConfiguration
+    from photon_ml_tpu.estimators.game import (
+        FixedEffectCoordinateConfiguration,
+        GameEstimator,
+        RandomEffectCoordinateConfiguration,
+    )
+    from photon_ml_tpu.types import TaskType
+
+    return GameEstimator(
+        task=TaskType.LOGISTIC_REGRESSION,
+        coordinates={
+            "fixed": FixedEffectCoordinateConfiguration("global"),
+            # 18 entities, over the adaptive driver's min_lanes of 8: rounds
+            "per_user": RandomEffectCoordinateConfiguration(
+                feature_shard="per_user",
+                data=RandomEffectDataConfiguration(random_effect_type="userId"),
+            ),
+        },
+        num_outer_iterations=1,
+    )
+
+
+class TestTrainingPathSpans:
+    """The step is covered by named spans end to end (ISSUE 26)."""
+
+    COVERING = ("game/prepare_fit", "cd/initial_scores", "cd/coordinate",
+                "cd/objective", "cd/validate", "glm/train")
+
+    @pytest.fixture(scope="class")
+    def traced_fits(self):
+        data, held_out = _tiny_glmix(0), _tiny_glmix(1, rows_per_user=4)
+        t = enable_tracing(device_sync=True, clear=True)
+        try:
+            fits = _tiny_glmix_estimator().fit_multiple(
+                data, validation_data=held_out, configs=[{}, {}], warm_start=True
+            )
+        finally:
+            disable_tracing()
+        return fits, t.spans()
+
+    @pytest.mark.parametrize("name", [
+        "game/prepare_fit", "cd/initial_scores", "cd/score", "glm/train",
+        "glm/solve", "re/round_wait",
+    ])
+    def test_span_is_recorded(self, traced_fits, name):
+        _, spans = traced_fits
+        assert any(s.name == name for s in spans)
+
+    def test_span_nesting_and_attrs(self, traced_fits):
+        _, spans = traced_fits
+        solves = [s for s in spans if s.name == "glm/solve"]
+        assert len(solves) == 2  # one fixed-effect solve a fit
+        for s in solves:
+            assert s.path.endswith("fe/solve/glm/train/glm/solve")
+            assert s.attrs["evaluations"] >= s.attrs["iterations"] + 1
+        trains = [s for s in spans if s.name == "glm/train"]
+        assert all(s.attrs == {"optimizer": "LBFGS", "weights": 1} for s in trains)
+        assert {s.attrs["coordinate"] for s in spans if s.name == "cd/score"} == {
+            "fixed", "per_user"}
+        assert all("re/adaptive_round" in s.path for s in spans if s.name == "re/round_wait")
+        # the second fit is warm-started: two models scored before its first update
+        assert sorted(s.attrs["coordinates"] for s in spans if s.name == "cd/initial_scores") == [0, 2]
+        # compile spans say which work caused them
+        under = {s.attrs["under"] for s in spans if s.name.startswith("jit/")}
+        assert any("glm/train" in u for u in under)
+        assert any("re/train" in u for u in under)
+
+    @pytest.mark.parametrize("fit_index", [0, 1])
+    def test_named_spans_cover_the_fit(self, traced_fits, fit_index):
+        _, spans = traced_fits
+        starts = sorted(s.start_s for s in spans if s.name == "game/prepare_fit")
+        ends = sorted(s.start_s + s.duration_s for s in spans if s.name == "game/fit")
+        lo, hi = starts[fit_index], ends[fit_index]
+        cut = [
+            (max(s.start_s, lo), min(s.start_s + s.duration_s, hi))
+            for s in spans if s.name in self.COVERING
+        ]
+        covered = union_seconds((a, b) for a, b in cut if b > a)
+        assert covered >= 0.95 * (hi - lo), (covered, hi - lo)
+
+
 @pytest.fixture(scope="module")
 def tiny_avro(tmp_path_factory):
     """Tiny GLMix logistic fixture (8 users) + a config whose RE coordinate
@@ -805,8 +1093,18 @@ class TestDriverTelemetrySmoke:
             model, _ = load_game_model(str(out / "best"))
             return model
 
+        # with tracing off nothing of the tracer runs: the compile listeners
+        # (registered by earlier traced runs in this process) record nothing
+        tracer = get_tracer()
+        tracer.clear()
+        cache_events = (get_registry().counter_value("jit.cache.hits"),
+                        get_registry().counter_value("jit.cache.misses"))
         plain = train("plain", telemetry=False)
+        assert len(tracer) == 0
+        assert cache_events == (get_registry().counter_value("jit.cache.hits"),
+                                get_registry().counter_value("jit.cache.misses"))
         traced = train("traced", telemetry=True)
+        assert any(s.name == "jit/trace" for s in tracer.spans())
         fixed_p = np.asarray(plain.models["fixed"].coefficients.means)
         fixed_t = np.asarray(traced.models["fixed"].coefficients.means)
         np.testing.assert_array_equal(fixed_p, fixed_t)

@@ -236,3 +236,50 @@ def test_variances_transformed_to_original_space(rng):
         fit_p.model.coefficients.variances
     )
     assert np.all(ratio > 0.2) and np.all(ratio < 5.0), ratio
+
+
+# -- evaluations counted where they happen (SolveResult.evaluations). The
+# solvers' own suite (test_optimizers.py) is the slow lane; these are fast.
+
+def _solve_by_name(name, objective, w0, data, l2, l1=0.0):
+    from photon_ml_tpu.opt import lbfgs_solve, owlqn_solve, tron_solve
+
+    if name == "owlqn":
+        return owlqn_solve(objective, w0, data, jnp.float32(l2), jnp.float32(l1))
+    solver = lbfgs_solve if name == "lbfgs" else tron_solve
+    return solver(objective, w0, data, jnp.float32(l2))
+
+
+@pytest.mark.parametrize("name", ["lbfgs", "owlqn", "tron"])
+def test_evaluations_at_least_iterations_plus_one(rng, name):
+    """Every iteration makes at least one value-and-gradient call, and the
+    initial point one more; line-search retries come on top."""
+    from photon_ml_tpu.losses import LogisticLoss, make_glm_objective
+
+    X, y = _logreg(rng, d=6, intercept=False)
+    data = LabeledData.create(DenseFeatures(matrix=jnp.asarray(X)), jnp.asarray(y))
+    res = _solve_by_name(
+        name, make_glm_objective(LogisticLoss), jnp.zeros(6), data, l2=1.0, l1=2.0
+    )
+    iterations, evaluations = int(res.iterations), int(res.evaluations)
+    assert res.evaluations.dtype == jnp.int32
+    assert iterations >= 2
+    assert evaluations >= iterations + 1
+    if name == "tron":
+        # one trial point an iteration, accepted or not
+        assert evaluations == iterations + 1
+
+
+@pytest.mark.parametrize("name", ["lbfgs", "owlqn", "tron"])
+def test_evaluations_equal_iterations_plus_one_when_first_step_accepted(name):
+    """0.5 * ||w - c||^2 with ||c|| = 1 from w = 0: the first trial step
+    (t = 1 / ||g||) lands on the optimum, so the solve is the initial
+    evaluation plus one per iteration and nothing else."""
+    from photon_ml_tpu.losses import SquaredLoss, make_glm_objective
+
+    c = jnp.asarray([0.5, -0.5, 0.5, -0.5, 0.0, 0.0], dtype=jnp.float32)
+    data = LabeledData.create(DenseFeatures(matrix=jnp.eye(6, dtype=jnp.float32)), c)
+    res = _solve_by_name(name, make_glm_objective(SquaredLoss), jnp.zeros(6), data, l2=0.0)
+    np.testing.assert_allclose(np.asarray(res.w), np.asarray(c), atol=1e-6)
+    assert int(res.iterations) == 1
+    assert int(res.evaluations) == int(res.iterations) + 1
