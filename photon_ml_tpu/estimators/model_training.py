@@ -6,15 +6,21 @@ Reference parity: ModelTraining.trainGeneralizedLinearModel
 (:160-206). Optional per-coefficient variances from the inverse Hessian
 diagonal (DistributedOptimizationProblem.scala:80-94).
 
-TPU notes: the solver program is compiled once (λ is a traced scalar); when
-``data`` is sharded over a mesh's batch axis the same code runs data-parallel
-with XLA-inserted psums — there is no separate "distributed trainer".
+TPU notes: the solver program is built once per (loss, optimizer settings)
+and kept while later calls ask for the same one, specialized by ``jax.jit``
+per argument shape: λ, the data, the start and a per-feature box are
+arguments, so a sweep, a warm start, replaced offsets and a later
+``train_glm`` call with equal optimizer settings all dispatch the program the
+first call compiled. When ``data`` is sharded over a mesh's batch axis the
+same code runs data-parallel with XLA-inserted psums — there is no separate
+"distributed trainer".
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence
+import functools
+from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -23,10 +29,11 @@ from photon_ml_tpu.losses.objective import GlmObjective, make_glm_objective
 from photon_ml_tpu.losses.pointwise import loss_for_task
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.models.glm import GeneralizedLinearModel
-from photon_ml_tpu.opt.config import GlmOptimizationConfiguration
-from photon_ml_tpu.opt.solve import solve
+from photon_ml_tpu.opt.config import GlmOptimizationConfiguration, OptimizerConfig
+from photon_ml_tpu.opt.solve import solve, solver_kind
 from photon_ml_tpu.opt.state import SolveResult
 from photon_ml_tpu.ops.data import LabeledData
+from photon_ml_tpu.telemetry import note_jit_trace
 from photon_ml_tpu.telemetry.span import get_tracer, span
 from photon_ml_tpu.types import TaskType
 
@@ -59,6 +66,47 @@ def block_on_fit(fit: GlmFit) -> GlmFit:
     return fit
 
 
+# How many static keys' solve programs the process keeps. One: a kept program
+# stays loaded on the device with the memory the runtime reserves for its
+# temporaries (twice the L-BFGS history: 6.5 GB of a v5e's 16 in the
+# benchmark's cells), so a second key's program may not find room beside the
+# first's. The last key is what a CD run, a λ sweep and a loop over fits
+# repeat; a caller that alternates keys rebuilds, as every call did before.
+_MAX_SOLVE_PROGRAMS = 1
+
+
+@functools.lru_cache(maxsize=_MAX_SOLVE_PROGRAMS)
+def _solve_program(
+    objective: GlmObjective, optimizer_config: OptimizerConfig, use_l1: bool
+) -> Callable[..., SolveResult]:
+    """The jitted ``(w0, data, l2, l1, box) -> SolveResult`` of one static
+    key. ``jax.jit`` keys its caches on the function object, so the program
+    has to be the same object at every ``train_glm`` call for the second call
+    to dispatch what the first traced, lowered and loaded; everything that
+    varies between calls is an argument, the regularization weights among
+    them, so the key holds the optimizer's settings and nothing else of the
+    configuration. ``box`` is ``None`` or a (lower, upper) pair of
+    per-coefficient arrays. A plain jitted callable, never an ahead-of-time
+    ``Compiled``: ``jax.clear_caches()`` still frees the program, and the next
+    call builds it again."""
+    configuration = GlmOptimizationConfiguration(optimizer_config=optimizer_config)
+    kind = solver_kind(configuration, 1.0 if use_l1 else 0.0)
+
+    def glm_solve(w0, data, l2, l1, box):
+        note_jit_trace("glm_solve", kind)  # fires only on a (re)trace
+        return solve(
+            objective,
+            w0,
+            data,
+            configuration,
+            l2_weight=l2,
+            l1_weight=l1 if use_l1 else 0.0,
+            box=box,
+        )
+
+    return jax.jit(glm_solve)
+
+
 def train_glm(
     data: LabeledData,
     task: TaskType,
@@ -83,7 +131,6 @@ def train_glm(
         optimizer=configuration.optimizer_config.optimizer.name,
         weights=1 if regularization_weights is None else len(regularization_weights),
     ):
-        objective = make_glm_objective(loss_for_task(task))
         if regularization_weights is None:
             regularization_weights = [configuration.regularization_weight]
         if track_models:
@@ -134,17 +181,16 @@ def train_glm(
                 lo = jnp.asarray(lo) / factor
                 hi = jnp.asarray(hi) / factor
             box_constraints = (lo, hi)
-        solver = jax.jit(
-            lambda w0, dd, l2, l1: solve(
-                objective,
-                w0,
-                dd,
-                configuration,
-                l2_weight=l2,
-                l1_weight=l1 if use_l1 else 0.0,
-                box=box_constraints,
+        if box_constraints is not None:
+            # uploaded once for the whole sweep, not at every solve
+            box_constraints = tuple(
+                None if b is None else jnp.asarray(b)
+                for b in box_constraints
             )
-        )
+        objective = make_glm_objective(loss_for_task(task))
+        solver = _solve_program(objective, configuration.optimizer_config, use_l1)
+        # the objective's functions are the same objects at every call, so
+        # this wrapper finds the program an earlier call's wrapper compiled
         hess_diag = jax.jit(objective.hessian_diag) if compute_variances else None
 
         # high -> low so each warm start begins from a smoother problem
@@ -155,7 +201,7 @@ def train_glm(
             l2 = jnp.float32(reg.l2_weight(lam))
             l1 = jnp.float32(reg.l1_weight(lam))
             with span("glm/solve", regularization_weight=float(lam)) as solving:
-                result = solver(w, data, l2, l1)
+                result = solver(w, data, l2, l1, box_constraints)
                 if get_tracer().enabled:
                     # a traced run waits for the solve here, so that the span
                     # holds the device's work and can say what it counted

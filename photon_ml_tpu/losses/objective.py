@@ -22,6 +22,7 @@ DistributedOptimizationProblem.scala:60-71).
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple, Type
 
 import jax
@@ -58,7 +59,22 @@ def make_glm_objective(
 ) -> GlmObjective:
     """``use_pallas``: route eligible dense problems through the fused
     pallas kernel; None (default) defers to the PHOTON_ML_TPU_PALLAS flag
-    (ops/pallas_kernels.enabled), read once at objective construction."""
+    (ops/pallas_kernels.enabled), read at every call.
+
+    One objective per (loss, resolved flag) for the life of the process: the
+    jitted programs built over it key on the identity of its functions, so
+    equal calls have to return the same closures for a program to be reused
+    (estimators/model_training.py, streaming/solver.py)."""
+    if use_pallas is None:
+        from photon_ml_tpu.ops import pallas_kernels
+
+        use_pallas = pallas_kernels.enabled()
+    return _glm_objective(loss, bool(use_pallas))
+
+
+# unbounded: one entry per loss class and flag
+@functools.lru_cache(maxsize=None)
+def _glm_objective(loss: Type[PointwiseLoss], use_pallas: bool) -> GlmObjective:
     def margins(w: jax.Array, data: LabeledData) -> jax.Array:
         norm = _norm_of(data)
         ew = norm.effective_coefficients(w)
@@ -73,11 +89,6 @@ def make_glm_objective(
         z = margins(w, data)
         loss_sum = jnp.sum(_wmask(data.weights, loss.value(z, data.labels)))
         return loss_sum + 0.5 * l2 * jnp.dot(w, w)
-
-    if use_pallas is None:
-        from photon_ml_tpu.ops import pallas_kernels
-
-        use_pallas = pallas_kernels.enabled()
 
     def value_and_grad(
         w: jax.Array, data: LabeledData, l2: jax.Array

@@ -40,15 +40,13 @@ from typing import List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ...losses.objective import make_glm_objective
+from ...losses.pointwise import loss_for_task
 from ...resilience.faultpoints import FatalInjectedFault, fault_point, register_fault_site
 from ...telemetry.metrics import get_registry
 from ...telemetry.span import span
 from ...streaming.blocks import StreamingSource
-from ...streaming.coordinate import (
-    _fuse_block_offsets,
-    _objective_for_task,
-    _pad_residual,
-)
+from ...streaming.coordinate import _fuse_block_offsets, _pad_residual
 from ...streaming.prefetch import BlockPrefetcher
 from ...streaming.solver import StreamPrograms
 from ...types import TaskType
@@ -83,7 +81,7 @@ class ClusterWorker:
         self.host_id = int(host_id)
         self.source = source
         self.shard_id = shard_id
-        self.objective = _objective_for_task(task)
+        self.objective = make_glm_objective(loss_for_task(task))
         self.programs = StreamPrograms.for_objective(self.objective)
         self.prefetch_depth = int(prefetch_depth)
         if block_latency_s is None:

@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,20 +55,6 @@ from photon_ml_tpu.streaming.solver import (
 )
 from photon_ml_tpu.telemetry import span
 from photon_ml_tpu.types import TaskType
-
-
-# make_glm_objective builds fresh closures per call; the streamed-solver
-# program caches key on objective identity, so same-task coordinates must
-# share one instance or every new estimator would retrace the solver suite
-_OBJECTIVE_CACHE: Dict[TaskType, GlmObjective] = {}
-
-
-def _objective_for_task(task: TaskType) -> GlmObjective:
-    obj = _OBJECTIVE_CACHE.get(task)
-    if obj is None:
-        obj = make_glm_objective(loss_for_task(task))
-        _OBJECTIVE_CACHE[task] = obj
-    return obj
 
 
 @partial(jax.jit, static_argnames=("padded",))
@@ -195,9 +181,6 @@ class StreamingFixedEffectCoordinate(Coordinate):
     _gap_scheduler: Optional[GapScheduler] = dataclasses.field(
         default=None, repr=False
     )
-    _objective: Optional[GlmObjective] = dataclasses.field(
-        default=None, repr=False
-    )
 
     supports_device_plane = True
 
@@ -256,9 +239,9 @@ class StreamingFixedEffectCoordinate(Coordinate):
         return self.source.plan.total_rows
 
     def objective(self) -> GlmObjective:
-        if self._objective is None:
-            self._objective = _objective_for_task(self.task)
-        return self._objective
+        # one instance per task (make_glm_objective keeps it): the
+        # streamed-solver programs key on the objective's identity
+        return make_glm_objective(loss_for_task(self.task))
 
     # -- streamed passes --------------------------------------------------
 

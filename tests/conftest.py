@@ -36,3 +36,15 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(seed=42)
+
+
+@pytest.fixture
+def interpret_kernels():
+    """The fused routed-map kernels through the Pallas interpreter (the
+    engine's test hook, read when a program is traced)."""
+    from photon_ml_tpu.ops import fused_perm
+
+    old = fused_perm._INTERPRET
+    fused_perm._INTERPRET = True
+    yield
+    fused_perm._INTERPRET = old

@@ -28,14 +28,6 @@ from photon_ml_tpu.ops.fused_perm import (
 )
 
 
-@pytest.fixture
-def interpret_kernels():
-    old = fused_perm._INTERPRET
-    fused_perm._INTERPRET = True
-    yield
-    fused_perm._INTERPRET = old
-
-
 def _random_coo(rng, n, d, nnz):
     rows = rng.integers(0, n, nnz)
     cols = rng.integers(0, d, nnz)
