@@ -378,7 +378,7 @@ def phase_engine(run: Run) -> None:
         f"{len(parse_plan(first.plan).descents)} recursion level(s), spill "
         f"side {'yes' if first.spill_rows is not None else 'no'}"
     )
-    # stage-by-stage executor (csc_view, the benes engine): any plan here
+    # stage-by-stage executor (the benes engine): any plan here
     # short enough to drop to the XLA gather?
     xla_plans = sum(
         1 for b in routed if not permute_net._use_pallas(b.plan.size // 128)

@@ -209,6 +209,8 @@ def train_glm(
                     solving.set_attrs(
                         iterations=int(result.iterations),
                         evaluations=int(result.evaluations),
+                        hessian_vecs=int(result.hessian_vecs),
+                        rejected_steps=int(result.rejected_steps),
                     )
             if warm_start:
                 w = result.w

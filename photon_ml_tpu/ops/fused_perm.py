@@ -491,8 +491,10 @@ def fused_execute(
     """Run a full permutation plan with fused prologue/epilogue.
 
     pro: Broadcast | MulBroadcast — builds the [S]-layout network input.
-    epi: MulReduce | Reduce — reduces the permuted output to a vector.
-    Returns the epilogue's [S // epi.group] vector.
+    epi: MulReduce | Reduce — reduces the permuted output to a vector; None
+    leaves it as it is.
+    Returns the epilogue's [S // epi.group] vector, or the permuted [S]
+    values as [S // 128, 128].
 
     ``payload_dtype=bfloat16`` stores the permuted intermediates half-size
     (one rounding at network entry; permutes are exact; reductions
@@ -534,6 +536,8 @@ def unfused_execute(dplan: DevicePlan, pro, epi, payload_dtype=jnp.float32) -> j
         x = vals * jnp.repeat(pro.vec, pro.group, total_repeat_length=S)
     x = x.astype(payload_dtype)
     y = apply_plan(dplan, x).astype(jnp.float32)
+    if epi is None:
+        return y
     if isinstance(epi, MulReduce):
         y = y * epi.values
     return y.reshape(-1, epi.group).sum(axis=1)
@@ -637,18 +641,17 @@ class FusedBenesFeatures:
             g = g.at[self.spill_cols].add(sv * c[self.spill_rows])
         return g
 
-    def csc_view(self, flat_ell: jax.Array) -> jax.Array:
-        """Route an [S] ELL-slot array to the column-grouped side and return
-        it as [d, KP] (one row per column). Stats-path utility — executes
-        the plain stage-by-stage permutation, not the fused kernels."""
-        d, KP = self.num_cols_, self.csc_k
-        return apply_plan(self.plan, flat_ell)[: d * KP].reshape(d, KP)
-
-    def weights_to_slots(self, weights: jax.Array) -> jax.Array:
-        """Broadcast per-row weights [n] to ELL slot order [S]."""
-        S, K = self.size, self.ell_k
-        wp = jnp.zeros((S // K,), weights.dtype).at[: self.num_rows_].set(weights)
-        return jnp.repeat(wp, K, total_repeat_length=S)
+    def routed_values(self, row_scale: jax.Array) -> jax.Array:
+        """The stored values, each times its row's entry of ``row_scale``
+        [n], routed to the column-grouped side: column ``c``'s ``csc_k``
+        slots are entries ``[c*csc_k, (c+1)*csc_k)`` of the [S] result, pads
+        0. An rmatvec without its reduction: the stats path reads per-column
+        minima and maxima off it."""
+        cp = jnp.zeros((self.size // self.ell_k,), row_scale.dtype)
+        cp = cp.at[: self.num_rows_].set(row_scale)
+        return self._run(
+            self.plan, MulBroadcast(self.ell_flat, cp, self.ell_k), None
+        ).reshape(-1)
 
     def row_norms_sq(self) -> jax.Array:
         sq = (self.ell_flat * self.ell_flat).reshape(-1, self.ell_k).sum(axis=1)

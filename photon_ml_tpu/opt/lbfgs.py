@@ -325,6 +325,8 @@ def lbfgs_finalize(
         grad_norm=jnp.linalg.norm(state.g),
         iterations=state.it,
         evaluations=state.evals,
+        hessian_vecs=jnp.zeros_like(state.it),
+        rejected_steps=jnp.zeros_like(state.it),
         reason=reason,
         value_history=state.history,
         w_history=state.w_hist if config.track_coefficients else None,

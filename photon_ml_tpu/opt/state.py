@@ -38,6 +38,11 @@ class SolveResult:
     # when OptimizerConfig.track_coefficients (reference ModelTracker /
     # OptimizationStatesTracker keeps per-iteration coefficients)
     w_history: Optional[jax.Array] = None
+    # int32 objective.hessian_vec calls (TRON's CG steps, summed over the
+    # outer iterations) and int32 trust-region steps rejected, counted in
+    # the loop carry; 0 from L-BFGS and OWL-QN, which make neither
+    hessian_vecs: Optional[jax.Array] = None
+    rejected_steps: Optional[jax.Array] = None
 
     def converged(self) -> jax.Array:
         return self.reason != ConvergenceReason.NOT_CONVERGED.value

@@ -47,8 +47,9 @@ class _CgState(NamedTuple):
 def _truncated_cg(hess_vec, g, delta, max_cg: int, cg_tol: float):
     """Steihaug truncated CG: approximately solve H s = -g with ||s|| <= delta.
 
-    Returns (s, r) where r is the final residual -g - H s (used for the
-    predicted-reduction formula, reference TRON.scala:275-335).
+    Returns (s, r, iterations) where r is the final residual -g - H s (used
+    for the predicted-reduction formula, reference TRON.scala:275-335) and
+    ``iterations`` the int32 count of CG steps made: one ``hess_vec`` each.
     """
     r0 = -g
     stop_norm = cg_tol * jnp.linalg.norm(g)
@@ -97,7 +98,7 @@ def _truncated_cg(hess_vec, g, delta, max_cg: int, cg_tol: float):
         )
 
     out = jax.lax.while_loop(cond, body, init)
-    return out.s, out.r
+    return out.s, out.r, out.it
 
 
 class _TronState(NamedTuple):
@@ -111,7 +112,8 @@ class _TronState(NamedTuple):
     delta: jax.Array
     it: jax.Array
     evals: jax.Array      # int32 objective.value_and_grad calls so far
-    failures: jax.Array
+    hvs: jax.Array        # int32 objective.hessian_vec calls so far (the CG steps, summed)
+    failures: jax.Array   # int32 trust-region steps rejected so far
     reason: jax.Array
     history: jax.Array
     w_hist: jax.Array     # [max_iter+1, d] coefficients (or [0] when off)
@@ -151,6 +153,7 @@ def tron_init(
         delta=g0_norm,  # initial radius = ||g0|| (reference TRON.scala:112)
         it=jnp.int32(0),
         evals=jnp.int32(1),
+        hvs=jnp.int32(0),
         failures=jnp.int32(0),
         reason=jnp.where(
             g0_norm <= abs_g_tol,
@@ -187,7 +190,7 @@ def tron_chunk(
 
     def body(s: _TronState) -> _TronState:
         hv = lambda v: objective.hessian_vec(s.w, v, data, l2_weight)
-        step, resid = _truncated_cg(
+        step, resid, cg_steps = _truncated_cg(
             hv, s.g, s.delta, config.max_cg_iterations, config.cg_tolerance
         )
         w_try = s.w + step
@@ -258,6 +261,7 @@ def tron_chunk(
             delta=delta,
             it=it,
             evals=s.evals + 1,
+            hvs=s.hvs + cg_steps,
             failures=failures,
             reason=reason,
             history=s.history.at[it].set(f_new),
@@ -288,6 +292,8 @@ def tron_finalize(
         grad_norm=jnp.linalg.norm(state.g),
         iterations=state.it,
         evaluations=state.evals,
+        hessian_vecs=state.hvs,
+        rejected_steps=state.failures,
         reason=reason,
         value_history=state.history,
         w_history=state.w_hist if config.track_coefficients else None,

@@ -18,6 +18,7 @@ from flax import struct
 
 from photon_ml_tpu.ops.data import LabeledData
 from photon_ml_tpu.ops.features import DenseFeatures, EllFeatures
+from photon_ml_tpu.telemetry.span import span
 
 
 @struct.dataclass
@@ -105,29 +106,66 @@ def _benes_stats(feats, weights):
     return s1, s2, sabs, nnz, mn, mx, wsum
 
 
+def _grouped(op: str, flat, group: int):
+    """``op`` ("max" or "min") over each run of ``group`` adjacent entries of
+    ``flat``; ``group`` is a power of two and ``flat.size`` a multiple of 128.
+    Never through a ``[n, group]`` array: on a TPU a minor dimension of 2 is
+    padded to 128 lanes (2.56 GB for a block of 5M columns) and XLA took
+    minutes to compile a reduction over it, at every eager call (so did
+    ``jnp.repeat`` of the row weights to their 16 slots each): a
+    ``summarize`` over 8 routed blocks of 40M columns took 288 s on a v5e
+    (chip, PR 28). Here runs are folded along the lanes by rolls, and every
+    ``group``-th lane is kept."""
+    lanes = 128
+    if group >= lanes:
+        return getattr(flat.reshape(-1, group), op)(axis=1)
+    pairwise = jnp.maximum if op == "max" else jnp.minimum
+    x = flat.reshape(-1, lanes)
+    shift = 1
+    while shift < group:
+        x = pairwise(x, jnp.roll(x, -shift, axis=1))
+        shift *= 2
+    return x[:, ::group].reshape(-1)
+
+
+@jax.jit
 def _fused_stats(feats, weights):
     """Stats through the fused engine's transformed linear maps; min/max
-    route the live-masked values to the column-grouped side once (plain
-    permutation — stats run once, not per optimizer step)."""
+    route the live rows' values to the column-grouped side once (an rmatvec
+    without its reduction) and fold each column's slots there. One compiled
+    program per block shape: run eagerly, every kernel was compiled again at
+    each call, and each of the spill side's three scatters cost the TPU
+    compiler 7 s (it sorts the indices) for every new spill length."""
     wsum = jnp.sum(weights)
     s1 = feats.rmatvec(weights)
     s2 = feats.rmatvec_sq(weights)
     sabs = feats._rmatvec_impl(weights, transform="abs")
     nnz = feats._rmatvec_impl(weights, transform="nnz")
 
-    w_slots = feats.weights_to_slots(weights)
-    live = (feats.ell_flat != 0) & (w_slots > 0)
-    big = jnp.asarray(jnp.inf, feats.ell_flat.dtype)
-    mx = jnp.max(
-        feats.csc_view(jnp.where(live, feats.ell_flat, -big)), axis=1
-    )
-    mn = jnp.min(
-        feats.csc_view(jnp.where(live, feats.ell_flat, big)), axis=1
-    )
-    hot = feats.hot_matrix
-    mn, mx = _fold_hot_minmax(mn, mx, hot, feats.hot_cols, weights)
+    # the values of live rows on the column-grouped side; 0 marks a pad, a
+    # stored zero or a weight-0 row, none of which is an observed value
+    values = feats.routed_values((weights > 0).astype(feats.ell_flat.dtype))
+    big = jnp.asarray(jnp.inf, values.dtype)
+    d, kp = feats.dim, feats.csc_k
+    mx = _grouped("max", jnp.where(values != 0, values, -big), kp)[:d]
+    mn = _grouped("min", jnp.where(values != 0, values, big), kp)[:d]
+    mn, mx = _fold_hot_minmax(mn, mx, feats.hot_matrix, feats.hot_cols, weights)
     mn, mx = _fold_spill_minmax(mn, mx, feats, weights)
     return s1, s2, sabs, nnz, mn, mx, wsum
+
+
+def _pad_spill(feats, length: int):
+    """``feats`` with its spill side padded to ``length`` entries of value 0
+    (no-ops in every statistic): the blocks of one column split then have
+    the same shapes and share ``_fused_stats``'s one compiled program."""
+    if feats.spill_rows is None or feats.spill_rows.shape[0] == length:
+        return feats
+    pad = lambda a: jnp.pad(a, (0, length - a.shape[0]))  # noqa: E731
+    return feats.replace(
+        spill_rows=pad(feats.spill_rows),
+        spill_cols=pad(feats.spill_cols),
+        spill_vals=pad(feats.spill_vals),
+    )
 
 
 def _split_stats(feats, weights):
@@ -140,6 +178,11 @@ def _split_stats(feats, weights):
     )
 
     wsum = jnp.sum(weights)
+    longest_spill = max(
+        (b.spill_rows.shape[0] for b in feats.blocks
+         if isinstance(b, FusedBenesFeatures) and b.spill_rows is not None),
+        default=0,
+    )
     parts = []
     for blk in feats.blocks:
         if isinstance(blk, _ZeroColumnsBlock):
@@ -154,7 +197,7 @@ def _split_stats(feats, weights):
         elif isinstance(blk, BenesSparseFeatures):
             parts.append(_benes_stats(blk, weights))
         elif isinstance(blk, FusedBenesFeatures):
-            parts.append(_fused_stats(blk, weights))
+            parts.append(_fused_stats(_pad_spill(blk, longest_spill), weights))
         else:
             raise TypeError(f"unknown column block type {type(blk)!r}")
     d = feats.num_cols_
@@ -200,6 +243,15 @@ def _fold_hot_minmax(mn, mx, hot, hot_cols, weights):
 
 
 def summarize(data: LabeledData) -> BasicStatisticalSummary:
+    # device_sync: the pass is dispatched device work, so the span waits for it
+    with span(
+        "glm/summarize", device_sync=True,
+        columns=int(data.dim), engine=type(data.features).__name__,
+    ):
+        return _summarize(data)
+
+
+def _summarize(data: LabeledData) -> BasicStatisticalSummary:
     from photon_ml_tpu.ops.fused_perm import FusedBenesFeatures
     from photon_ml_tpu.ops.sparse_perm import (
         BenesSparseFeatures,

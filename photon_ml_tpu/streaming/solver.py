@@ -389,6 +389,8 @@ def solve_streaming(
         grad_norm=g_norm,
         iterations=jnp.int32(it),
         evaluations=jnp.int32(evaluations),
+        hessian_vecs=jnp.int32(0),
+        rejected_steps=jnp.int32(0),
         reason=jnp.int32(reason.value),
         value_history=jnp.asarray(value_history),
     )

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from photon_ml_tpu.losses.objective import make_glm_objective
-from photon_ml_tpu.losses.pointwise import LogisticLoss
+from photon_ml_tpu.losses.pointwise import LogisticLoss, SquaredLoss
+from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.ops import fused_perm, permute_net, sparse_perm
 from photon_ml_tpu.ops.data import LabeledData
 from photon_ml_tpu.ops.pallas_kernels import fused_value_grad_single
@@ -33,6 +34,7 @@ from photon_ml_tpu.opt.config import (
     OptimizerConfig,
 )
 from photon_ml_tpu.opt.solve import solve
+from photon_ml_tpu.stat.summary import summarize
 
 ENGINES = {"fused": fused_perm, "benes": sparse_perm}
 OPS = ("matvec", "rmatvec", "rmatvec_sq")
@@ -168,6 +170,34 @@ def test_lbfgs_solve_over_fused_features_compiles(v5e, on_tpu):
         v5e, jnp.zeros(feats.dim, jnp.float32), data,
     )
     assert kernels >= 6  # at least one matvec and one rmatvec
+
+
+def test_tron_solve_with_normalization_compiles(v5e, on_tpu):
+    """The benchmark's ``fe-linear-tron`` solve: Hessian-vector products
+    through the routed maps inside TRON's CG loop, a factor in the data."""
+    feats = _features("fused", "column_split")
+    norm = NormalizationContext(factor=jnp.ones(feats.dim, jnp.float32))
+    data = LabeledData.create(feats, jnp.zeros(feats.num_rows, jnp.float32), norm=norm)
+    objective = make_glm_objective(SquaredLoss)
+    cfg = GlmOptimizationConfiguration(
+        optimizer_config=OptimizerConfig.tron(), regularization_weight=1.0,
+    )
+    kernels = compile_for_tpu(
+        lambda w0, dd: solve(objective, w0, dd, cfg).hessian_vecs,
+        v5e, jnp.zeros(feats.dim, jnp.float32), data,
+    )
+    per_map = 3 * len(_routed_blocks(feats))
+    # the start's and a step's value-and-gradient, and a product's maps
+    assert kernels >= (2 + 2 + 2) * per_map
+
+
+@pytest.mark.parametrize("plan", ["two_level", "column_split"])
+def test_summarize_compiles(v5e, on_tpu, plan):
+    """The feature statistics a normalization is built from: the transformed
+    maps (abs, nnz, squares) and the column-grouped view for min and max."""
+    feats = _features("fused", plan)
+    data = LabeledData.create(feats, jnp.zeros(feats.num_rows, jnp.float32))
+    assert compile_for_tpu(summarize, v5e, data) > 0
 
 
 @pytest.mark.parametrize("batched", [False, True], ids=["plain", "vmapped"])
