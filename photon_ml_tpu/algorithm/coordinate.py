@@ -40,7 +40,7 @@ from photon_ml_tpu.opt.tracking import (
     RandomEffectOptimizationTracker,
 )
 from photon_ml_tpu.sampler import down_sampler_for
-from photon_ml_tpu.telemetry import span
+from photon_ml_tpu.telemetry import note_jit_trace, span
 from photon_ml_tpu.types import TaskType
 
 
@@ -86,6 +86,19 @@ def _fused_residual_offsets(base: jax.Array, residual: jax.Array) -> jax.Array:
     if residual.shape[0] < base.shape[0]:
         residual = jnp.pad(residual, (0, base.shape[0] - residual.shape[0]))
     return base + residual
+
+
+@jax.jit
+def _fe_score(features, w: jax.Array) -> jax.Array:
+    """``features.matvec(w)`` as one program, the same function object for
+    the life of the process: ``jax.jit`` keys its caches on the function, and
+    a routed engine's eager matvec makes a fresh Pallas wrapper per kernel
+    per call, which never hits them. The features are an argument (a pytree:
+    plans and values as leaves, sizes static), so every engine, coordinate
+    object and ``fit_multiple`` configuration of one tree structure and
+    shapes dispatches what the first call compiled."""
+    note_jit_trace("fe_score")  # fires only on a (re)trace
+    return features.matvec(w)
 
 
 @dataclasses.dataclass
@@ -238,7 +251,7 @@ class FixedEffectCoordinate(Coordinate):
         return w
 
     def score(self, model: GeneralizedLinearModel) -> np.ndarray:
-        scores = fetch_global(self.data.features.matvec(self._padded_w(model)))
+        scores = fetch_global(_fe_score(self.data.features, self._padded_w(model)))
         if self.num_real_rows is not None:
             scores = scores[: self.num_real_rows]
         return scores
@@ -246,7 +259,7 @@ class FixedEffectCoordinate(Coordinate):
     def score_device(self, model: GeneralizedLinearModel) -> jax.Array:
         """Device-plane ``score``: the matvec result never leaves the mesh;
         padded batch rows are sliced off on device."""
-        scores = self.data.features.matvec(self._padded_w(model))
+        scores = _fe_score(self.data.features, self._padded_w(model))
         if self.num_real_rows is not None:
             scores = scores[: self.num_real_rows]
         return scores

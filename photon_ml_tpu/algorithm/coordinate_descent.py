@@ -363,29 +363,31 @@ class CoordinateDescent:
             stats.record_h2d()
             return coord.score_device(model)
 
-        # initial scoring for warm-started models
-        with span("cd/initial_scores", coordinates=len(models)):
+        # initial scoring for warm-started models and the plane's running
+        # total of it, waited for here (as ``cd/coordinate`` waits for its
+        # update) so that no span's entry barrier holds their device time
+        with span("cd/initial_scores", device_sync=True, coordinates=len(models)):
             for cid, model in models.items():
                 scores[cid] = _score(cid, model)
 
-        # Both planes maintain a RUNNING total (the legacy driver re-summed
-        # all C coordinates TWICE per update — once for the residual, once
-        # for the objective; host_score_sums stays 0 now and the regression
-        # test pins that down). The two planes execute the same sequence of
-        # IEEE f32 elementwise adds/subs — np on host, XLA on device — so
-        # their residuals (and therefore the trained models) match bitwise.
-        if device:
-            apply_, residual_ = _plane_programs()
-            zeros = jnp.zeros(self.num_rows, dtype=jnp.float32)
-            # fresh buffer: ``apply_`` donates its first argument, and the
-            # shared ``zeros`` must outlive every first-update residual
-            total = jnp.zeros_like(zeros)
-            for s in scores.values():
-                total = total + s
-        else:
-            total_np = np.zeros(self.num_rows, dtype=np.float32)
-            for s in scores.values():
-                total_np = total_np + s
+            # Both planes maintain a RUNNING total (the legacy driver re-summed
+            # all C coordinates TWICE per update — once for the residual, once
+            # for the objective; host_score_sums stays 0 now and the regression
+            # test pins that down). The two planes execute the same sequence of
+            # IEEE f32 elementwise adds/subs — np on host, XLA on device — so
+            # their residuals (and therefore the trained models) match bitwise.
+            if device:
+                apply_, residual_ = _plane_programs()
+                zeros = jnp.zeros(self.num_rows, dtype=jnp.float32)
+                # fresh buffer: ``apply_`` donates its first argument, and the
+                # shared ``zeros`` must outlive every first-update residual
+                total = jnp.zeros_like(zeros)
+                for s in scores.values():
+                    total = total + s
+            else:
+                total_np = np.zeros(self.num_rows, dtype=np.float32)
+                for s in scores.values():
+                    total_np = total_np + s
 
         objective_history: List[Tuple[str, float]] = []
         validation_history: List[Tuple[str, float]] = []
@@ -594,7 +596,7 @@ class CoordinateDescent:
         total = jnp.zeros_like(zeros)
 
         # initial scoring for warm-started models (same path as sync)
-        with span("cd/initial_scores", coordinates=len(models)):
+        with span("cd/initial_scores", device_sync=True, coordinates=len(models)):
             for cid, model in models.items():
                 coord = self.coordinates[cid]
                 if not coord.supports_device_plane:
