@@ -50,7 +50,7 @@ import time
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 
-# ``full`` is bench.py's grid tile (N_GRID, K_GRID, D_GRID) with the chip's
+# ``full`` is a 2^20-row x 16-nonzero tile over 2^24 columns with the chip's
 # share of two random effects beside it; ``tiny`` shrinks every shape.
 SIZES = {
     "full": dict(

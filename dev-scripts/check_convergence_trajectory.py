@@ -4,7 +4,7 @@
 CI trains the tiny golden ratings fixture with ``--progress-out`` and hands
 the resulting ``progress.jsonl`` to this script, which compares the run's
 convergence TRAJECTORY against the golden records committed in
-``BENCH_HISTORY.jsonl`` (``mode: "convergence"``):
+``tests/fixtures/ratings/convergence_golden.jsonl``:
 
 * ``golden_fixture_final_objective`` — the final training objective; the
   gate fires when the fresh value sits above the reference by more than
@@ -15,17 +15,17 @@ convergence TRAJECTORY against the golden records committed in
 * optionally, with ``--target-metric``, iterations until the held-out
   metric reaches the target (``golden_fixture_iterations_to_target``).
 
-Unlike the perf sentinel these are OPTIMIZATION quantities — deterministic
-on the fixed-seed CPU fixture and independent of wall-clock noise — so no
-host fingerprint gating applies: a slower machine converges in exactly the
-same number of updates to exactly the same objective. Infrastructure
+These are OPTIMIZATION quantities — deterministic on the fixed-seed CPU
+fixture and independent of wall-clock noise: a slower machine converges in
+exactly the same number of updates to exactly the same objective. Infrastructure
 problems (missing ledger, no progress records, no golden baseline) report
 and pass; only a measured degradation fails.
 
 Usage:
     python -m photon_ml_tpu.cli.train_game ... --progress-out /tmp/p.jsonl
     python dev-scripts/check_convergence_trajectory.py /tmp/p.jsonl \
-        [--history BENCH_HISTORY.jsonl] [--objective-tolerance 0.01] \
+        [--history tests/fixtures/ratings/convergence_golden.jsonl] \
+        [--objective-tolerance 0.01] \
         [--iteration-slack 1] [--target-metric 0.9 [--lower-is-better]]
 """
 import argparse
@@ -91,8 +91,11 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("ledger", help="progress.jsonl from a --progress-out run")
     ap.add_argument(
-        "--history", default=os.path.join(REPO, "BENCH_HISTORY.jsonl"),
-        help="history file holding the golden mode=convergence records",
+        "--history",
+        default=os.path.join(
+            REPO, "tests", "fixtures", "ratings", "convergence_golden.jsonl"
+        ),
+        help="file holding the golden mode=convergence records",
     )
     ap.add_argument(
         "--objective-tolerance", type=float, default=0.01,

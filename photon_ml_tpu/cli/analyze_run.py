@@ -1,11 +1,10 @@
 """Replay a telemetry run ledger into a performance report.
 
-Reads the JSONL RunLedger a ``--telemetry-out`` run (or ``bench.py``
-telemetry mode) wrote, reconstructs the span tree, and prints per-phase
-occupancy/bubble accounting with the SolverStats / TransferStats /
-jit-retrace joins. Optionally emits the structured ``RunReport`` as JSON,
-gates on wall-clock attribution coverage (the CI analyze smoke gate), and
-runs the offline tuner over the report to propose a config.
+Reads the JSONL RunLedger a ``--telemetry-out`` run wrote, reconstructs
+the span tree, and prints per-phase occupancy/bubble accounting with the
+SolverStats / TransferStats / jit-retrace joins. Optionally emits the
+structured ``RunReport`` as JSON, gates on wall-clock attribution coverage
+(``tests/test_analyze.py``), and runs the offline tuner over the report to propose a config.
 
 Usage:
     # human-readable occupancy report
@@ -137,8 +136,7 @@ def run(args: argparse.Namespace) -> int:
             print(
                 "analyze_run: ledger carries no cluster_pass records (run "
                 "the cluster plane with telemetry — train_game --hosts "
-                "N --telemetry-out, or bench.py --multihost with "
-                "BENCH_TELEMETRY_DIR — to record skew profiles)",
+                "N --telemetry-out — to record skew profiles)",
                 file=sys.stderr,
             )
             return 1

@@ -278,15 +278,6 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
                         "--stream-mode full and exactly one fixed-effect "
                         "coordinate; random-effect coordinates still run "
                         "on this host (entity-partitioned)")
-    p.add_argument("--cluster-block-latency-ms", type=float, default=0.0,
-                   metavar="MS",
-                   help="cluster: emulated per-block device latency in each "
-                        "worker (benchmarking scaling on one box; 0 = off)")
-    p.add_argument("--cluster-kill-host", default=None, metavar="HOST:BLOCKS",
-                   help="cluster chaos drill: worker HOST kills itself after "
-                        "streaming BLOCKS blocks; training must finish "
-                        "anyway with its blocks reassigned (recovery lands "
-                        "in the --progress-out ledger)")
     p.add_argument("--progress-out", default=None, metavar="PROGRESS.jsonl",
                    help="write the convergence-plane ledger here: one JSONL "
                         "record per coordinate update (objective, grad norm, "
@@ -353,15 +344,6 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
             "--hosts requires --streaming with --stream-mode full (the "
             "distributed pass sums exact per-host partials)"
         )
-    if args.cluster_kill_host is not None:
-        if args.hosts < 2:
-            p.error("--cluster-kill-host needs --hosts >= 2 (someone must "
-                    "survive to take over the blocks)")
-        try:
-            h, n = args.cluster_kill_host.split(":")
-            int(h), int(n)
-        except ValueError:
-            p.error("--cluster-kill-host must be HOST:BLOCKS, e.g. 1:4")
     if args.staleness < 0:
         p.error("--staleness must be >= 0")
     if args.parallel_data < 0 or args.parallel_feat < 1:
@@ -747,10 +729,6 @@ def run(args: argparse.Namespace) -> GameFit:
                         "--hosts requires exactly one fixed-effect "
                         f"coordinate, config has {len(fe_shards)}"
                     )
-                kill_host = None
-                if args.cluster_kill_host is not None:
-                    h, n = args.cluster_kill_host.split(":")
-                    kill_host = (int(h), int(n))
                 # federate observability across the mesh: worker ledgers
                 # land beside the coordinator's --telemetry-out ledger
                 cluster_telemetry_dir = None
@@ -776,12 +754,6 @@ def run(args: argparse.Namespace) -> GameFit:
                             if cache_dir
                             else None
                         ),
-                        block_latency_s=(
-                            args.cluster_block_latency_ms / 1000.0
-                            if args.cluster_block_latency_ms > 0
-                            else None
-                        ),
-                        kill_host=kill_host,
                         telemetry_dir=cluster_telemetry_dir,
                     )
                 if progress is not None or telemetry is not None:

@@ -197,7 +197,7 @@ class TransferStats:
     (``num_rows``) score array that crosses the host/device boundary, plus
     the full host score-plane re-sums the legacy host plane performs. On the
     device plane the steady state is zero row transfers and zero host sums —
-    tests and the ``bench.py --cd-scores`` contract gate on exactly that.
+    ``tests/test_cd_device_scores.py`` gates on exactly that.
     """
 
     score_plane: str               # 'host' | 'device'

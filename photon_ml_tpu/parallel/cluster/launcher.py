@@ -22,7 +22,7 @@ import os
 import subprocess
 import sys
 import tempfile
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -62,8 +62,6 @@ class ClusterPlane:
         on_block_error: str = "fail",
         prefetch_depth: int = 2,
         block_cache_dir: Optional[str] = None,
-        block_latency_s: Optional[float] = None,
-        kill_host: Optional[Tuple[int, int]] = None,
         heartbeat_timeout_s: Optional[float] = None,
         startup_timeout_s: Optional[float] = None,
         log_dir: Optional[str] = None,
@@ -71,11 +69,9 @@ class ClusterPlane:
         telemetry_dir: Optional[str] = None,
     ) -> "ClusterPlane":
         """Spawn ``num_hosts`` workers over the same training files and
-        block plan; ``kill_host=(h, n)`` arms host ``h`` to chaos-die after
-        streaming ``n`` blocks (the killed-host-mid-epoch drill).
-        ``telemetry_dir`` federates observability across the mesh: the
-        coordinator profiles every pass (skew/straggler attribution) and
-        each worker writes its own ledger to
+        block plan. ``telemetry_dir`` federates observability across the
+        mesh: the coordinator profiles every pass (skew/straggler
+        attribution) and each worker writes its own ledger to
         ``{telemetry_dir}/worker-{host}-ledger.jsonl``."""
         coordinator = ClusterCoordinator(
             num_hosts, num_blocks, heartbeat_timeout_s=heartbeat_timeout_s
@@ -120,10 +116,6 @@ class ClusterPlane:
                         "--block-cache-dir",
                         os.path.join(block_cache_dir, f"host-{host}"),
                     ]
-                if block_latency_s is not None:
-                    cmd += ["--block-latency-s", str(block_latency_s)]
-                if kill_host is not None and kill_host[0] == host:
-                    cmd += ["--chaos-kill-after", str(kill_host[1])]
                 if telemetry_dir is not None:
                     cmd += [
                         "--telemetry-out",

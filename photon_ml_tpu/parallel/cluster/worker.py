@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import argparse
 import logging
-import os
 import threading
 import time
 from typing import List, Optional, Tuple
@@ -61,7 +60,6 @@ register_fault_site(
     "kills the worker mid-pass, exercising host-loss reassignment",
 )
 
-BLOCK_LATENCY_ENV = "PHOTON_CLUSTER_BLOCK_LATENCY_S"
 HEARTBEAT_INTERVAL_S = 2.0
 
 
@@ -75,7 +73,6 @@ class ClusterWorker:
         shard_id: str,
         task: TaskType,
         prefetch_depth: int = 2,
-        block_latency_s: Optional[float] = None,
         chaos_kill_after: Optional[int] = None,
     ):
         self.host_id = int(host_id)
@@ -84,12 +81,6 @@ class ClusterWorker:
         self.objective = make_glm_objective(loss_for_task(task))
         self.programs = StreamPrograms.for_objective(self.objective)
         self.prefetch_depth = int(prefetch_depth)
-        if block_latency_s is None:
-            block_latency_s = float(os.environ.get(BLOCK_LATENCY_ENV, "0"))
-        # emulated per-block device latency for scaling benchmarks on a
-        # 1-CPU box: sleeps in separate worker processes genuinely overlap,
-        # so throughput scales with hosts the way real device time would
-        self.block_latency_s = float(block_latency_s)
         self.chaos_kill_after = (
             None if chaos_kill_after is None else int(chaos_kill_after)
         )
@@ -136,8 +127,6 @@ class ClusterWorker:
             f, g, bf, bg, bgap = self.programs.acc_vg_probe(w_dev, data, f, g)
             stats.append((int(blk.index), bf, bg, bgap))
             self._blocks_done += 1
-            if self.block_latency_s > 0:
-                time.sleep(self.block_latency_s)
         reply = {
             "f": float(f),
             "g": np.asarray(g, dtype=np.float64),
@@ -293,8 +282,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("--prefetch-depth", type=int, default=2)
     p.add_argument("--on-block-error", default="fail")
     p.add_argument("--block-cache-dir", default=None)
-    p.add_argument("--block-latency-s", type=float, default=None)
-    p.add_argument("--chaos-kill-after", type=int, default=None)
     p.add_argument(
         "--telemetry-out",
         default=None,
@@ -337,8 +324,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         shard_id=args.feature_shard,
         task=TaskType[args.task],
         prefetch_depth=args.prefetch_depth,
-        block_latency_s=args.block_latency_s,
-        chaos_kill_after=args.chaos_kill_after,
     )
     run = None
     if args.telemetry_out:

@@ -22,7 +22,7 @@ map onto that design:
 - :mod:`photon_ml_tpu.serving.metrics` — latency percentiles, queue depth,
   batch fill ratio and cache hit rate as a dict snapshot.
 - :mod:`photon_ml_tpu.serving.replay` — turn a scoring dataset into a
-  request stream and pump it through the batcher (CLI + bench driver).
+  request stream and pump it through the batcher (the CLI's driver).
 - :mod:`photon_ml_tpu.serving.hotswap` — apply nearline delta artifacts
   (``photon_ml_tpu.incremental``) to a live scorer between batches: in-place
   table mutation with no retrace, per-row cache invalidation, AUC validation
@@ -55,7 +55,7 @@ map onto that design:
 - :mod:`photon_ml_tpu.serving.scenarios` — seeded traffic-shape scenarios
   (steady, diurnal, burst storm, cold-entity flood, hot-swap under load,
   plus the tenancy trio: tenant isolation, ramped rollout, nearline loop)
-  driving ``replay_requests`` for the ``bench.py --scenarios`` harness.
+  driving ``replay_requests``.
 - :mod:`photon_ml_tpu.serving.tenancy` — the tenancy plane: N GLMix model
   variants as fingerprint-chained delta overlays on ONE shared sharded
   scorer, seeded deterministic variant routing with hot ramp percentages,

@@ -354,7 +354,19 @@ class ContinuousBatcher:
                 # queue room just opened: wake blocked submitters (and any
                 # sibling replica thread waiting for work)
                 self._cond.notify_all()
-            self._score(scorer, batch)
+            try:
+                self._score(scorer, batch)
+            except BaseException:
+                # the loop is about to die under its supervisor: whatever
+                # of the drained batch no one has answered goes back to the
+                # head of its lane, for the restarted loop or a sibling
+                # replica, instead of blocking its submitters forever
+                with self._cond:
+                    stranded = [item for item in batch if not item[2].done]
+                    self._inflight -= len(stranded)
+                    lane.extendleft(reversed(stranded))
+                    self._cond.notify_all()
+                raise
 
     def _supports_stages(self, scorer) -> bool:
         """Whether this replica's ``score_batch`` accepts a stage clock

@@ -2,7 +2,7 @@
 
 The batcher feeds per-request latencies (enqueue → scored) and per-batch
 fill/queue observations; ``snapshot`` renders everything as one plain dict
-so it can be logged, JSON-dumped by the CLI/bench, or attached to a
+so it can be logged, JSON-dumped by the CLI, or attached to a
 ``ScoringFinishEvent``. Latencies additionally land in a fixed log-spaced
 histogram (100µs … 10s) whose bucket counts are EXACT for the lifetime of
 the collector.

@@ -3,9 +3,9 @@
 Turns ``GameData`` rows into ``ScoreRequest``s (one per row: sparse
 features per shard the artifact consumes, the row's entity id per
 random-effect type, its offset) and pumps them through a microbatcher with
-full metrics/event instrumentation. This is the shared driver behind
-``cli/serve_game.py`` and the serving mode of ``bench.py``; tests use it to
-prove the online path reproduces the offline ``GameModel.score``.
+full metrics/event instrumentation. This is the driver behind
+``cli/serve_game.py``; tests use it to prove the online path reproduces the
+offline ``GameModel.score``.
 """
 
 from __future__ import annotations

@@ -1,22 +1,21 @@
 """Seeded traffic-shape scenarios for the serving replay harness.
 
-Steady-state replay (``bench.py --serving``) regression-gates one traffic
-shape. Production regressions live in the others: a diurnal ramp that
+A steady-state replay exercises one traffic shape. Production regressions
+live in the others: a diurnal ramp that
 outruns admission, a burst storm that fills the backpressure queue, a
 cold-entity flood that craters device residency, a hot-swap landing under
 load. Each scenario here is a deterministic (seeded) reshaping of a base
 request stream into phases driven through
 :func:`~photon_ml_tpu.serving.replay.replay_requests`, with the request
-plane sampling lifecycles and the SLO tracker keeping the verdict — so
-``bench.py --scenarios`` emits one per-stage p50/p99 breakdown, residency
-rate, and SLO verdict per traffic shape into ``BENCH_SCENARIOS.json``,
-and the CI scenario sentinel gates them all.
+plane sampling lifecycles and the SLO tracker keeping the verdict:
+:func:`run_scenario` returns one per-stage breakdown, residency rate and
+SLO verdict per traffic shape (``tests/test_requestplane.py``,
+``tests/test_tenancy.py``).
 
 Scenario catalog (``SCENARIO_NAMES``):
 
 ``steady``
-    The base stream in even phases — the control arm; matches the
-    ``--serving`` bench's shape.
+    The base stream in even phases — the control arm.
 ``diurnal``
     A one-day load curve compressed into the replay: sinusoidal phase
     sizes (peak ~3x trough) with idle gaps before the troughs, so the
@@ -187,7 +186,7 @@ def build_scenario(
     """Deterministically reshape ``requests`` into the named scenario.
 
     ``pause_s`` scales the idle gaps (diurnal troughs, storm quiets);
-    smoke/CI callers shrink it, the committed bench uses the default.
+    tests shrink it.
     ``tenants``/``ramp_variant`` apply only to the tenancy scenarios:
     the stream is tagged round-robin across ``tenants``, and the
     ``ramped_rollout`` phases drive ``ramp_variant``'s ramp.

@@ -2,9 +2,9 @@
 format so epochs after the first stream at disk/memory bandwidth with ZERO
 Avro work.
 
-PR 10's streaming trainer re-decoded every part file on every pass — 171.7
-of 180.7 bench seconds stalled on decode. Snap ML's hierarchical pipeline
-(arxiv 1803.06333) is the blueprint: pay the decode once, then every later
+The streaming trainer would otherwise decode every part file again on
+every pass. Snap ML's hierarchical pipeline (arxiv 1803.06333) is the
+blueprint: pay the decode once, then every later
 block visit is pure data movement. This module is that second level of the
 hierarchy:
 

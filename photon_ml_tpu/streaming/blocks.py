@@ -82,8 +82,8 @@ def readahead_file_budget() -> int:
     Decoded-file residency is the peak-RSS term of streaming, and it must
     be bounded independently of the pool width: with the worker cap at 16,
     scheduling ``workers + depth`` files ahead would let a many-core host
-    keep ~17 decoded files resident — the out-of-core bound the bench
-    guarantees assumes a handful. The default (4) matches the residency of
+    keep ~17 decoded files resident, where the out-of-core bound assumes
+    a handful. The default (4) matches the residency of
     the original ``min(4, cpus-1)`` pool; override with
     ``PHOTON_STREAM_READAHEAD_FILES`` when files are small relative to
     RAM and deeper readahead measurably helps the hide ratio.
@@ -261,7 +261,7 @@ class StreamingSource:
         self.on_block_error = "abort"
         self.failed_blocks: set = set()
         self._skipped_log: List[dict] = []
-        # decode accounting for the planning/setup passes (bench evidence)
+        # decode accounting for the planning/setup passes
         self.files_decoded = 0
         # RAM level of the residency hierarchy: part files served from the
         # decoded-file LRU instead of re-decoding (residency_hierarchy)

@@ -259,7 +259,7 @@ class ResidencyManager:
         return out
 
     def snapshot(self) -> dict:
-        """Point-in-time summary for bench/telemetry reports."""
+        """Point-in-time summary for telemetry reports."""
         return {
             "capacity_blocks": int(self.capacity),
             "block_bytes": int(self.block_bytes),
@@ -283,8 +283,7 @@ def residency_hierarchy(source, manager: Optional[ResidencyManager] = None) -> d
       decode entirely.
     * ``hbm``   — the resident set: a hit skips the ``device_put`` upload.
 
-    Levels a run does not use report zeros, so the dict shape is stable
-    for the bench contract.
+    Levels a run does not use report zeros, so the dict shape is stable.
     """
     cache = getattr(source, "cache", None)
     disk = {

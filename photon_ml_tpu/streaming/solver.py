@@ -19,8 +19,8 @@ Two modes, both built on the repo's existing optimizer primitives:
   epoch, ``chunk_iters`` solver iterations per group, warm-started ``w``
   carried between groups, λ scaled by the group's weight fraction so the
   per-group optimum matches the full-batch regularization scale. Gated on
-  held-out metric parity (tests/bench), per the convergence guidance of
-  arxiv 1702.07005 / 1811.01564.
+  held-out metric parity (tests/test_streaming.py), per the convergence
+  guidance of arxiv 1702.07005 / 1811.01564.
 
 Every jitted program calls ``_note_trace`` inside its traced body, so
 ``stream_trace_counts()`` counts actual (re)compiles — the CI parity gate

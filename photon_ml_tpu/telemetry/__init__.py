@@ -11,7 +11,7 @@ the ``Event`` pub/sub). This package is the single place they all land:
   :func:`record_memory_watermarks`.
 * sinks — JSONL run ledger, Chrome trace-event (Perfetto) export, terminal
   summary table, and the :class:`TelemetryEventListener` bridge.
-* :func:`start_run` — one handle tying the above together for a CLI/bench
+* :func:`start_run` — one handle tying the above together for a CLI
   run (``--telemetry-out`` / ``--trace-out``).
 
 See docs/OBSERVABILITY.md for the span model, metric names, and schemas.

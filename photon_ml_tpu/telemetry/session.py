@@ -1,11 +1,11 @@
 """Run-scoped telemetry session: tracer + registry + sinks, one handle.
 
-A CLI (or bench lane) calls :func:`start_run` once, optionally
+A CLI calls :func:`start_run` once, optionally
 :meth:`TelemetryRun.attach`\\ es the driver's emitter so existing events
 land in the ledger, and calls :meth:`TelemetryRun.finish` in its
 ``finally`` block. ``finish`` drains the tracer into the ledger and Chrome
 trace files, records memory watermarks, logs the terminal summary table,
-and returns the summary dict (which bench embeds in its artifacts).
+and returns the summary dict.
 """
 from __future__ import annotations
 

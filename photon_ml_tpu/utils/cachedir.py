@@ -44,8 +44,8 @@ def enable_compilation_cache() -> Optional[str]:
 
     When ``JAX_COMPILATION_CACHE_DIR`` is set the caller has placed the
     cache and no directory is set in code (JAX reads the variable itself).
-    Otherwise the cache is :data:`COMPILE_CACHE_DIR`. Every CLI, ``bench.py``
-    and ``chip_smoke.py`` call this before their first compile. It looks at
+    Otherwise the cache is :data:`COMPILE_CACHE_DIR`. Every CLI and
+    ``chip_smoke.py`` call this before their first compile. It looks at
     the backend, so a process that joins a cluster does that first.
 
     Off on the CPU: in jax 0.9.0 a multi-device XLA:CPU program loaded back

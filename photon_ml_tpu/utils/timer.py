@@ -2,7 +2,7 @@
 
 The reference wraps every driver phase in ``Timed { }`` blocks writing to a
 driver-side logger; here the same pattern is a context manager that logs
-wall-clock per phase and can be queried afterwards (bench/driver code uses it).
+wall-clock per phase and can be queried afterwards (driver code uses it).
 
 Both ``Timer`` and ``Timed`` are thin shims over the telemetry span API
 (:mod:`photon_ml_tpu.telemetry.span`) so there is exactly ONE timing path:

@@ -1,8 +1,7 @@
 """Gap-guided block scheduling (DuHL) for stochastic streaming.
 
-The CI "Gap scheduler parity gate" runs this module. The load-bearing
-contract: with ``gap_schedule`` OFF (the default) the stochastic visit
-order is bitwise-identical to the historical blind per-epoch
+The load-bearing contract: with ``gap_schedule`` OFF (the default) the
+stochastic visit order is bitwise-identical to the historical blind per-epoch
 ``rng.permutation`` trajectory — the scheduler must be impossible to
 observe unless opted into. With it ON, the scheduler's invariants hold:
 bootstrap epochs cover every block, stale scores decay, the exploration
@@ -78,6 +77,47 @@ def source(dataset):
         dataset["paths"], SHARDS, index_maps=dataset["index_maps"],
         block_rows=BLOCK_ROWS,
     )
+
+
+@pytest.fixture()
+def skewed_source(tmp_path):
+    """Eight blocks over four files; blocks 0 and 4 hold a logistic signal,
+    the others near-zero features under a constant label."""
+    rng = np.random.default_rng(11)
+    hard = (0, 4)
+    n_blocks = 8
+    n = n_blocks * BLOCK_ROWS
+    w = 2.0 * rng.normal(size=D).astype(np.float32)
+    X = (0.01 * rng.normal(size=(n, D))).astype(np.float32)
+    y = np.ones(n, dtype=np.float32)
+    for b in hard:
+        rows = slice(b * BLOCK_ROWS, (b + 1) * BLOCK_ROWS)
+        X[rows] = rng.normal(size=(BLOCK_ROWS, D))
+        y[rows] = (
+            1.0 / (1.0 + np.exp(-(X[rows] @ w))) > rng.random(BLOCK_ROWS)
+        ).astype(np.float32)
+    paths = []
+    per_file = 2 * BLOCK_ROWS
+    for fi in range(n // per_file):
+        recs = [
+            {
+                "uid": f"r{i}",
+                "label": float(y[i]),
+                "weight": 1.0,
+                "features": [
+                    ("g", str(j), float(X[i, j])) for j in range(D)
+                ],
+            }
+            for i in range(fi * per_file, (fi + 1) * per_file)
+        ]
+        p = str(tmp_path / f"part-{fi:05d}.avro")
+        write_training_examples(p, recs)
+        paths.append(p)
+    source = StreamingSource.open(
+        paths, SHARDS, index_maps=build_index_maps(paths, SHARDS),
+        block_rows=BLOCK_ROWS,
+    )
+    return source, hard
 
 
 # ------------------------------------------------------- scheduler unit
@@ -250,7 +290,7 @@ class TestSolverScheduling:
             objective, w0, make_blocks,
             configuration=cfg,
             num_blocks=source.plan.num_blocks,
-            total_weight=float(N_ROWS),
+            total_weight=float(source.plan.total_rows),
             epochs=epochs, chunk_iters=2, blocks_per_update=2, seed=seed,
             scheduler=scheduler,
         )
@@ -287,6 +327,29 @@ class TestSolverScheduling:
         # the solver fed measured gaps back: nothing left unmeasured
         assert np.all(np.isfinite(sched.scores))
         assert np.asarray(result.w).shape == (source.plan.shard_dims["global"],)
+
+    def test_gap_path_keeps_its_visits_on_the_blocks_with_signal(
+        self, skewed_source
+    ):
+        """DuHL's point, in the scheduler's own currency (block visits):
+        where two blocks in eight carry the signal and the rest are fitted
+        by the bootstrap pass, every scheduled epoch visits both, and an
+        easy block is visited less often than either."""
+        source, hard = skewed_source
+        n = source.plan.num_blocks
+        sched = GapScheduler(
+            n, plan=source.plan, visit_fraction=0.5, explore=0.0, seed=0
+        )
+        _, orders = self._run(source, scheduler=sched, epochs=6)
+        assert sorted(orders[0].tolist()) == list(range(n))
+        scheduled = orders[1:]
+        assert all(o.size < n for o in scheduled)
+        visits = np.zeros(n, dtype=int)
+        for order in scheduled:
+            assert set(hard) <= set(order.tolist()), (hard, order)
+            visits[order] += 1
+        easy = [b for b in range(n) if b not in hard]
+        assert visits[easy].mean() < visits[list(hard)].min(), visits
 
     def test_gap_orders_are_file_grouped(self, source):
         sched = GapScheduler(source.plan.num_blocks, plan=source.plan, seed=1)

@@ -21,8 +21,6 @@ The load-bearing guarantees, per ISSUE acceptance criteria:
 
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -711,30 +709,3 @@ class TestNearlineCli:
         )
         assert summary["compacted_fingerprint"] == fingerprint_dir(compacted)
         load_artifact(compacted)  # the folded chain is a full artifact
-
-
-@pytest.mark.slow
-def test_bench_incremental_smoke_contract():
-    """bench.py --incremental emits one machine-readable JSON line with the
-    nearline metrics (same contract as the training/serving benches)."""
-    env = dict(
-        os.environ, BENCH_SMOKE="1", JAX_PLATFORMS="cpu",
-        BENCH_PLAN_CACHE="",
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--incremental"],
-        capture_output=True, text=True, timeout=900, env=env, cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert payload["metric"] == "incremental_update_latency_s"
-    assert payload["unit"] == "seconds"
-    assert payload["value"] > 0
-    assert payload["publish_s"] > 0
-    assert payload["swap_blackout_s"] > 0
-    assert payload["swap_compiles_added"] == 0
-    assert payload["swap_regrew"] == []
-    assert payload["rows_updated"] > 0
-    assert "error" not in payload
-    # smoke mode must not write the results file
-    assert not os.path.exists(os.path.join(REPO, "BENCH_INCREMENTAL.json"))

@@ -84,6 +84,7 @@ def _features(rows, engine):
     feats = fused_perm.from_coo(
         r, c, v, (N, D), size_floor=128 * 128, plan_cache="",
         col_split=2 if engine == "split" else 1,
+        payload_dtype="bfloat16" if engine == "fused_bf16" else "float32",
     )
     assert isinstance(feats, ColumnSplitFeatures) == (engine == "split")
     assert INTERCEPT in np.asarray(feats.hot_cols)  # the dense column went to the hot side
@@ -177,12 +178,14 @@ def test_blocks_of_a_column_split_share_one_stats_program(linear, interpret_kern
     np.testing.assert_array_equal(np.asarray(summary.num_nonzeros), want["nonzeros"])
 
 
-@pytest.mark.parametrize("engine", ["dense", "fused"])
+@pytest.mark.parametrize("engine", ["dense", "fused", "fused_bf16"])
 @pytest.mark.parametrize("kind", NORMALIZATIONS)
 def test_tron_fit_on_normalized_features_reaches_the_reference_optimum(
     linear, rows, kind, engine, interpret_kernels
 ):
-    """``train_glm`` hands back the model in the original feature space."""
+    """``train_glm`` hands back the model in the original feature space. The
+    bfloat16 payload is held to the optimum only as the exact objective
+    scores its solution (relative 1e-4): its maps round at network entry."""
     data, _ = _labeled(rows, engine, kind)
     ref = _reference(linear, rows, kind)
     w_ref, info = ref.solve()
@@ -192,6 +195,9 @@ def test_tron_fit_on_normalized_features_reaches_the_reference_optimum(
         intercept_index=INTERCEPT,
     )[0])
     w = np.asarray(fit.model.coefficients.means)
+    if engine == "fused_bf16":
+        assert ref.objective(w) == pytest.approx(info["value"], rel=1e-4)
+        return
     assert float(fit.result.value) == pytest.approx(info["value"], rel=1e-5)
     assert ref.objective(w) == pytest.approx(info["value"], rel=1e-5)
     assert np.linalg.norm(w - np.asarray(w_ref)) <= 2e-3 * np.linalg.norm(w_ref)

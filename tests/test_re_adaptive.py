@@ -226,8 +226,11 @@ def test_pow2_ladder_bounds_recompiles(rng):
     s = stats1[0]
     widths = list(s.dispatch_widths)
     assert widths[0] == s.num_entities
-    for w in widths[1:]:
-        assert w & (w - 1) == 0, f"non-pow2 re-dispatch width {w}"
+    # a round that compacts nothing dispatches its width again; only a new
+    # width has to sit on the ladder
+    for prev, w in zip(widths, widths[1:]):
+        if w != prev:
+            assert w & (w - 1) == 0, f"non-pow2 re-dispatch width {w}"
         assert w >= ADAPTIVE.min_lanes
     assert widths == sorted(widths, reverse=True)
     # ladder bound: the initial width plus at most one program per pow2

@@ -6,7 +6,6 @@ The load-bearing guarantees, per ISSUE acceptance criteria:
 - **Disabled-path parity**: replaying the same stream with no plane, with a
   plane at ``sample_rate=0``, and with a fully-sampling plane produces
   BITWISE-identical scores — observation may never perturb the data path.
-  (The matching CI step is the request-plane disabled-path parity gate.)
 - **Attribution completeness**: stage boundaries telescope, so each sampled
   record's per-stage durations sum to its end-to-end latency and the tail
   breakdown's attribution coverage is ~1.0 (>= the 0.95 acceptance floor).
@@ -256,7 +255,7 @@ class TestSLOTracker:
 
 
 class TestDisabledPathParity:
-    """The CI request-plane disabled-path parity gate runs this class."""
+    """The request plane may observe, never perturb."""
 
     def test_scores_bitwise_identical_across_plane_modes(self, glmix):
         artifact, requests = _requests(glmix)

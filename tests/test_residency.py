@@ -1,6 +1,6 @@
 """Hierarchical device residency (streaming/residency.py).
 
-The CI "Residency parity gate" runs this module: the residency-disabled
+The residency-disabled
 path must stay bitwise identical to the historical streamed solver with
 zero extra jit traces, and the enabled path must cut warm-pass H2D bytes
 while leaving the solve trajectory untouched (identical visit order —

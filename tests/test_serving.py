@@ -10,13 +10,11 @@ The load-bearing guarantees, per ISSUE acceptance criteria:
 - LRU cache eviction order, hit accounting and batch pinning;
 - artifact export/load round trip (npy tables + PHIX off-heap entity maps);
 - the ``serve_game`` CLI never silently rots (fast smoke over the golden
-  ratings fixture); the throughput bench itself is ``slow``-marked.
+  ratings fixture).
 """
 
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -951,68 +949,3 @@ class TestScoreGameMissingEntityPolicy:
                 "--output-dir", str(tmp_path / "scores"),
                 "--missing-entity-policy", "error",
             ]))
-
-
-@pytest.mark.slow
-class TestServingBench:
-    def test_bench_serving_contract(self):
-        """`python bench.py --serving` emits one well-formed JSON line with
-        the p99/throughput contract (smoke shapes on CPU)."""
-        env = dict(os.environ, BENCH_SMOKE="1", JAX_PLATFORMS="cpu")
-        env.pop("BENCH_SERVING_WRITE", None)
-        out_path = os.path.join(REPO, "BENCH_SERVING.json")
-        mtime_before = (
-            os.path.getmtime(out_path) if os.path.exists(out_path) else None
-        )
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "--serving"],
-            capture_output=True, text=True, timeout=600, env=env, cwd=REPO,
-        )
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        payload = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert payload["metric"] == "serving_p99_latency_s"
-        assert "error" not in payload
-        assert payload["value"] > 0
-        assert payload["requests_per_s"] > 0
-        assert payload["latency_p50_s"] <= payload["latency_p99_s"]
-        assert payload["serving_mode"] == "sharded-continuous"
-        assert 0.0 <= payload["device_resident_rate"] <= 1.0
-        assert payload["admission"]["admitted_total"] >= 0
-        assert "per_user" in payload["residency"]
-        # compile-once-per-bucket holds on the bench path too, even with
-        # the admission tier scattering rows in the background
-        assert payload["warm_compiles"] == len(payload["bucket_sizes"])
-        assert payload["post_warmup_compiles"] == 0
-        # eviction-policy A/B: both arms recorded with rates in range and
-        # zero post-warmup compiles (victim choice must not retrace)
-        ab = payload["eviction_ab"]
-        assert ab["device_budget_rows"] > 0
-        for arm in ("oldest", "importance"):
-            stats = ab[arm]
-            assert 0.0 <= stats["device_resident_rate"] <= 1.0
-            assert 0.0 <= stats["deferred_rate"] <= 1.0
-            assert stats["evicted_total"] >= 0
-            assert stats["post_warmup_compiles"] == 0
-        assert "resident_rate_gain" in ab
-        # smoke must not overwrite a committed measurement
-        mtime_after = (
-            os.path.getmtime(out_path) if os.path.exists(out_path) else None
-        )
-        assert mtime_after == mtime_before
-
-    def test_bench_serving_committed_artifact(self):
-        """The committed full-scale record must back the importance-eviction
-        claim: at the same device budget on the Zipf-replay A/B, scoring
-        victims by request-frequency x coefficient-norm keeps a higher
-        device-resident rate than oldest-admitted FIFO."""
-        path = os.path.join(REPO, "BENCH_SERVING.json")
-        assert os.path.exists(path), "full-scale --serving record missing"
-        with open(path) as f:
-            payload = json.load(f)
-        assert payload["metric"] == "serving_p99_latency_s"
-        ab = payload["eviction_ab"]
-        assert ab["importance"]["device_resident_rate"] > (
-            ab["oldest"]["device_resident_rate"]
-        )
-        assert ab["oldest"]["post_warmup_compiles"] == 0
-        assert ab["importance"]["post_warmup_compiles"] == 0

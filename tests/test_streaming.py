@@ -1,8 +1,7 @@
 """Out-of-core streaming training: block planning, the double-buffered
 prefetcher, block-sharded solvers, and estimator/CLI parity.
 
-The CI "Streaming parity gate" runs this whole module (including the
-slow-marked golden-fixture case): streamed full-batch training must match
+Streamed full-batch training must match
 the in-memory fit within 1e-3 on held-out metrics, with ZERO extra jit
 retraces across blocks — every streamed program compiles exactly once per
 (objective, shape), however many blocks, passes, and fits run.
@@ -263,8 +262,7 @@ class TestPrefetcher:
         """Satellite contract: with a 2-worker decode pool over >= 2 cold
         part files, summed per-thread decode work exceeds decode wall clock
         — the pool genuinely overlapped — and PrefetchStats reports the
-        achieved parallelism (the decode_parallelism field the streaming
-        bench artifact now carries)."""
+        achieved parallelism (``decode_parallelism``)."""
         src = StreamingSource.open(
             dataset["paths"], SHARDS, index_maps=dataset["index_maps"],
             block_rows=BLOCK_ROWS, id_tags=("userId",), decode_workers=2,
@@ -518,7 +516,7 @@ class TestStreamingEstimator:
 # --------------------------------------------------- golden fixture (slow)
 @pytest.mark.slow
 class TestGoldenFixtureStreaming:
-    """The CI streaming parity gate on the committed ratings fixture: the
+    """Streaming parity on the committed ratings fixture: the
     streamed trainer over the fixture split into blocks must land within
     1e-3 RMSE of the in-memory trainer, with zero extra retraces across
     blocks (same LBFGS config both arms; TRON cannot stream)."""

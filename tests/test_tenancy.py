@@ -3,8 +3,7 @@
 The load-bearing guarantees, per ISSUE acceptance criteria:
 
 - a single tenant on the base variant scores BITWISE identically through
-  the tenancy plane and through the plain sharded path (the parity gate
-  CI runs);
+  the tenancy plane and through the plain sharded path;
 - per-variant delta overlays diverge ONLY the delta-touched entities of
   the variant they are applied to — the base variant and every other
   variant stay bitwise unchanged — and a rollback restores bitwise state;
@@ -21,7 +20,7 @@ The load-bearing guarantees, per ISSUE acceptance criteria:
   tenant-labeled Prometheus series;
 - the tenancy scenarios (tenant_isolation / ramped_rollout /
   nearline_loop) build and run end to end, producing the per-tenant SLO
-  verdicts the scenario sentinel requires.
+  verdicts.
 """
 
 import dataclasses
@@ -92,7 +91,7 @@ def _delta_for(art, entities, seed=0, scale=0.5):
 
 class TestVariantRegistry:
     def test_base_parity_through_plane(self):
-        """The CI parity gate: one tenant, base variant only — scores
+        """One tenant, base variant only — scores
         through the tenancy plane are bitwise identical to the plain
         sharded path."""
         art = _artifact()
