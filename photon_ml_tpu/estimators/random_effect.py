@@ -15,16 +15,18 @@ When ``configuration.adaptive.enabled`` the per-bucket solve instead runs in
 chunks of K outer iterations (full solver state — L-BFGS memory, OWL-QN
 orthant state, TRON trust radius — carried across chunks, so the per-lane
 trajectory is IDENTICAL to one-shot), pulls the converged mask after each
-chunk, compacts unconverged entities into a dense prefix (stable argsort on
-the mask + one gather program), and re-dispatches survivors at the next
-smaller power-of-two lane count. Compiled programs per (optimizer, bucket
-shape) are bounded by the pow2 ladder and verified by ``solver_trace_counts``.
+chunk, and dispatches the next chunk on the live lanes only. State and data
+stay at the bucket's full width in entity order: a round hands the ONE chunk
+program of the bucket shape an index vector (live lanes first) and a tile
+count, and the program loops over that many tiles of ``T`` lanes (gather,
+vmapped chunk, scatter back). The number of live lanes is an operand, never a
+shape, so a bucket shape compiles one init, one chunk and one extract program
+for the life of the process (``solver_trace_counts``).
 """
 
 from __future__ import annotations
 
 import collections
-import dataclasses
 import functools
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -58,7 +60,7 @@ _NOT_CONVERGED = ConvergenceReason.NOT_CONVERGED.value
 # Python-side jit-cache-miss counter: each key is (program, optimizer kind)
 # and its count only grows when XLA actually (re)traces that program — the
 # increment sits inside the traced body, which never executes on cache hits.
-# Tests use this to assert the pow2 ladder bounds compilation.
+# Tests use this to assert one chunk program per bucket shape.
 _TRACE_COUNTS: "collections.Counter[Tuple[str, str]]" = collections.Counter()
 
 
@@ -89,6 +91,20 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (int(n) - 1).bit_length())
 
 
+# A bucket is worked on in tiles of a sixteenth of its (power-of-two) width.
+_TILES_PER_BUCKET = 16
+
+
+def _tile_lanes(num_entities: int, min_lanes: int) -> int:
+    """Lanes a tile of the adaptive chunk program holds: a function of the
+    bucket's shape alone, so the host's bookkeeping and the traced program
+    agree on it. ``min_lanes`` is its floor (a tile under that is launch
+    overhead); a bucket of one tile is a lockstep dispatch."""
+    return max(
+        _next_pow2(min_lanes), _next_pow2(num_entities) // _TILES_PER_BUCKET
+    )
+
+
 def _is_multi_device(x) -> bool:
     sharding = getattr(x, "sharding", None)
     return sharding is not None and len(sharding.device_set) > 1
@@ -96,16 +112,18 @@ def _is_multi_device(x) -> bool:
 
 class _RePrograms(NamedTuple):
     """Jitted programs for one (task, configuration, compute_variances)
-    combination. jax.jit specializes each per input shape, so the compiled
-    program count is (#pow2 widths) per bucket shape — never per round."""
+    combination. jax.jit specializes each per input shape, and every shape
+    is the bucket's: one program each per bucket shape — never per round,
+    per step or per count of live lanes."""
 
     kind: str
     chunk_iters: int
+    min_lanes: int
     oneshot: Callable    # (w0, data, pv, l2, l1) -> (SolveResult, w_masked, var|None)
     init: Callable       # (w0, data, l2, l1) -> batched solver state
-    chunk: Callable      # (state, data, l2) -> state advanced by <= K iters
+    chunk: Callable      # (state, data, l2, live_idx, n_tiles) -> state with the
+                         # lanes of the first n_tiles tiles advanced by <= K iters
     extract: Callable    # (state, data, pv, l2) -> (SolveResult, w_masked, var|None)
-    compact: Callable    # (tree, idx) -> tree gathered along the entity axis
 
 
 @functools.lru_cache(maxsize=None)
@@ -118,6 +136,7 @@ def _re_programs(
     use_l1 = configuration.l1_weight > 0
     kind = solver_kind(configuration, None if use_l1 else 0.0)
     K = configuration.adaptive.chunk_iters
+    min_lanes = configuration.adaptive.min_lanes
 
     def _mask_and_var(res: SolveResult, data, pv, l2):
         # padding columns have all-zero features; L2 keeps them at 0, but be
@@ -163,47 +182,33 @@ def _re_programs(
         _note_trace("re_init", kind)
         return jax.vmap(init_one, in_axes=(0, 0, None, None))(w0, data, l2, l1)
 
-    def _chunk(state, data, l2):
+    def _chunk(state, data, l2, live_idx, n_tiles):
         _note_trace("re_chunk", kind)
-        return jax.vmap(chunk_one, in_axes=(0, 0, None))(state, data, l2)
+        T = _tile_lanes(state.it.shape[0], min_lanes)
+
+        def tile(t, state):
+            idx = jax.lax.dynamic_slice_in_dim(live_idx, t * T, T)
+            lanes, lane_data = jax.tree.map(lambda a: a[idx], (state, data))
+            lanes = jax.vmap(chunk_one, in_axes=(0, 0, None))(lanes, lane_data, l2)
+            return jax.tree.map(lambda a, new: a.at[idx].set(new), state, lanes)
+
+        return jax.lax.fori_loop(0, n_tiles, tile, state)
 
     def _extract(state, data, pv, l2):
         _note_trace("re_extract", kind)
         return jax.vmap(extract_one, in_axes=(0, 0, 0, None))(state, data, pv, l2)
-
-    def _compact(tree, idx):
-        _note_trace("re_compact", kind)
-        return jax.tree.map(lambda a: a[idx], tree)
 
     # Donate the carried solver state so each round updates in place instead
     # of copying the (w, memory, history) buffers.
     return _RePrograms(
         kind=kind,
         chunk_iters=K,
+        min_lanes=min_lanes,
         oneshot=jax.jit(_oneshot),
         init=jax.jit(_init),
         chunk=jax.jit(_chunk, donate_argnums=(0,)),
         extract=jax.jit(_extract),
-        compact=jax.jit(_compact),
     )
-
-
-def _scatter_extract(progs, state, data, pv, l2, live, buffers, num_entities):
-    """Finalize the current lanes on device, then scatter every result leaf
-    into host buffers at the original entity rows (``live``). Re-scattering a
-    frozen lane later is idempotent: done lanes never advance."""
-    res, w_m, var = jax.device_get(progs.extract(state, data, pv, l2))
-    leaves = {"__w_masked": np.asarray(w_m)}
-    if var is not None:
-        leaves["__var"] = np.asarray(var)
-    for f in dataclasses.fields(SolveResult):
-        v = getattr(res, f.name)
-        if v is not None:
-            leaves[f.name] = np.asarray(v)
-    for name, arr in leaves.items():
-        if name not in buffers:
-            buffers[name] = np.zeros((num_entities,) + arr.shape[1:], dtype=arr.dtype)
-        buffers[name][live] = arr
 
 
 def _solve_bucket_adaptive(
@@ -213,76 +218,63 @@ def _solve_bucket_adaptive(
     l2: jax.Array,
     l1: jax.Array,
     max_iterations: int,
-    min_lanes: int,
     bucket_index: int,
 ):
-    """Chunked rounds + lane compaction for one bucket. Returns
-    (SolveResult over the ORIGINAL entity order, masked w, variances|None,
-    SolverStats)."""
+    """Chunked rounds over the live lanes of one bucket. Returns
+    (SolveResult, masked w, variances|None, SolverStats); the first three
+    stay on the device, in entity order."""
     E = bucket.num_entities
     K = progs.chunk_iters
+    T = _tile_lanes(E, progs.min_lanes)
     data = _bucket_data(bucket)
-    pv = bucket.proj_valid
     retrace0 = _TRACE_COUNTS[("re_chunk", progs.kind)]
 
     state = progs.init(w0, data, l2, l1)
-    live = np.arange(E)             # lane -> original entity row
-    width = E
-    its_before = np.zeros(E, dtype=np.int64)
+    # live lanes first, each group in entity order; round 0 takes every lane
+    # (a lane converged at init never advances, and no mask is pulled for it)
+    order = np.arange(E, dtype=np.int32)
+    n_tiles = -(-E // T)
+    pad = n_tiles * T - E
+    its = np.zeros(E, dtype=np.int64)
     executed = 0
     widths: List[int] = []
-    buffers: Dict[str, np.ndarray] = {}
     # ceil(max_iter/K) chunks always finish every lane; +1 slack for the
     # converged-at-init case where the first chunk advances nothing.
     max_rounds = -(-max_iterations // K) + 1
 
     for round_index in range(max_rounds):
+        # the slots past E repeat the last lane in the order: a done lane
+        # once any is done, else lane E-1, which shares their tile; either
+        # way every copy computes what its original does
+        live_idx = np.concatenate([order, np.full(pad, order[-1], np.int32)])
         with span(
             "re/adaptive_round",
             bucket=bucket_index,
             round=round_index,
-            width=width,
+            width=n_tiles * T,
+            tiles=n_tiles,
         ):
-            state = progs.chunk(state, data, l2)
-            widths.append(width)
+            state = progs.chunk(state, data, l2, live_idx, np.int32(n_tiles))
+            widths.append(n_tiles * T)
+            its_before = its
             # the host's wait for the device, once a round: the two pulls
             # block until the chunk just dispatched has retired
             with span("re/round_wait"):
-                its_after = np.asarray(jax.device_get(state.it)).astype(np.int64)
+                its = np.asarray(jax.device_get(state.it)).astype(np.int64)
                 reasons = np.asarray(jax.device_get(state.reason))
-            executed += width * int(np.max(its_after - its_before)) if width else 0
-            done = (reasons != _NOT_CONVERGED) | (its_after >= max_iterations)
-            n_live = int(np.sum(~done))
-            if n_live == 0:
-                _scatter_extract(progs, state, data, pv, l2, live, buffers, E)
-                break
-            new_width = _next_pow2(max(n_live, min_lanes))
-            if new_width < width:
-                # freeze current results, then compact survivors (+ filler done
-                # lanes up to the pow2 width) into a dense prefix on device
-                _scatter_extract(progs, state, data, pv, l2, live, buffers, E)
-                keep = np.argsort(done, kind="stable")[:new_width]
-                idx = jnp.asarray(keep, dtype=jnp.int32)
-                state, data, pv = progs.compact((state, data, pv), idx)
-                live = live[keep]
-                its_before = its_after[keep]
-                width = new_width
-            else:
-                its_before = its_after
-    else:
-        _scatter_extract(progs, state, data, pv, l2, live, buffers, E)
+        # a tile's while_loop runs until its slowest lane stops
+        advance = (its - its_before)[live_idx[: n_tiles * T]]
+        executed += T * int(advance.reshape(n_tiles, T).max(axis=1).sum())
+        done = (reasons != _NOT_CONVERGED) | (its >= max_iterations)
+        n_live = E - int(np.sum(done))
+        if n_live == 0:
+            break
+        order = np.argsort(done, kind="stable").astype(np.int32)
+        n_tiles = -(-n_live // T)
 
-    sr_kwargs = {
-        f.name: (jnp.asarray(buffers[f.name]) if f.name in buffers else None)
-        for f in dataclasses.fields(SolveResult)
-    }
-    res_full = SolveResult(**sr_kwargs)
-    w_full = jnp.asarray(buffers["__w_masked"])
-    var_full = jnp.asarray(buffers["__var"]) if "__var" in buffers else None
-
-    its = buffers["iterations"].astype(np.int64)
-    reasons_full = buffers["reason"]
-    max_its = int(its.max()) if its.size else 0
+    res, w, var = progs.extract(state, data, bucket.proj_valid, l2)
+    reasons = np.asarray(jax.device_get(res.reason))
+    max_its = int(its.max())  # E > min_lanes >= 1 lanes: never empty
     stats = SolverStats(
         bucket=bucket_index,
         optimizer=progs.kind,
@@ -290,16 +282,16 @@ def _solve_bucket_adaptive(
         rounds=len(widths),
         chunk_iters=K,
         dispatch_widths=tuple(widths),
-        iterations_p50=float(np.percentile(its, 50)) if its.size else 0.0,
-        iterations_p99=float(np.percentile(its, 99)) if its.size else 0.0,
+        iterations_p50=float(np.percentile(its, 50)),
+        iterations_p99=float(np.percentile(its, 99)),
         iterations_max=max_its,
         sum_entity_iterations=int(its.sum()),
         executed_lane_iterations=int(executed),
         lockstep_lane_iterations=E * max_its,
-        converged=int(np.sum(reasons_full != _NOT_CONVERGED)),
+        converged=int(np.sum(reasons != _NOT_CONVERGED)),
         chunk_retraces=_TRACE_COUNTS[("re_chunk", progs.kind)] - retrace0,
     )
-    return res_full, w_full, var_full, stats
+    return res, w, var, stats
 
 
 def _solve_bucket_oneshot(
@@ -351,17 +343,17 @@ def train_random_effects(
     RandomEffectOptimizationTracker equivalent).
 
     When ``configuration.adaptive.enabled`` each bucket runs through the
-    convergence-adaptive driver (chunked rounds + pow2 lane compaction);
-    sharded buckets and buckets at/below ``adaptive.min_lanes`` fall back to
+    convergence-adaptive driver (chunked rounds over tiles of the live
+    lanes); sharded buckets and buckets at/below ``adaptive.min_lanes`` fall back to
     the one-shot lockstep dispatch, whose results are identical. If
     ``stats_out`` is given, one :class:`SolverStats` per bucket is appended.
 
     ``overlap_buckets >= 2`` overlaps that many bucket solves on worker
     threads (the async CD schedule's RE leg): while one bucket's adaptive
-    driver blocks on its converged-mask pull or runs host-side lane
-    compaction bookkeeping, another bucket's chunk dispatches keep the
-    device busy. Bucket solves are mutually independent and the programs
-    come from the same pow2 registry, so per-bucket results are
+    driver blocks on its converged-mask pull or orders the live lanes on
+    the host, another bucket's chunk dispatches keep the device busy.
+    Bucket solves are mutually independent and the programs come from the
+    same per-shape registry, so per-bucket results are
     bitwise-identical to the sequential path and no new retraces are
     introduced. Sharded (multi-device) buckets force the sequential path —
     collectives must be issued in one global order.
@@ -385,7 +377,7 @@ def train_random_effects(
     def _solve_one(b, bucket, w0, use_adaptive):
         if use_adaptive:
             return _solve_bucket_adaptive(
-                progs, bucket, w0, l2, l1, max_iter, adaptive.min_lanes, b
+                progs, bucket, w0, l2, l1, max_iter, b
             )
         return _solve_bucket_oneshot(progs, bucket, w0, l2, l1, b)
 

@@ -117,9 +117,10 @@ class AdaptiveSolveConfig:
 
     The driver runs the vmap'd per-entity solve in chunks of ``chunk_iters``
     outer iterations, pulls the per-lane converged mask after each chunk,
-    compacts unconverged entities into a dense prefix, and re-dispatches at
-    the next smaller power-of-two lane count. Compiled-program count per
-    (optimizer, bucket shape) is therefore bounded by the pow2 ladder.
+    and hands the next chunk the live lanes as an index vector and a count
+    of tiles: the one chunk program of a bucket shape loops over that many
+    tiles of the bucket's lanes. A bucket shape therefore compiles one
+    chunk program, whatever its rounds' live counts.
     ``enabled=False`` restores the one-shot lockstep dispatch exactly.
     """
 
@@ -127,8 +128,9 @@ class AdaptiveSolveConfig:
     # Outer solver iterations per chunk. Small K pulls the converged mask
     # often (more savings on skewed workloads) at the cost of more dispatches.
     chunk_iters: int = 8
-    # Stop shrinking below this lane count: tiny dispatches are dominated by
-    # launch overhead, so the tail just runs lockstep at this width.
+    # Floor of a tile's lane count (a tile is otherwise a fixed fraction of
+    # the bucket's width): tiny tiles are dominated by launch overhead.
+    # Buckets at or below it run the one-shot lockstep program.
     min_lanes: int = 8
 
     def __post_init__(self) -> None:
@@ -147,8 +149,8 @@ class GlmOptimizationConfiguration:
     regularization: RegularizationContext = RegularizationContext()
     regularization_weight: float = 0.0
     down_sampling_rate: float = 1.0
-    # Convergence-adaptive random-effect solving (chunked rounds + lane
-    # compaction); only consulted by train_random_effects.
+    # Convergence-adaptive random-effect solving (chunked rounds over
+    # tiles of the live lanes); only consulted by train_random_effects.
     adaptive: AdaptiveSolveConfig = AdaptiveSolveConfig()
 
     def __post_init__(self) -> None:

@@ -140,7 +140,8 @@ class SolverStats:
     """Per-bucket telemetry from the convergence-adaptive RE driver.
 
     ``executed_lane_iterations`` counts iterations actually dispatched
-    (Σ over rounds of width × chunk-advance); ``lockstep_lane_iterations``
+    (Σ over rounds and tiles of tile lanes × the tile's largest advance: a
+    tile runs until its slowest lane stops); ``lockstep_lane_iterations``
     is what the one-shot vmap would have executed (num_entities × slowest
     entity's iteration count) — their ratio is the adaptive win.
     """
@@ -150,7 +151,7 @@ class SolverStats:
     num_entities: int
     rounds: int
     chunk_iters: int
-    dispatch_widths: tuple         # lane count per round (pow2 ladder)
+    dispatch_widths: tuple         # lanes per round (tiles × tile lanes)
     iterations_p50: float
     iterations_p99: float
     iterations_max: int
@@ -158,7 +159,8 @@ class SolverStats:
     executed_lane_iterations: int
     lockstep_lane_iterations: int
     converged: int                 # entities with reason != NOT_CONVERGED
-    chunk_retraces: int            # jit trace count for chunk programs
+    chunk_retraces: int            # chunk traces this solve caused (1 for
+                                   # a bucket shape's first, then 0)
 
     @property
     def wasted_lane_fraction(self) -> float:

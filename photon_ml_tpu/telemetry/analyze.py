@@ -889,8 +889,10 @@ def format_report(report: RunReport) -> str:
             "",
             "  solver join: "
             f"{s['buckets']} bucket(s), {s['entities']} entities, "
-            f"{s['rounds']} adaptive round(s)",
-            f"    lane iterations executed/lockstep: "
+            f"{s['rounds']} adaptive round(s), "
+            f"{s['chunk_retraces']} chunk trace(s) (one per bucket shape "
+            "the process had not solved before)",
+            f"    lane iterations executed (per tile)/lockstep: "
             f"{s['executed_lane_iterations']}/{s['lockstep_lane_iterations']}"
             + (
                 f" (savings {s['lane_iteration_savings']}x)"

@@ -114,9 +114,11 @@ register_knob(KnobSpec(
     ),
     candidates=(4, 8, 16, 32),
     description=(
-        "Smallest compacted lane count an adaptive round may shrink to. "
-        "Lower values squeeze out more wasted lanes per round but add "
-        "compaction rounds (and retraces for new lane shapes)."
+        "Floor of the lane count of a tile of the adaptive chunk program "
+        "(a tile is a sixteenth of the bucket's width where that is more); "
+        "buckets at or below it solve one-shot. Lower values waste fewer "
+        "lanes in a small bucket's last tiles at more launch overhead per "
+        "lane. One compiled program per bucket shape at any value."
     ),
 ))
 

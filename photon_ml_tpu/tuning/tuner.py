@@ -130,18 +130,18 @@ def _propose_one(spec: KnobSpec, report: RunReport) -> KnobProposal:
             if savings < 1.2 and idx > 0:
                 value = ladder[idx - 1]
                 why = (
-                    "low lane-iteration savings — allow compaction to shrink "
-                    "further so converged lanes stop burning device time"
+                    "low lane-iteration savings — allow smaller tiles so "
+                    "converged lanes stop burning device time"
                 )
             elif rounds > 0 and savings >= 2.0 and idx + 1 < len(ladder):
                 value = ladder[idx + 1]
                 why = (
-                    f"{int(rounds)} compaction rounds for {savings:.2f}x "
+                    f"{int(rounds)} adaptive rounds for {savings:.2f}x "
                     "savings — a higher floor trades a little lane waste for "
-                    "fewer rounds and retraced shapes"
+                    "fewer, wider tiles a round"
                 )
             else:
-                why = "compaction cadence looks balanced at the default floor"
+                why = "tile width looks balanced at the default floor"
         elif share:
             why = f"RE solve is only {share:.0%} of wall-clock; not worth moving"
 

@@ -189,7 +189,7 @@ def test_update_offsets_residual_trick(rng):
 
 def test_adaptive_driver_matches_oneshot_across_buckets(rng):
     """End-to-end over multiple size buckets: the convergence-adaptive driver
-    (chunked rounds + lane compaction, on by default) and the forced one-shot
+    (chunked rounds over tiles of live lanes, on by default) and the forced one-shot
     lockstep path must produce the same exported per-entity rows."""
     import dataclasses
 

@@ -23,11 +23,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from photon_ml_tpu.estimators import random_effect
 from photon_ml_tpu.losses.objective import make_glm_objective
 from photon_ml_tpu.losses.pointwise import LogisticLoss, SquaredLoss
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.ops import fused_perm, permute_net, sparse_perm
 from photon_ml_tpu.ops.data import LabeledData
+from photon_ml_tpu.ops.features import DenseFeatures
 from photon_ml_tpu.ops.pallas_kernels import fused_value_grad_single
 from photon_ml_tpu.opt.config import (
     GlmOptimizationConfiguration,
@@ -35,6 +37,7 @@ from photon_ml_tpu.opt.config import (
 )
 from photon_ml_tpu.opt.solve import solve
 from photon_ml_tpu.stat.summary import summarize
+from photon_ml_tpu.types import TaskType
 
 ENGINES = {"fused": fused_perm, "benes": sparse_perm}
 OPS = ("matvec", "rmatvec", "rmatvec_sq")
@@ -214,3 +217,28 @@ def test_random_effect_kernel_compiles(v5e, batched):
         for shape in ((s, d), (s,), (s,), (s,), (d,))
     )
     assert compile_for_tpu(jax.vmap(fn) if batched else fn, v5e, *args) == 1
+
+
+def test_adaptive_chunk_program_compiles_at_the_cell_width(v5e):
+    """The adaptive random-effect driver's one chunk program of a bucket
+    shape, at the benchmark's per-user bucket: a loop over a run-time count
+    of tiles that gathers 1,024 of the 16,384 lanes, runs the vmapped L-BFGS
+    chunk on them and scatters them back."""
+    E, S, D = 16384, 100, 16
+    cfg = GlmOptimizationConfiguration(
+        optimizer_config=OptimizerConfig.lbfgs(max_iterations=20),
+        regularization_weight=1.0,
+    )
+    progs = random_effect._re_programs(TaskType.LOGISTIC_REGRESSION, cfg, False)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    data = LabeledData(
+        features=DenseFeatures(matrix=f32((E, S, D))),
+        labels=f32((E, S)), offsets=f32((E, S)), weights=f32((E, S)), norm=None,
+    )
+    state = jax.eval_shape(progs.init, f32((E, D)), data, f32(()), f32(()))
+    T = random_effect._tile_lanes(E, cfg.adaptive.min_lanes)
+    assert T == 1024
+    live_idx = jax.ShapeDtypeStruct((E,), jnp.int32)
+    n_tiles = jax.ShapeDtypeStruct((), jnp.int32)
+    # no Mosaic kernel: gathers, scatters and the solver's XLA loops
+    assert compile_for_tpu(progs.chunk, v5e, state, data, f32(()), live_idx, n_tiles) == 0
