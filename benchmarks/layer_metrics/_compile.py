@@ -7,7 +7,7 @@ zero-length ``jit/cache`` with ``hit``.
 Every time here is a *union* of intervals cut to the measured window, never a
 sum of durations: the events nest and overlap (a trace inside a lowering,
 two threads compiling at once), and a sum of them is not a time
-(``compile_s.step`` reads 40.5 s in a 39.4 s step; ledger, PR 25). Host
+(JAX's own event durations summed to 40.5 s in a 39.4 s step; ledger, PR 25). Host
 seconds. A program without these spans (any commit before PR 26) gives every
 reader here ``None``."""
 

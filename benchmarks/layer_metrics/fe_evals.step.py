@@ -8,6 +8,5 @@ NAME, UNIT, SOURCE = "fe_evals.step", "count/step", "program_counter"
 
 
 def read(context):
-    counts = [s["attrs"]["evaluations"] for s in _spans.in_window(context, "glm/solve")
-              if s["attrs"].get("evaluations") is not None]
+    counts = [a["evaluations"] for a in _spans.window_solves(context)]
     return sum(counts) / context["steps"] if counts else None

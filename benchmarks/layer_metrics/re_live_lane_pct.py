@@ -7,7 +7,7 @@ NAME, UNIT, SOURCE = "re_live_lane_pct", "%", "program_counter"
 
 
 def read(context):
-    lanes = [lane for c in _spans.window_counters(context) for lane in c.get("re_lanes", [])]
+    lanes = _spans.window_lanes(context)
     executed = sum(lane["executed"] for lane in lanes)
     if not executed:
         return None

@@ -5,13 +5,16 @@ bound it) over the summed device time of the routed-map Pallas kernels in
 the trace (tracing.group_name: custom calls to ``tpu_custom_call`` whose
 operands are plan indices).
 
-The count of maps is a lower bound: two an iteration of every fixed-effect
-solve plus two for its first evaluation; line-search retries and the
-score-plane matvecs run the same kernels and are in the time but not in the
-count, and the spill side's scatter-add and the maps' XLA prologue are in
-neither. So the share reads low, never high. None where the trace names no
-such kernel (a later PR that renames or removes them leaves this silent;
-``step_mfu`` still bounds the step)."""
+The count is the algorithm's minimum (work.fe_maps): two maps a
+value-and-gradient and two a Hessian-vector product, from the ``evaluations``
+and ``hessian_vecs`` of the window's ``glm/solve`` spans. The time is every
+call's: a map the program makes beyond those (``hessian_vec`` computes the
+margins again at every product, the score-plane matvecs after a solve) is in
+the time and not in the count, and the spill side's scatter-add and the maps'
+XLA prologue are in neither. So the share reads low, never high. None where
+the trace names no such kernel (a later PR that renames or removes them
+leaves this silent; ``step_mfu`` still bounds the step) or the spans carry
+no counts."""
 from benchmarks import work
 from benchmarks.layer_metrics import _spans
 
@@ -24,11 +27,9 @@ def read(context):
     if trace is None or peaks is None:
         return None
     seconds = trace["self_times"].get(KERNELS)
-    iterations = [c["fe_iterations"] for c in _spans.window_counters(context)
-                  if c.get("fe_iterations") is not None]
-    if not seconds or not iterations:
+    maps = work.fe_maps(_spans.window_solves(context))
+    if not seconds or not maps:
         return None
-    maps = sum(2 * (i + 1) for i in iterations)
     shapes = context["shapes"]
     flops, nbytes = work.fe_map(shapes["nnz"], shapes["n_rows"], shapes["n_cols"])
     least, _ = work.least_seconds(maps * flops, maps * nbytes, peaks)

@@ -1,55 +1,9 @@
-"""Host-side meters: JAX's own compile events, and the device's memory.
-
-``CompileMeter`` is chip_smoke.py's (PR 21), copied so that the yardstick
-lives with the benchmark.
-"""
+"""Host-side meter: the device's memory over a run. (Compile time is read
+from the program's own ``jit/*`` spans: layer_metrics/_compile.py.)"""
 
 from __future__ import annotations
 
 import threading
-import time
-
-
-class CompileMeter:
-    """Trace, lowering and backend-compile seconds as JAX reports them
-    (jax.monitoring), stamped with the host clock so they can be cut by
-    window, plus the persistent cache's hits and misses."""
-
-    _DURATIONS = (
-        "/jax/core/compile/jaxpr_trace_duration",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    )
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.events = []  # (perf_counter at end, seconds, is_backend_compile)
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, seconds, **_):
-        if event in self._DURATIONS:
-            self.events.append(
-                (time.perf_counter(), seconds, event == self._DURATIONS[2])
-            )
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
-
-    def window(self, start: float, end: float):
-        """(compile seconds, programs compiled) inside [start, end]."""
-        inside = [e for e in self.events if start <= e[0] <= end]
-        return sum(e[1] for e in inside), sum(1 for e in inside if e[2])
-
-    def intervals(self, start: float, end: float):
-        """[(start, end)] of every compile event that ended inside the window."""
-        return [(e[0] - e[1], e[0]) for e in self.events if start <= e[0] <= end]
 
 
 class MemoryMeter:

@@ -155,13 +155,18 @@ class GlmixReference:
         )
 
     # -- the descent ----------------------------------------------------
-    def run(self, steps: int, log=None) -> List[Snapshot]:
-        """``steps`` outer iterations from the zero model; a snapshot after
-        every block update."""
+    def run(self, steps: int, log=None, start: Optional[Snapshot] = None) -> List[Snapshot]:
+        """``steps`` outer iterations from the zero model (or from the model
+        of ``start``: a fit that is handed its predecessor's); a snapshot
+        after every block update."""
         fixed: Optional[jax.Array] = None
         random: Dict[str, jax.Array] = {}
         scores: Dict[str, jax.Array] = {}
         total = jnp.zeros_like(self.labels)
+        if start is not None:
+            fixed, random = start.fixed, dict(start.random)
+            scores = self.train_scores(fixed, random)
+            total = sum(scores.values())
         out: List[Snapshot] = []
         for step in range(steps):
             for cid in self.order:

@@ -169,11 +169,11 @@ def main(argv=None) -> int:
     say(f"compile cache {start.compile_cache} ; plan cache {start.caches['plans']}")
 
     from benchmarks import compare, meter as meters, tracing, work
+    from benchmarks.layer_metrics._compile import PHASES as COMPILE_PHASES
     from benchmarks.traffic.steps import Window
 
     memory_meter = meters.MemoryMeter().start()
     tracer = None
-    compile_meter = meters.CompileMeter() if args.trace else None
     if args.trace:
         from photon_ml_tpu.telemetry import enable_tracing
 
@@ -216,8 +216,7 @@ def main(argv=None) -> int:
         from photon_ml_tpu.telemetry import disable_tracing
 
         disable_tracing()
-    ends = [window.start] + window.step_ends
-    say("steps " + " ".join(f"{b - a:.2f}s" for a, b in zip(ends, ends[1:])))
+    say("steps " + " ".join(f"{s:.2f}s" for s in window.step_seconds))
     say(f"window {window.length:.3f}s, {window.steps} step(s), set-up {setup_s:.1f}s, "
         f"peak {peak:,} bytes held at one instant ({memory})")
 
@@ -240,6 +239,10 @@ def main(argv=None) -> int:
              "attrs": dict(s.attrs), "depth": s.depth}
             for s in tracer.spans()
         ]
+        compiles = [s for s in spans if s["name"] in COMPILE_PHASES
+                    and s["end"] > window.start and s["start"] < window.end]
+        say(f"compile spans of any phase inside the window: {len(compiles)}"
+            + "".join(f" {s['name']}:{s['attrs'].get('fun_name')}" for s in compiles[:8]))
         if not rehearsal:
             path = tracing.find_xplane(trace_dir)
             events, mark = tracing.load_events(path)
@@ -250,13 +253,14 @@ def main(argv=None) -> int:
             reduced = tracing.reduce_trace(events, window_ns, chips)
             if reduced["busy_s"] <= 0:
                 raise RuntimeError("no operation ran on the device inside the traced window")
-            for a, b in compile_meter.intervals(window.start, window.end):
-                host_spans.append((to_trace(a), to_trace(b), "compile (trace, lower, backend)"))
             for s in sorted(spans, key=lambda s: s["depth"]):
                 if s["end"] >= window.start and s["start"] <= window.end:
-                    label = s["name"] + (
-                        f"[{s['attrs']['coordinate']}]" if "coordinate" in s["attrs"] else ""
-                    )
+                    if s["name"] in COMPILE_PHASES:
+                        label = "compile (trace, lower, backend)"
+                    else:
+                        label = s["name"] + (
+                            f"[{s['attrs']['coordinate']}]" if "coordinate" in s["attrs"] else ""
+                        )
                     host_spans.append((to_trace(s["start"]), to_trace(s["end"]), label))
             # compiles are the innermost host activity: let them win ties
             host_spans.sort(key=lambda h: h[2].startswith("compile"))
@@ -271,7 +275,7 @@ def main(argv=None) -> int:
             shutil.rmtree(trace_dir, ignore_errors=True)
         context = {
             "window": (window.start, window.end), "steps": window.steps,
-            "window_s": window.length, "spans": spans, "compile": compile_meter,
+            "window_s": window.length, "spans": spans,
             "counters": driver.step_counters, "shapes": driver.work_shapes(),
             "times": getattr(driver, "times", {}), "trace": reduced,
             "peaks": None if rehearsal else work.load_peaks(device.device_kind),

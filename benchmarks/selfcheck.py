@@ -54,10 +54,14 @@ def check_work():
         pass
     else:
         raise AssertionError("an unknown device must be an error")
-    counters = [{"fe_iterations": 9, "re_lanes": [{"samples": 10, "dim": 16, "live": 3.0, "executed": 8}]}]
-    flops, nbytes = work.step_work({"nnz": 5, "n_rows": 3, "n_cols": 4}, counters)
-    close(flops, 10 * 20 + 3 * 640)
-    close(nbytes, 10 * (80 + 88) + 3 * 640)
+    # one step of two solves: 6 + 4 evaluations, 3 Hessian-vector products
+    solves = [{"iterations": 5, "evaluations": 6, "hessian_vecs": 0},
+              {"iterations": 3, "evaluations": 4, "hessian_vecs": 3}]
+    lanes = [{"samples": 10, "dim": 16, "live": 3.0, "executed": 8}]
+    assert work.fe_maps(solves) == 2 * (6 + 4 + 3)
+    flops, nbytes = work.step_work({"nnz": 5, "n_rows": 3, "n_cols": 4}, solves, lanes)
+    close(flops, 10 * 20 + 2 * 3 * 10 + 3 * 640)
+    close(nbytes, 10 * (80 + 88) + 2 * 3 * (40 + 28) + 3 * 640)
 
 
 def check_trace_by_hand():
@@ -124,8 +128,13 @@ def check_compare():
     # median leaf norm is 1: leaf b is held against it, not against 1e-6
     gap = compare.worst_leaf_norm_gap(prog, ref)
     close(gap, (math.hypot(3, 4.1) - 5.0) / 5.0)
-    assert compare.picked_update([0.7, 0.8, 0.8], first_fit=False) == 1
-    assert compare.picked_update([0.9, 0.8, 0.7], first_fit=True) == 2
+    # a fit from nothing over three coordinates: updates 0 and 1 hold no full model
+    assert compare.picked_update([0.9, 0.8, 0.7], complete_from=2) == 2
+    assert compare.picked_update([0.9, 0.8, 0.7, 0.75, 0.75, 0.6], complete_from=2) == 3
+    assert compare.picked_update([0.7, 0.8, 0.8], complete_from=0) == 1
+    close(compare.repeat_gap([{"objective": [4.0, 2.0]}, {"objective": [4.0, 2.0]},
+                              {"objective": [4.0, 2.5]}])["repeat_gap"], 0.25)
+    assert compare.repeat_gap([{"objective": [4.0, 2.0]}, {"objective": [4.0]}])["repeat_gap"] == math.inf
     ok, rows = compare.verdict({"x": 1.0, "y": float("nan")}, {"x": 2.0, "y": 1.0})
     assert not ok
     ok, _ = compare.verdict({"x": 1.0}, {"x": 2.0})

@@ -1,13 +1,17 @@
 """Faults planted in the timed path, for the tests and for the readings on
 the chip: each has to make ``correct`` come out false.
 
-- ``unchanged``: a step returns its state unchanged. For ``cd_train`` every
-  coordinate update of a warm-started step hands back the model it was
-  given; for ``refit`` the fit hands back the zero model it started from.
+- ``unchanged``: a step returns its state unchanged. For ``cd_fit`` every
+  coordinate update that is handed a model (those of a fit's second outer
+  iteration) hands it back; for ``refit`` the fit hands back the zero model
+  it started from.
 - ``half_batch``: the program's entry leaves out the second half of the
   training rows and fits the rest; the reference keeps all of them. For
-  ``cd_train``, ``GameEstimator.fit_multiple`` slices its data to the first
+  ``cd_fit``, ``GameEstimator.fit_multiple`` slices its data to the first
   half; for ``refit``, ``train_glm`` gives the second half the weight 0.
+- ``carried_over`` (``cd_fit`` alone): a fit starts from the model its
+  predecessor returned and not from nothing: ``fit_multiple`` runs with
+  ``warm_start=True`` whatever it was asked.
 
 One chip, one program, no token: the exchange between chips and an altered
 answer are not faults these cells can have.
@@ -22,14 +26,25 @@ from unittest import mock
 import numpy as np
 
 FAULTS = ("unchanged", "half_batch")
+CARRIED_OVER = "carried_over"
 
 
 @contextlib.contextmanager
 def planted(fault: str, driver_module):
-    if fault not in FAULTS:
-        raise ValueError(f"unknown fault {fault!r}; have {FAULTS}")
-    cd_train = driver_module.__name__.endswith("cd_train")
-    if fault == "half_batch" and cd_train:
+    cd_fit = driver_module.__name__.endswith("cd_fit")
+    if fault not in FAULTS and not (cd_fit and fault == CARRIED_OVER):
+        raise ValueError(f"unknown fault {fault!r}; have {FAULTS}, and {CARRIED_OVER} for cd_fit")
+    if fault == CARRIED_OVER:
+        from photon_ml_tpu.estimators.game import GameEstimator
+
+        real_fit = GameEstimator.fit_multiple
+
+        def warm_started_fit(self, *args, **kwargs):
+            return real_fit(self, *args, **{**kwargs, "warm_start": True})
+
+        with mock.patch.object(GameEstimator, "fit_multiple", warm_started_fit):
+            yield
+    elif fault == "half_batch" and cd_fit:
         from photon_ml_tpu.estimators.game import GameEstimator
 
         real_fit = GameEstimator.fit_multiple
@@ -52,7 +67,7 @@ def planted(fault: str, driver_module):
 
         with mock.patch.object(M, "train_glm", first_half_train):
             yield
-    elif cd_train:
+    elif cd_fit:
         from photon_ml_tpu.algorithm import coordinate as C
 
         def unchanged(real):

@@ -1,8 +1,9 @@
 """The cell ``fe-linear-tron.refit-norm`` at its rehearsal size, on the CPU:
 a sound run is correct; the control (the reference at bfloat16) and each
-stand-in and planted fault are not; the three readers this cell brings
-(``fe_hvs.step``, ``hv_map_roofline``, ``summarize_s``) on a hand-made
-``context``, and silent on what a program without the counts hands over.
+stand-in and planted fault are not; the readers this cell brought
+(``fe_hvs.step``, ``summarize_s``) and the maps' roofline counting its
+Hessian-vector products (``sparse_map_roofline``) on a hand-made ``context``,
+and silent on what a program without the counts hands over.
 
     JAX_PLATFORMS=cpu python -m pytest benchmarks/tests/test_refit_norm.py -q
 """
@@ -23,7 +24,8 @@ from benchmarks.tests import faults  # noqa: E402
 from benchmarks.traffic import refit_norm  # noqa: E402
 
 REHEARSAL = "fe-linear-tron.refit-norm.tiny"
-NEW = ("fe_hvs.step", "hv_map_roofline", "summarize_s")
+NEW = ("fe_hvs.step", "sparse_map_roofline", "summarize_s")
+ONLY_HERE = ("fe_hvs.step", "summarize_s")
 READERS = {m.NAME: m for m in harness.list_layer_metrics() if m.NAME in NEW}
 
 
@@ -41,7 +43,7 @@ def test_sound_run_is_correct_and_reports_the_program_side_metrics(capsys):
     metrics = result["metrics"]
     assert {"fe_hvs.step", "summarize_s", "fe_evals.step", "fe_iterations.step",
             "fe_solve_s.step", "routing_prep_s"} <= set(metrics)
-    assert "hv_map_roofline" not in metrics  # a device metric: never from a CPU
+    assert "sparse_map_roofline" not in metrics  # a device metric: never from a CPU
     assert metrics["fe_hvs.step"]["value"] >= metrics["fe_iterations.step"]["value"] >= 1
     assert metrics["fe_evals.step"]["value"] == metrics["fe_iterations.step"]["value"] + 1
     assert metrics["fe_compile_s.step"]["value"] == 0.0  # no compile inside the window
@@ -50,10 +52,10 @@ def test_sound_run_is_correct_and_reports_the_program_side_metrics(capsys):
 @pytest.mark.parametrize("stand_in", refit_norm.STAND_INS)
 def test_stand_in_is_not_correct(stand_in):
     w = harness.load_workload(REHEARSAL)
-    config, limits = w["config_doc"], w["traffic_doc"]["limits"]
+    config, traffic, limits = w["config_doc"], w["traffic_doc"], w["traffic_doc"]["limits"]
     for seed in (21, 22, 23):
         problem = refit_norm.make_problem(config, seed)
-        kept = refit_norm.reference_run(config, problem)
+        kept = refit_norm.reference_run(config, traffic, problem)
         numbers = refit_norm.control_numbers(config, problem, *kept, stand_in=stand_in)
         ok, rows = compare.verdict(numbers, limits)
         assert not ok, rows
@@ -93,11 +95,11 @@ def test_readers_on_a_recorded_context():
     assert READERS["summarize_s"].read(ctx) == 7.5
     maps = 2 * (6 + 21) + 2 * (7 + 24)
     _, nbytes = work.fe_map(**SHAPES)
-    share = READERS["hv_map_roofline"].read(ctx)
+    share = READERS["sparse_map_roofline"].read(ctx)
     assert share == pytest.approx(100.0 * maps * nbytes / 819e9 / 6.0)
     assert 0 < share < 100
     # three kernel calls a product where the count has two: the share falls, never rises
-    assert READERS["hv_map_roofline"].read(context(RECORDED, kernels_s=9.0)) < share
+    assert READERS["sparse_map_roofline"].read(context(RECORDED, kernels_s=9.0)) < share
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -105,9 +107,10 @@ def test_reader_is_silent_without_the_counts(name):
     """What the parent commit hands over: ``glm/solve`` spans without
     ``hessian_vecs``, no ``glm/summarize``; and a trace without the kernels."""
     old = [span("glm/solve", 100.0, 105.0, iterations=5, evaluations=6)]
-    assert READERS[name].read(context(old)) is None
+    if name in ONLY_HERE:
+        assert READERS[name].read(context(old)) is None
     assert READERS[name].read(context([])) is None
-    if name == "hv_map_roofline":
+    if name == "sparse_map_roofline":
         renamed = dict(context(RECORDED), trace={"self_times": {"fusion": 1.0}})
         assert READERS[name].read(renamed) is None
         assert READERS[name].read(dict(context(RECORDED), trace=None)) is None
@@ -117,7 +120,8 @@ def test_every_new_metric_has_an_entry_for_this_cell_alone():
     with open(os.path.join(os.path.dirname(os.path.dirname(HERE)), "BENCHMARK.json")) as f:
         declared = {m["name"]: m for m in json.load(f)["per_layer"]}
     assert set(READERS) == set(NEW)
-    for name, reader in READERS.items():
-        entry = declared[name]
+    for name in ONLY_HERE:
+        entry, reader = declared[name], READERS[name]
         assert (entry["unit"], entry["source"]) == (reader.UNIT, reader.SOURCE)
         assert entry["workloads"] == ["fe-linear-tron.refit-norm"]
+    assert "workloads" not in declared["sparse_map_roofline"]

@@ -1,6 +1,8 @@
 """The readers of the program's compile spans and of the spans that cover a
 step (``layer_metrics/`` files added by PR 26), on a hand-made ``context``
-and in the two rehearsals:
+and in the two rehearsals; and the readers that count a step's work from the
+solver's own spans (``fe_iterations.step``, ``fe_evals.step``,
+``sparse_map_roofline``, ``step_mfu`` through ``work.step_work``):
 
     JAX_PLATFORMS=cpu python -m pytest benchmarks/tests/test_span_metrics.py -q
 
@@ -26,7 +28,7 @@ NEW = {
     "fe_compile_s.step", "fe_evals.step", "re_compile_s.step", "re_round_wait_s.step",
     "cd_compile_s.step", "step_unattributed_s.step",
 }
-ONLY_CD_TRAIN = {"re_compile_s.step", "re_round_wait_s.step", "cd_compile_s.step"}
+ONLY_CD_FIT = {"re_compile_s.step", "re_round_wait_s.step", "cd_compile_s.step"}
 READERS = {m.NAME: m for m in harness.list_layer_metrics() if m.NAME in NEW}
 
 
@@ -100,7 +102,7 @@ def test_every_new_metric_has_a_reader_and_an_entry():
         entry = declared[name]
         assert (entry["unit"], entry["source"]) == (reader.UNIT, reader.SOURCE)
         assert entry["moves"] == "train_step_s" and entry["better"] == "lower"
-        assert ("workloads" in entry) == (name in ONLY_CD_TRAIN)
+        assert ("workloads" in entry) == (name in ONLY_CD_FIT)
 
 
 @pytest.mark.parametrize("name", sorted(NEW))
@@ -141,15 +143,17 @@ def test_a_window_without_compiles_reads_zero_not_none():
     for name in ("retrace_s.step", "lower_s.step", "backend_compile_s.step",
                  "cache_misses.step", "fe_compile_s.step", "step_unattributed_s.step"):
         assert READERS[name].read(ctx) == 0.0
-    for name in ONLY_CD_TRAIN:
+    for name in ONLY_CD_FIT:
         assert READERS[name].read(ctx) is None
 
 
-@pytest.mark.parametrize("workload,silent", [
-    ("glmix-1b-chip.cd-train.tiny", set()),
-    ("fe-poisson-owlqn.refit.tiny", ONLY_CD_TRAIN),
+@pytest.mark.parametrize("workload,silent,window_compiles", [
+    # the cd-fit rehearsal's window holds compiles (the Pallas interpreter
+    # traces as it runs); a refit's holds none since PR 27
+    ("glmix-1b-chip.cd-fit.tiny", set(), True),
+    ("fe-poisson-owlqn.refit.tiny", ONLY_CD_FIT, False),
 ])
-def test_rehearsal_reports_every_new_metric(workload, silent, capsys):
+def test_rehearsal_reports_every_new_metric(workload, silent, window_compiles, capsys):
     rc = harness.main(["--workload", workload, "--seed", "2600000011", "--seconds", "1", "--trace", "1"])
     assert rc == 0
     result = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
@@ -162,5 +166,74 @@ def test_rehearsal_reports_every_new_metric(workload, silent, capsys):
     compile_union = metrics["fe_compile_s.step"]["value"] + sum(
         metrics[n]["value"] for n in ("re_compile_s.step", "cd_compile_s.step") if n in metrics)
     by_phase = sum(metrics[n]["value"] for n in ("retrace_s.step", "lower_s.step", "backend_compile_s.step"))
-    assert by_phase >= compile_union * (1 - 1e-9) > 0
+    assert by_phase >= compile_union * (1 - 1e-9) >= 0
+    assert (compile_union > 0) == window_compiles
     assert steps >= 1
+
+
+# -- a step's work, counted from the solver's own spans (PR 33) ---------------
+SHAPES = {"nnz": 1 << 24, "n_rows": 1 << 20, "n_cols": 40_000_000}
+PEAKS = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+LANE = {"coordinate": "per_user", "samples": 100, "dim": 16, "executed": 4096, "live": 3000.0}
+# window [100, 120]: one step (a fit) of two solves; the warm-up fit's lie before it
+TWO_SOLVES = [
+    span("glm/solve", 80.0, 85.0, iterations=8, evaluations=10, hessian_vecs=0),
+    span("glm/solve", 90.0, 93.0, iterations=5, evaluations=7, hessian_vecs=0),
+    span("glm/solve", 100.0, 105.0, iterations=8, evaluations=10, hessian_vecs=0),
+    span("glm/solve", 110.0, 113.0, iterations=5, evaluations=7, hessian_vecs=3),
+]
+
+
+def work_context(spans, kernels_s=2.0):
+    trace = {"self_times": {"pallas:routed_map_kernel": kernels_s, "fusion": 1.0}}
+    return {"window": (100.0, 120.0), "steps": 1, "window_s": 20.0, "spans": spans,
+            "counters": [{"re_lanes": [LANE]}, {"re_lanes": [LANE]}], "shapes": SHAPES,
+            "times": {}, "trace": trace, "peaks": PEAKS}
+
+
+def test_step_work_counts_the_evaluations_and_products_of_two_solves_in_one_step():
+    from benchmarks import work
+
+    by_name = {m.NAME: m for m in harness.list_layer_metrics()}
+    ctx = work_context(TWO_SOLVES)
+    assert by_name["fe_iterations.step"].read(ctx) == 8 + 5
+    assert by_name["fe_evals.step"].read(ctx) == 10 + 7
+    assert by_name["fe_hvs.step"].read(ctx) == 3
+    solves = [s["attrs"] for s in TWO_SOLVES[2:]]
+    assert work.fe_maps(solves) == 2 * (10 + 7 + 3)
+    flops, nbytes = work.step_work(SHAPES, solves, [LANE])
+    ef, eb = work.fe_evaluation(**SHAPES)
+    mf, mb = work.fe_map(**SHAPES)
+    lf, lb = work.re_lane_iteration(100, 16)
+    assert flops == pytest.approx(17 * ef + 2 * 3 * mf + 3000.0 * lf)
+    assert nbytes == pytest.approx(17 * eb + 2 * 3 * mb + 3000.0 * lb)
+    assert by_name["step_mfu"].read(ctx) == pytest.approx(100.0 * nbytes / 819e9 / 20.0)
+    share = by_name["sparse_map_roofline"].read(ctx)
+    assert share == pytest.approx(100.0 * 40 * mb / 819e9 / 2.0)
+    assert 0 < share < 100
+    # a third kernel call a product where the count has two: the share falls, never rises
+    assert by_name["sparse_map_roofline"].read(work_context(TWO_SOLVES, kernels_s=3.0)) < share
+
+
+@pytest.mark.parametrize("name", ["fe_iterations.step", "fe_evals.step", "sparse_map_roofline"])
+def test_work_readers_are_silent_without_the_solvers_counts(name):
+    by_name = {m.NAME: m for m in harness.list_layer_metrics()}
+    bare = [span("glm/solve", 100.0, 105.0)]     # an untraced solve sets no attrs
+    assert by_name[name].read(work_context(bare)) is None
+    assert by_name[name].read(work_context([])) is None
+    if name == "sparse_map_roofline":
+        renamed = dict(work_context(TWO_SOLVES), trace={"self_times": {"fusion": 1.0}})
+        assert by_name[name].read(renamed) is None
+        assert by_name[name].read(dict(work_context(TWO_SOLVES), trace=None)) is None
+
+
+def test_retired_metrics_are_gone():
+    names = {m.NAME for m in harness.list_layer_metrics()}
+    with open(os.path.join(os.path.dirname(os.path.dirname(HERE)), "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    declared = {m["name"] for m in doc["per_layer"]}
+    assert names == declared
+    assert not {"compile_s.step", "programs.step", "hv_map_roofline"} & names
+    cells = {w["name"] for w in doc["workloads"]}
+    for m in doc["per_layer"]:
+        assert set(m.get("workloads", [])) <= cells

@@ -1,15 +1,17 @@
-"""Traffic ``cd-train``: continued block coordinate descent on a GLMix
-configuration.
+"""Traffic ``cd-fit``: whole GLMix fits from the zero model, one after
+another on one prepared data set.
 
-A step is one outer CD iteration: fixed-effect solve, every random-effect
-solve, the score-plane updates, the training objective and the held-out AUC
-after each update. Warm-up is ``warmup_steps`` of them (the traffic file's;
-one: iteration 0 from the zero model). The entry is ``GameEstimator.fit_multiple(warm_start=
-True)`` with ``num_outer_iterations=1``: the public path that prepares the
-data once and continues each fit from the previous fit's models. Its
+A step is one whole fit: ``outer_iterations`` (the traffic file's; two)
+outer CD iterations over the update order, each a fixed-effect solve, every
+random-effect solve, the score-plane updates, the training objective and the
+held-out AUC after each update, and the best-AUC model returned. The entry
+is ``GameEstimator.fit_multiple(warm_start=False)``: the public path that
+prepares the data once and fits one model a configuration, each from
+nothing, as a sweep over one data set or a scheduled retrain does. Its
 ``configs`` argument is a sequence that this driver hands over lazily, one
-empty override map a step, until the window has closed; the estimator has no
-other stop hook. Data prep and the warm-up steps are set-up.
+empty override map a fit, until the window has closed; the estimator has no
+other stop hook. Every fit is the same work. Data prep and the warm-up fit
+with its compiles are set-up.
 """
 
 from __future__ import annotations
@@ -25,8 +27,8 @@ from benchmarks.traffic.steps import Window
 
 
 class _Steps:
-    """``configs`` for ``fit_multiple``: an empty override map per step,
-    for as long as the window lasts."""
+    """``configs`` for ``fit_multiple``: an empty override map a fit, for
+    as long as the window lasts."""
 
     def __init__(self, window: Window, warmup_steps: int):
         self.window, self.warmup_steps = window, warmup_steps
@@ -62,15 +64,17 @@ class _SolverStats:
         pass
 
 
-class Driver:
-    reference_steps = 2   # the reference follows steps 0 and 1
+make_problem = datagen.make_problem
+STAND_INS = ("bfloat16", "half_batch", "unchanged", "carried_over")
 
+
+class Driver:
     def __init__(self, config: dict, params: dict, seed: int, rehearsal: bool, log):
         self.config, self.params, self.seed = config, params, int(seed)
         self.rehearsal, self.log = rehearsal, log
         self.problem: Optional[datagen.Problem] = None
+        self.outer_iterations = int(params["outer_iterations"])
         self.step_counters: List[dict] = []
-        self.setup_spans: Dict[str, float] = {}
         self.fits = None
 
     # -- set-up -----------------------------------------------------------
@@ -123,7 +127,7 @@ class Driver:
 
         def lbfgs_l2(c: dict):
             if c["optimizer"] != "LBFGS" or c["regularization"] != "L2":
-                raise ValueError(f"cd-train drives L-BFGS + L2 coordinates, got {c}")
+                raise ValueError(f"cd-fit drives L-BFGS + L2 coordinates, got {c}")
             return GlmOptimizationConfiguration(
                 optimizer_config=OptimizerConfig.lbfgs(
                     max_iterations=int(c["max_iterations"]),
@@ -136,8 +140,9 @@ class Driver:
 
         class KeepsCoordinates(GameEstimator):
             """Keeps the coordinates it builds, so that the driver can read
-            the fixed effect's tracker and the bucket shapes between steps
-            (fit_multiple hands them to nobody)."""
+            the random effects' bucket shapes (fit_multiple hands the
+            coordinates to nobody, and a solver-stats event names its bucket
+            by index)."""
 
             def __init__(self, *args, **kwargs):
                 super().__init__(*args, **kwargs)
@@ -173,7 +178,7 @@ class Driver:
             task=TaskType[self.config["task"]],
             coordinates=coordinates,
             update_order=self.config["update_order"],
-            num_outer_iterations=1,
+            num_outer_iterations=self.outer_iterations,
             emitter=emitter,
         )
 
@@ -189,22 +194,20 @@ class Driver:
         window.on_step = on_step
         self.fits = self.estimator.fit_multiple(
             self.train, validation_data=self.held_out,
-            configs=_Steps(window, int(self.params["warmup_steps"])), warm_start=True,
+            configs=_Steps(window, int(self.params["warmup_steps"])), warm_start=False,
         )
         if self.emitter.listener_errors:
             raise RuntimeError("an event listener raised during the fit")
 
     def _read_counters(self) -> dict:
-        """What the last step counted: fixed-effect iterations from the
-        solver's tracker, random-effect lane-iterations from the events the
-        CD driver sent since the previous step."""
+        """What the last fit's random effects counted: lane-iterations from
+        the events the CD driver sent since the previous fit. (The fixed
+        effect's counts are on the program's ``glm/solve`` spans.)"""
         built = self.estimator.built
-        tracker = getattr(built.get("fixed"), "last_tracker", None)
         events, self.stats.events = self.stats.events, []
         lanes = []
         for e in events:
-            coord = built.get(e.coordinate_id)
-            bucket = coord.dataset.buckets[e.bucket]
+            bucket = built[e.coordinate_id].dataset.buckets[e.bucket]
             lanes.append({
                 "coordinate": e.coordinate_id,
                 "samples": int(bucket.X.shape[1]),
@@ -212,10 +215,7 @@ class Driver:
                 "executed": int(e.executed_lane_iterations),
                 "live": float(e.executed_lane_iterations) * (1.0 - float(e.wasted_lane_fraction)),
             })
-        return {
-            "fe_iterations": None if tracker is None else int(tracker.states.iterations),
-            "re_lanes": lanes,
-        }
+        return {"re_lanes": lanes}
 
     def end_to_end(self, window: Window) -> dict:
         return window.train_step_s()
@@ -230,10 +230,10 @@ class Driver:
 
     # -- what the timed path produced -------------------------------------
     def collect(self) -> None:
-        """Host copies of what the check needs; then the program's state
-        can go."""
+        """Host copies of what the check needs: every fit's readings, and
+        the models the warm-up fit and the window's last fit returned; then
+        the program's state can go."""
         fits = self.fits
-        keep = sorted({0, min(1, len(fits) - 1), len(fits) - 1})
         self.histories = [
             {
                 "objective": [float(v) for _, v in f.objective_history],
@@ -241,7 +241,8 @@ class Driver:
             }
             for f in fits
         ]
-        self.models = {i: self._host_model(fits[i].model.models) for i in keep}
+        self.models = {i: self._host_model(fits[i].model.models)
+                       for i in sorted({0, len(fits) - 1})}
 
     def _host_model(self, models: dict) -> dict:
         out = {"fixed": np.asarray(models["fixed"].coefficients.means)}
@@ -269,38 +270,34 @@ class Driver:
 
     # -- correct ------------------------------------------------------------
     def check(self) -> Dict[str, float]:
-        """The numbers compared (compare.py has the arithmetic): the
-        program's first steps against the plain reference's, and every kept
-        model a step returned (steps 0, 1 and the window's last), scored by
-        the reference."""
-        from benchmarks import compare
-        from benchmarks.reference.glmix import GlmixReference
-
-        ref = GlmixReference(self.config, self.problem, "float32")
-        steps = min(self.reference_steps, len(self.histories))
-        snaps = ref.run(steps, log=self.log)
-        self.kept_reference = (ref, snaps)  # for control_numbers (calibrate.py)
-        numbers = compare.training_numbers(
-            program_histories=self.histories[:steps],
-            program_models={s: self.models[s] for s in range(steps)},
-            reference_steps=_by_step(snaps, self.config, steps), log=self.log,
-        )
-        numbers.update(compare.scored_objective_gap(
-            self.histories, self.models, lambda m: _evaluate(ref, m)))
-        return numbers
+        """The numbers compared (compare.py has the arithmetic): the warm-up
+        fit and the window's last fit, which are the same work, against the
+        plain reference's run of as many outer iterations from the zero
+        model; their returned models scored by the reference; and every fit
+        of the window against the warm-up fit."""
+        # kept for control_numbers (calibrate.py)
+        self.kept_reference = reference_run(self.config, self.params, self.problem, self.log)
+        return _numbers(self.config, *self.kept_reference, self.histories, self.models, self.log)
 
 
-def reference_run(config: dict, problem, log=None):
-    """(the float32 reference, its run over the compared steps)."""
+def reference_run(config: dict, params: dict, problem, log=None):
+    """(the float32 reference, its run of one fit: as many outer iterations
+    as the traffic file gives a fit)."""
     from benchmarks.reference.glmix import GlmixReference
 
     ref = GlmixReference(config, problem, "float32")
-    return ref, ref.run(Driver.reference_steps, log=log)
+    return ref, ref.run(int(params["outer_iterations"]), log=log)
 
 
-def _by_step(snaps, config, steps):
-    per = len(config["update_order"])
-    return [snaps[s * per:(s + 1) * per] for s in range(steps)]
+def _numbers(config, ref, snaps, histories, models, log=None) -> Dict[str, float]:
+    from benchmarks import compare
+
+    complete_from = len(config["update_order"]) - 1
+    numbers = compare.training_numbers(histories, models, snaps, complete_from, log)
+    numbers.update(compare.scored_gaps(
+        histories, models, complete_from, lambda m: _evaluate(ref, m)))
+    numbers.update(compare.repeat_gap(histories))
+    return numbers
 
 
 def _evaluate(ref, model):
@@ -310,45 +307,50 @@ def _evaluate(ref, model):
 def control_numbers(config: dict, problem, reference, reference_snaps, stand_in: str = "bfloat16",
                     log=None) -> Dict[str, float]:
     """A stand-in put in the program's place and compared as the program
-    is; each has to come out as not correct. ``reference`` and
+    is: its run is the warm-up fit and the window's last fit alike, but for
+    ``carried_over``. Each has to come out as not correct. ``reference`` and
     ``reference_snaps`` are the float32 reference and its run (Driver.check
     keeps them).
 
     - "bfloat16": the control. The reference computed in bfloat16.
     - "half_batch": the float32 reference given the first half of the
       training rows (half of the batch left out).
-    - "unchanged": the float32 reference whose second step returns its
-      state unchanged: step 1 reports step 0's last objective and AUC and
-      hands back step 0's model.
+    - "unchanged": the float32 reference whose second outer iteration
+      returns its state unchanged: its updates report the first iteration's
+      last objective and AUC and hand back its model.
+    - "carried_over": the warm-up fit is the float32 reference's; the last
+      fit is the reference started from the model that fit returned, as
+      ``fit_multiple(warm_start=True)`` would start it.
     """
     from benchmarks import compare
     from benchmarks.reference.glmix import GlmixReference, Snapshot
 
-    steps = len(reference_snaps) // len(config["update_order"])
+    per = len(config["update_order"])
+    outer = len(reference_snaps) // per
+    runs = None
     if stand_in == "bfloat16":
-        low = GlmixReference(config, problem, "bfloat16").run(steps, log=log)
+        low = GlmixReference(config, problem, "bfloat16").run(outer, log=log)
     elif stand_in == "half_batch":
         halved = datagen.Problem(problem.n_cols, problem.train.first_half(),
                                  problem.held_out, problem.entity_counts)
-        low = GlmixReference(config, halved, "float32").run(steps, log=log)
+        low = GlmixReference(config, halved, "float32").run(outer, log=log)
     elif stand_in == "unchanged":
-        per = len(config["update_order"])
         stuck = reference_snaps[per - 1]
         low = list(reference_snaps[:per]) + [
             Snapshot(s.step, s.coordinate, stuck.objective, stuck.auc, stuck.fixed, stuck.random)
             for s in reference_snaps[per:]
         ]
+    elif stand_in == "carried_over":
+        first = list(reference_snaps)
+        returned = first[compare.picked_update([s.auc for s in first], per - 1)]
+        runs = [first, reference.run(outer, log=log, start=returned)]
     else:
         raise ValueError(f"unknown stand-in {stand_in!r}")
     histories, models = [], {}
-    for s, mine in enumerate(_by_step(low, config, steps)):
+    for i, mine in enumerate(runs or [low, low]):
         hist = {"objective": [m.objective for m in mine], "validation": [m.auc for m in mine]}
         histories.append(hist)
-        picked = mine[compare.picked_update(hist["validation"], first_fit=s == 0)]
-        models[s] = {"fixed": np.asarray(picked.fixed),
+        picked = mine[compare.picked_update(hist["validation"], per - 1)]
+        models[i] = {"fixed": np.asarray(picked.fixed),
                      **{k: np.asarray(v) for k, v in picked.random.items()}}
-    numbers = compare.training_numbers(
-        histories, models, _by_step(reference_snaps, config, steps))
-    numbers.update(compare.scored_objective_gap(
-        histories, models, lambda m: _evaluate(reference, m)))
-    return numbers
+    return _numbers(config, reference, reference_snaps, histories, models)

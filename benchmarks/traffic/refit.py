@@ -20,11 +20,15 @@ from benchmarks import datagen
 from benchmarks.traffic.steps import Window
 
 
+make_problem = datagen.make_problem
+STAND_INS = ("bfloat16", "half_batch", "unchanged")
+
+
 class Driver:
     def __init__(self, config: dict, params: dict, seed: int, rehearsal: bool, log):
         self.config, self.params, self.seed = config, params, int(seed)
         self.rehearsal, self.log = rehearsal, log
-        self.step_counters = []
+        self.step_counters = []   # none: what a fit counted is on its glm/solve span
         self.times: Dict[str, float] = {}
 
     def prepare(self) -> None:
@@ -90,14 +94,12 @@ class Driver:
 
     def run(self, window: Window) -> None:
         self.first_fit, _ = self._step()
-        self.step_counters.append({"fe_iterations": int(self.first_fit.result.iterations)})
         window.warmup_step_done()
         window.warmed_up()
         in_window = 0.0
         while True:
             self.fit, took = self._step()
             in_window += took
-            self.step_counters.append({"fe_iterations": int(self.fit.result.iterations)})
             if window.step_done():
                 break
         self.times["solve_s_in_window"] = in_window
@@ -138,8 +140,9 @@ class Driver:
         return _numbers(*self.kept_reference, list(self.produced.values()))
 
 
-def reference_run(config: dict, problem, log=None):
-    """(the float32 reference, its solve)."""
+def reference_run(config: dict, params: dict, problem, log=None):
+    """(the float32 reference, its solve); the traffic's ``params`` hold
+    nothing that it needs."""
     from benchmarks.reference.glm import GlmReference
 
     ref = GlmReference(config, problem, "float32")

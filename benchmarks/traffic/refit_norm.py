@@ -98,13 +98,14 @@ class Driver(refit.Driver):
         """The warm-up fit and the window's last fit (the same work) against
         the reference's optimum in the original space, the program's factor
         against the reference's own statistics."""
-        self.kept_reference = reference_run(self.config, self.problem, self.log)
+        self.kept_reference = reference_run(self.config, self.params, self.problem, self.log)
         produced = [(w, value, self.produced_factor) for w, value in self.produced.values()]
         return _numbers(*self.kept_reference, produced)
 
 
-def reference_run(config: dict, problem, log=None):
-    """(the float32 reference, its solve)."""
+def reference_run(config: dict, params: dict, problem, log=None):
+    """(the float32 reference, its solve); the traffic's ``params`` hold
+    nothing that it needs."""
     from benchmarks.reference.linear import LinearReference
 
     ref = LinearReference(config, problem, "float32")

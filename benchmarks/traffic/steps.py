@@ -58,6 +58,11 @@ class Window:
     def length(self) -> float:
         return self.end - self.start
 
+    @property
+    def step_seconds(self) -> List[float]:
+        ends = [self.start] + self.step_ends
+        return [b - a for a, b in zip(ends, ends[1:])]
+
     def train_step_s(self) -> dict:
         """The training drivers' end-to-end metric: the whole window over
         the whole steps in it."""

@@ -5,7 +5,9 @@ the chip could take for them.
 One fixed-effect objective evaluation reads the matrix twice (matvec and
 rmatvec) at 8 bytes a nonzero (a float32 value and an int32 column id), and
 reads and writes the coefficient vector, the gradient and the row vector
-once each; 2 FLOPs a nonzero a map. One random-effect lane-iteration reads
+once each; 2 FLOPs a nonzero a map. A Hessian-vector product is two maps.
+Both are counted by the solver where they happen and reach the benchmark on
+the program's ``glm/solve`` spans. One random-effect lane-iteration reads
 its [samples, dim] float32 block once and spends 4 FLOPs an element (margin
 and gradient). Nothing of the routed network's padding, slots or passes is
 counted: that is this implementation's cost, not the algorithm's.
@@ -58,20 +60,35 @@ def least_seconds(flops: float, nbytes: float, peaks: Dict[str, float]) -> Tuple
     return (by_flops, "flops") if by_flops >= by_bytes else (by_bytes, "bytes")
 
 
-def step_work(shapes: dict, counters: list) -> Tuple[float, float]:
-    """(FLOPs, bytes) summed over the steps whose counters are given. A
-    fixed-effect solve of ``i`` iterations makes at least ``i + 1``
-    evaluations (the solver counts no line-search retries), so this is a
-    lower bound and the share of peak built on it cannot flatter."""
+def fe_maps(solves: list) -> int:
+    """Sparse maps the algorithm needs for the given fixed-effect solves
+    (the attrs of the program's ``glm/solve`` spans): two a value-and-gradient
+    evaluation (``evaluations``: counted by the solver in its loop carry,
+    line-search and projection retries included) and two a Hessian-vector
+    product (``hessian_vecs``: TRON's CG steps)."""
+    return sum(2 * (int(a["evaluations"]) + int(a.get("hessian_vecs") or 0)) for a in solves)
+
+
+def step_work(shapes: dict, solves: list, lanes: list) -> Tuple[float, float]:
+    """(FLOPs, bytes) of the steps whose fixed-effect solves (``glm/solve``
+    attrs) and random-effect lanes (the driver's ``re_lanes`` counters) are
+    given: ``fe_evaluation`` an evaluation, two ``fe_map`` a Hessian-vector
+    product, ``re_lane_iteration`` a live lane-iteration. What the program
+    does beyond the algorithm's count (a third map a product where it
+    computes the margins again, the score plane's matvecs, validation) is
+    in the time and not here, so a share of peak built on this reads low,
+    never high."""
     flops = nbytes = 0.0
-    for c in counters:
-        if c.get("fe_iterations") is not None:
-            f, b = fe_evaluation(shapes["nnz"], shapes["n_rows"], shapes["n_cols"])
-            evaluations = c["fe_iterations"] + 1
-            flops += evaluations * f
-            nbytes += evaluations * b
-        for lane in c.get("re_lanes", []):
-            f, b = re_lane_iteration(lane["samples"], lane["dim"])
-            flops += lane["live"] * f
-            nbytes += lane["live"] * b
+    dims = (shapes["nnz"], shapes["n_rows"], shapes["n_cols"])
+    for a in solves:
+        f, b = fe_evaluation(*dims)
+        flops += int(a["evaluations"]) * f
+        nbytes += int(a["evaluations"]) * b
+        f, b = fe_map(*dims)
+        flops += 2 * int(a.get("hessian_vecs") or 0) * f
+        nbytes += 2 * int(a.get("hessian_vecs") or 0) * b
+    for lane in lanes:
+        f, b = re_lane_iteration(lane["samples"], lane["dim"])
+        flops += lane["live"] * f
+        nbytes += lane["live"] * b
     return flops, nbytes
