@@ -66,9 +66,9 @@ class Coordinate(abc.ABC):
 
     def update_model_device(self, model, residual_scores: jax.Array):
         """``update_model`` with a device-resident residual plane. The base
-        implementation round-trips through host (coordinates without a
-        device path, e.g. the factored RE block); FE/RE override it with
-        zero-row-transfer versions."""
+        implementation round-trips through host (a coordinate without a
+        device path of its own); the fixed-effect, random-effect and
+        factored coordinates override it with zero-row-transfer versions."""
         return self.update_model(model, np.asarray(residual_scores))
 
     def score_device(self, model) -> jax.Array:

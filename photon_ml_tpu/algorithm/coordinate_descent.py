@@ -357,8 +357,9 @@ class CoordinateDescent:
                 return coord.score(model)
             if coord.supports_device_plane:
                 return coord.score_device(model)
-            # fallback coordinate (e.g. factored RE): its host scores are
-            # pulled down then pushed back up onto the device plane
+            # a coordinate without a device path (none of the built-in ones):
+            # its host scores are pulled down then pushed back up onto the
+            # device plane
             stats.record_d2h()
             stats.record_h2d()
             return coord.score_device(model)

@@ -258,9 +258,9 @@ def _fixed_effect_scorings(tracer):
 def test_a_fit_on_routed_features_compiles_the_scoring_once(
     path, clean_slate, interpret_kernels, tracer
 ):
-    """``fit_multiple`` over two GLMix configurations (the benchmark's
-    cd-train step: a new ``CoordinateDescent`` each, the second
-    warm-started), two estimators' fits on equal-shaped data, one fit of two
+    """``fit_multiple`` over two GLMix configurations (as the benchmark's
+    ``cd-fit`` traffic runs one fit after another: a new
+    ``CoordinateDescent`` each; here the second is warm-started), two estimators' fits on equal-shaped data, one fit of two
     outer iterations, and two bare ``CoordinateDescent`` objects over
     warm-started fixed effects of equal shapes: after the first scoring no
     other compiles anything."""

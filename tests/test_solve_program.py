@@ -259,8 +259,8 @@ def test_variances_and_tracking_reuse_their_programs(clean_slate, watch):
 
 @pytest.mark.parametrize("path", ["fit_multiple", "coordinate_descent"])
 def test_the_normal_path_traces_the_fixed_effect_once(path, clean_slate, watch):
-    """``fit_multiple`` over two equal configurations (the benchmark's
-    cd-train step) and one ``CoordinateDescent`` run of two outer
+    """``fit_multiple`` over two equal configurations (as the benchmark's
+    ``cd-fit`` traffic runs one fit after another) and one ``CoordinateDescent`` run of two outer
     iterations both reach ``train_glm`` twice with an equal configuration."""
     data = _tiny_glmix()
     w = watch("lbfgs")
