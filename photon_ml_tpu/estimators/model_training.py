@@ -30,6 +30,7 @@ from photon_ml_tpu.losses.pointwise import loss_for_task
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.models.glm import GeneralizedLinearModel
 from photon_ml_tpu.opt.config import GlmOptimizationConfiguration, OptimizerConfig
+from photon_ml_tpu.opt.lbfgs import HISTORY_LAYOUT
 from photon_ml_tpu.opt.solve import solve, solver_kind
 from photon_ml_tpu.opt.state import SolveResult
 from photon_ml_tpu.ops.data import LabeledData
@@ -189,6 +190,9 @@ def train_glm(
             )
         objective = make_glm_objective(loss_for_task(task))
         solver = _solve_program(objective, configuration.optimizer_config, use_l1)
+        # what a traced run's glm/solve spans say of the curvature history
+        kind = solver_kind(configuration, 1.0 if use_l1 else 0.0)
+        layout = "none" if kind == "tron" else HISTORY_LAYOUT
         # the objective's functions are the same objects at every call, so
         # this wrapper finds the program an earlier call's wrapper compiled
         hess_diag = jax.jit(objective.hessian_diag) if compute_variances else None
@@ -211,6 +215,7 @@ def train_glm(
                         evaluations=int(result.evaluations),
                         hessian_vecs=int(result.hessian_vecs),
                         rejected_steps=int(result.rejected_steps),
+                        history_layout=layout,
                     )
             if warm_start:
                 w = result.w

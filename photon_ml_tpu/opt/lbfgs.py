@@ -14,6 +14,7 @@ accepted step (LBFGS.scala:72).
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -31,17 +32,61 @@ from photon_ml_tpu.opt.state import (
 from photon_ml_tpu.types import ConvergenceReason
 
 
+# What a ``glm/solve`` span of an L-BFGS or OWL-QN solve reports as its
+# ``history_layout`` (TRON keeps no history and reports "none").
+HISTORY_LAYOUT = "tiled"
+_LANES = 128
+
+
+def history_zeros(m: int, dim: int, dtype) -> jax.Array:
+    """An empty s/y ring buffer for a solve of static width ``dim``:
+    ``[m, ceil(dim/128), 128]``, rows zero-padded to a multiple of 128 (zeros
+    add nothing to a dot product).
+
+    As ``[m, d]`` float32 the TPU tiled the buffer (8, 128): eight rows
+    interleaved in one tile and ten rows padded to sixteen, so reading one
+    row moved eight and the buffer took 1.6x its data. With a row to itself a
+    row is contiguous, nothing pads, and its flattening to a vector is a
+    bitcast. The vmapped per-entity solves (16 or 32 coefficients) get the
+    same layout: on the chip they run faster on it than on ``[m, d]``, at
+    eight times the bytes of a 16-wide history (PERF.md section 6, PR 35).
+    The shape is known here and to the three helpers below, through which
+    ``two_loop_direction`` and ``update_history`` go."""
+    return jnp.zeros((m, -(-dim // _LANES), _LANES), dtype=dtype)
+
+
+def _row_width(hist: jax.Array) -> int:
+    """Elements in one row of a history buffer, its padding included."""
+    return math.prod(hist.shape[1:])
+
+
+def _history_row(hist: jax.Array, idx, dtype) -> jax.Array:
+    """Row ``idx`` of a history buffer as a flat vector of the working dtype,
+    its zero padding (if any) included: a bitcast of the row's tiles, where
+    a ``[:d]`` slice would write the row out first."""
+    return hist[idx].reshape(-1).astype(dtype)
+
+
+def _as_history_row(vec: jax.Array, hist: jax.Array) -> jax.Array:
+    """``vec`` [dim] in the storage dtype and row shape of ``hist``."""
+    pad = _row_width(hist) - vec.shape[-1]
+    return jnp.pad(vec.astype(hist.dtype), (0, pad)).reshape(hist.shape[1:])
+
+
 class _LbfgsState(NamedTuple):
     """Resumable L-BFGS loop state: everything the next outer iteration
     needs, including the absolute tolerances derived from the initial point
     (so a solve can be split into chunks — ``lbfgs_chunk`` — and each chunk
-    continues exactly where the previous one stopped)."""
+    continues exactly where the previous one stopped).
+
+    ``s_hist`` / ``y_hist`` are what ``history_zeros`` makes for ``d``:
+    ``[m, ceil(d/128), 128]``, so that a row is contiguous in HBM."""
 
     w: jax.Array          # [d]
     f: jax.Array
     g: jax.Array          # [d]
-    s_hist: jax.Array     # [m, d] steps ring buffer
-    y_hist: jax.Array     # [m, d] gradient-diff ring buffer
+    s_hist: jax.Array     # steps ring buffer (history_zeros)
+    y_hist: jax.Array     # gradient-diff ring buffer (history_zeros)
     rho: jax.Array        # [m] 1/(s.y)
     count: jax.Array      # int32 number of valid history pairs
     it: jax.Array         # int32 outer iteration
@@ -67,14 +112,26 @@ def two_loop_direction(
     # History may be stored bf16 (config.history_dtype); rows are cast to
     # the working dtype on read so every dot/axpy accumulates full precision.
     wd = g.dtype
+    # The recursion runs at the rows' padded width (g padded once, the
+    # direction cut once): the padding is zero in every row, so it stays
+    # zero in q and r and adds nothing to a dot product. No-ops where d is
+    # a multiple of 128.
+    dim = g.shape[-1]
+    g = jnp.pad(g, (0, _row_width(s_hist) - dim))
+
+    def s_row(idx):
+        return _history_row(s_hist, idx, wd)
+
+    def y_row(idx):
+        return _history_row(y_hist, idx, wd)
 
     def bwd(i, carry):
         q, alphas = carry
         idx = jnp.mod(count - 1 - i, m)  # newest first
         valid = i < count
         r = jnp.where(valid, rho[idx], 0.0)
-        a = r * jnp.dot(s_hist[idx].astype(wd), q)
-        q = q - a * y_hist[idx].astype(wd)
+        a = r * jnp.dot(s_row(idx), q)
+        q = q - a * y_row(idx)
         alphas = alphas.at[idx].set(a)
         return q, alphas
 
@@ -83,8 +140,8 @@ def two_loop_direction(
     # initial Hessian scaling gamma = (s.y)/(y.y) of the newest valid pair
     newest = jnp.mod(count - 1, m)
     have = count > 0
-    s_new = s_hist[newest].astype(wd)
-    y_new = y_hist[newest].astype(wd)
+    s_new = s_row(newest)
+    y_new = y_row(newest)
     sy = jnp.dot(s_new, y_new)
     yy = jnp.dot(y_new, y_new)
     gamma = jnp.where(have & (yy > 0), sy / jnp.maximum(yy, 1e-30), 1.0)
@@ -94,11 +151,11 @@ def two_loop_direction(
         idx = jnp.mod(count - m + i, m)  # oldest first among the last m
         valid = i >= (m - jnp.minimum(count, m))
         r = jnp.where(valid, rho[idx], 0.0)
-        beta = r * jnp.dot(y_hist[idx].astype(wd), r_vec)
-        return r_vec + jnp.where(valid, (alphas[idx] - beta), 0.0) * s_hist[idx].astype(wd)
+        beta = r * jnp.dot(y_row(idx), r_vec)
+        return r_vec + jnp.where(valid, (alphas[idx] - beta), 0.0) * s_row(idx)
 
     r_vec = jax.lax.fori_loop(0, m, fwd, r_vec)
-    return -r_vec
+    return -r_vec[:dim]
 
 
 def resolve_history_dtype(config: OptimizerConfig, working_dtype) -> jnp.dtype:
@@ -112,18 +169,20 @@ def update_history(
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Curvature-guarded ring-buffer insert (skip when s.y too small),
     casting the pair to the buffers' storage dtype — shared by L-BFGS and
-    OWL-QN so their history handling cannot diverge."""
+    OWL-QN so their history handling cannot diverge. The guard selects on
+    the row, not on the buffer: a rejected pair rewrites one row with
+    itself, an accepted one is an in-place dynamic-update-slice."""
     m = rho.shape[0]
     sy = jnp.dot(s_vec, y_vec)
     good_pair = sy > 1e-10 * jnp.maximum(jnp.dot(y_vec, y_vec), 1e-30)
     slot = jnp.mod(count, m)
-    hdtype = s_hist.dtype
-    s_hist = jnp.where(
-        good_pair, s_hist.at[slot].set(s_vec.astype(hdtype)), s_hist
-    )
-    y_hist = jnp.where(
-        good_pair, y_hist.at[slot].set(y_vec.astype(hdtype)), y_hist
-    )
+
+    def put(hist, vec):
+        row = jnp.where(good_pair, _as_history_row(vec, hist), hist[slot])
+        return hist.at[slot].set(row)
+
+    s_hist = put(s_hist, s_vec)
+    y_hist = put(y_hist, y_vec)
     rho = jnp.where(
         good_pair, rho.at[slot].set(1.0 / jnp.maximum(sy, 1e-30)), rho
     )
@@ -177,8 +236,8 @@ def lbfgs_init(
         w=w0,
         f=f0,
         g=g0,
-        s_hist=jnp.zeros((m, dim), dtype=hdtype),
-        y_hist=jnp.zeros((m, dim), dtype=hdtype),
+        s_hist=history_zeros(m, dim, hdtype),
+        y_hist=history_zeros(m, dim, hdtype),
         rho=jnp.zeros((m,), dtype=dtype),
         count=jnp.int32(0),
         it=jnp.int32(0),

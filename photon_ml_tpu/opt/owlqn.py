@@ -30,6 +30,7 @@ from photon_ml_tpu.losses.objective import GlmObjective
 from photon_ml_tpu.opt.config import OptimizerConfig
 from photon_ml_tpu.opt.lbfgs import (
     _project_box,
+    history_zeros,
     resolve_box,
     resolve_history_dtype,
     two_loop_direction,
@@ -60,9 +61,10 @@ def _project_orthant(w: jax.Array, xi: jax.Array) -> jax.Array:
 
 
 class _OwlqnState(NamedTuple):
-    """Resumable OWL-QN loop state (see _LbfgsState): carries the L1 weight
-    and the init-derived tolerances so chunked execution — ``owlqn_chunk``
-    every K iterations — follows the one-shot trajectory exactly."""
+    """Resumable OWL-QN loop state (see _LbfgsState, also for the shape of
+    ``s_hist`` / ``y_hist``): carries the L1 weight and the init-derived
+    tolerances so chunked execution — ``owlqn_chunk`` every K iterations —
+    follows the one-shot trajectory exactly."""
 
     w: jax.Array
     f: jax.Array          # smooth f (no L1)
@@ -114,8 +116,8 @@ def owlqn_init(
         f=f0,
         g=g0,
         F=F0,
-        s_hist=jnp.zeros((m, dim), dtype=hdtype),
-        y_hist=jnp.zeros((m, dim), dtype=hdtype),
+        s_hist=history_zeros(m, dim, hdtype),
+        y_hist=history_zeros(m, dim, hdtype),
         rho=jnp.zeros((m,), dtype=dtype),
         count=jnp.int32(0),
         it=jnp.int32(0),

@@ -41,6 +41,7 @@ import numpy as np
 from photon_ml_tpu.losses.objective import GlmObjective
 from photon_ml_tpu.opt.config import GlmOptimizationConfiguration, OptimizerType
 from photon_ml_tpu.opt.lbfgs import (
+    history_zeros,
     resolve_history_dtype,
     two_loop_direction,
     update_history,
@@ -202,7 +203,9 @@ class StreamPrograms:
             _note_trace("stream_step")
             return w + t * d
 
-        @jax.jit
+        # the buffers are donated so the new pair is written into its row in
+        # place (the solver loop rebinds both at once)
+        @partial(jax.jit, donate_argnums=(0, 1))
         def hist_update(s_hist, y_hist, rho, count, w_old, w_new, g_old, g_new):
             _note_trace("stream_history")
             s = (w_new - w_old).astype(s_hist.dtype)
@@ -337,8 +340,8 @@ def solve_streaming(
 
     m = cfg.history_length
     hdtype = resolve_history_dtype(cfg, w.dtype)
-    s_hist = jnp.zeros((m, dim), dtype=hdtype)
-    y_hist = jnp.zeros((m, dim), dtype=hdtype)
+    s_hist = history_zeros(m, dim, hdtype)
+    y_hist = history_zeros(m, dim, hdtype)
     rho = jnp.zeros((m,), dtype=w.dtype)
     count = jnp.int32(0)
 

@@ -436,12 +436,18 @@ def test_solvers_survive_ill_conditioned_data(task_name, solver_name):
     assert float(res.value) <= f0 + 1e-6
 
 
-@pytest.mark.parametrize("name", ["lbfgs", "owlqn", "tron"])
-def test_chunked_resume_matches_oneshot(rng, name):
+@pytest.mark.parametrize(
+    "name,width",
+    [("lbfgs", 6), ("owlqn", 6), ("tron", 8), ("lbfgs", 1100), ("owlqn", 1100)],
+    ids=["lbfgs", "owlqn", "tron", "lbfgs-tiled", "owlqn-tiled"],
+)
+def test_chunked_resume_matches_oneshot(rng, name, width):
     """init -> chunk(K) ... -> finalize must follow the EXACT trajectory of
     the uninterrupted solve: the chunk boundary only caps the while_loop's
     trip count, it never perturbs the carried state (L-BFGS history ring,
-    TRON trust radius, OWL-QN pseudo-gradient bookkeeping)."""
+    TRON trust radius, OWL-QN pseudo-gradient bookkeeping). At 1,100
+    coefficients a row of the history that crosses the boundary spans nine
+    tiles and is padded to 1,152 (``opt/lbfgs.py:history_zeros``)."""
     from photon_ml_tpu.opt import solve, solve_chunk, solve_finalize, solve_init
 
     if name == "tron":
@@ -453,7 +459,7 @@ def test_chunked_resume_matches_oneshot(rng, name):
             regularization_weight=0.1,
         )
     else:
-        data, _ = _logreg_problem(rng)
+        data, _ = _logreg_problem(rng, d=width)
         obj = make_glm_objective(LogisticLoss)
         reg = RegularizationType.L1 if name == "owlqn" else RegularizationType.L2
         configuration = GlmOptimizationConfiguration(
@@ -461,10 +467,13 @@ def test_chunked_resume_matches_oneshot(rng, name):
             regularization_weight=0.01 if name == "owlqn" else 0.1,
         )
     d = data.features.matrix.shape[1]
+    assert d == width
     w0 = jnp.zeros(d)
 
     ref = solve(obj, w0, data, configuration)
     state = solve_init(obj, w0, data, configuration)
+    if name != "tron":
+        assert state.s_hist.shape[1:] == (-(-width // 128), 128)
     for _ in range(40):  # 40 chunks x 3 iters covers max_iterations=100
         state = solve_chunk(obj, state, data, configuration, num_iters=3)
     res = solve_finalize(state, configuration)

@@ -257,6 +257,22 @@ def test_variances_and_tracking_reuse_their_programs(clean_slate, watch):
     assert len(hessians) == 1
 
 
+@pytest.mark.parametrize(
+    "solver,d,layout",
+    [("lbfgs", 40, "tiled"), ("lbfgs", 1100, "tiled"), ("owlqn", 40, "tiled"),
+     ("owlqn", 1100, "tiled"), ("tron", 1100, "none")],
+)
+def test_a_traced_solve_says_how_its_history_lies(solver, d, layout, clean_slate, watch):
+    """``glm/solve`` carries ``history_layout`` beside the solver's counts:
+    ``tiled`` (a row of the history to itself, whatever the width) for L-BFGS
+    and OWL-QN, ``none`` for TRON."""
+    w = watch(solver)
+    _fit(_data("ell", d=d), solver)
+    (solved,) = [s for s in w.tracer.spans() if s.name == "glm/solve"]
+    assert solved.attrs["history_layout"] == layout
+    assert solved.attrs["evaluations"] >= solved.attrs["iterations"] + 1
+
+
 @pytest.mark.parametrize("path", ["fit_multiple", "coordinate_descent"])
 def test_the_normal_path_traces_the_fixed_effect_once(path, clean_slate, watch):
     """``fit_multiple`` over two equal configurations (as the benchmark's

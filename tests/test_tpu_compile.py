@@ -13,6 +13,7 @@ another process that does (a second pytest session, a chip run).
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -34,6 +35,11 @@ from photon_ml_tpu.ops.pallas_kernels import fused_value_grad_single
 from photon_ml_tpu.opt.config import (
     GlmOptimizationConfiguration,
     OptimizerConfig,
+)
+from photon_ml_tpu.opt.lbfgs import (
+    history_zeros,
+    two_loop_direction,
+    update_history,
 )
 from photon_ml_tpu.opt.solve import solve
 from photon_ml_tpu.stat.summary import summarize
@@ -242,3 +248,48 @@ def test_adaptive_chunk_program_compiles_at_the_cell_width(v5e):
     n_tiles = jax.ShapeDtypeStruct((), jnp.int32)
     # no Mosaic kernel: gathers, scatters and the solver's XLA loops
     assert compile_for_tpu(progs.chunk, v5e, state, data, f32(()), live_idx, n_tiles) == 0
+
+
+@pytest.mark.parametrize("d", [40_000_000, 40_000_001], ids=["cell_width", "padded_row"])
+def test_history_layout_at_the_cell_width(v5e, d):
+    """The L-BFGS history of the benchmark's fixed effect (``m = 10`` over 40M
+    columns) as the chip lays it out. As ``[10, d]`` the TPU tiled it (8, 128):
+    ten rows padded to sixteen (5.28 GB of arguments for 3.36 GB of data), a
+    row read moved eight rows, and an insert rewrote the buffer (12.16 GB
+    accessed without donation). As ``history_zeros`` makes it, nothing pads,
+    an insert into donated buffers moves its two rows, and where ``d`` is a
+    multiple of 128 a row's flattening to ``[d]`` is a bitcast.
+
+    One over the width (a row padded to 312,501 x 128) the recursion runs at
+    the padded width: ``g`` is padded once and the direction cut once (0.16 GB
+    of temporaries), and no row is written out to be sliced."""
+    m = 10
+    hist = jax.eval_shape(lambda: history_zeros(m, d, jnp.float32))
+    assert hist.shape == (m, -(-d // 128), 128)
+    struct = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    h = struct(hist.shape, jnp.float32)
+    vec, rho, count = struct((d,), jnp.float32), struct((m,), jnp.float32), struct((), jnp.int32)
+    data_bytes = (2 * m + 1) * d * 4
+
+    # g is donated as a solver loop's carry is, so q may take its buffer
+    direction = jax.jit(two_loop_direction, donate_argnums=0).lower(
+        vec, h, h, rho, count
+    ).compile()
+    args = direction.memory_analysis().argument_size_in_bytes
+    assert data_bytes <= args <= 1.05 * data_bytes  # no sublane padding
+    width = hist.shape[1] * 128
+    hlo = direction.as_text()
+    copies = re.findall(rf"= f32\[({d}|{width})\]\S* copy\(", hlo)
+    assert not copies, "a row's reshape to a vector did not become a bitcast"
+    # no fusion writes a row out to be read again: the loop bodies read a
+    # row and read and write a vector (4.80 GB where each row was sliced)
+    assert direction.cost_analysis()["bytes accessed"] < 3.2e9
+    # what is left beside the arguments is the padded q and r, if any
+    assert direction.memory_analysis().temp_size_in_bytes < 1e7 + (width != d) * 3 * width * 4
+
+    update = jax.jit(update_history, donate_argnums=(0, 1)).lower(
+        h, h, rho, count, vec, vec
+    ).compile()
+    # two rows read, two written, the pair read for its two dot products
+    assert update.cost_analysis()["bytes accessed"] < 1.5e9
+    assert update.memory_analysis().temp_size_in_bytes < 1e7
