@@ -301,10 +301,11 @@ def phase_native(run: Run) -> None:
     check(color.min() == 0 and color.max() == 127, "euler_color misbehaves")
 
 
-def barrier_waits_for_compute_stream(run: Run) -> None:
+def barrier_waits_for_compute_stream(run: Run, device=None) -> None:
     """The tracer's device barrier (telemetry/span.py), behind every
     ``device_sync`` span: dispatched after a device program of about half a
-    second, it must not return before that program has retired."""
+    second (on ``device``; the default one if None), it must not return
+    before that program has retired."""
     import jax
     import jax.numpy as jnp
 
@@ -318,7 +319,7 @@ def barrier_waits_for_compute_stream(run: Run) -> None:
             0, n, lambda i, a: (a @ a) * jnp.bfloat16(1.0 / side), x
         )
 
-    x = jnp.ones((side, side), jnp.bfloat16)
+    x = jax.device_put(jnp.ones((side, side), jnp.bfloat16), device)
     jax.block_until_ready(long_program(x, 8))
     t0 = time.perf_counter()
     jax.block_until_ready(long_program(x, 64))
@@ -333,8 +334,9 @@ def barrier_waits_for_compute_stream(run: Run) -> None:
     jax.block_until_ready(busy)
     t2 = time.perf_counter()
     say(
-        f"  span barrier: returned {(t1 - t0) * 1e3:.1f}ms after a device program "
-        f"that retired after {(t2 - t0) * 1e3:.1f}ms was dispatched"
+        f"  span barrier: returned {(t1 - t0) * 1e3:.1f}ms after a program on "
+        f"{'the default device' if device is None else device} that retired after "
+        f"{(t2 - t0) * 1e3:.1f}ms was dispatched"
     )
     check(retired, "the span barrier returned before the program ahead of it retired")
 
@@ -728,10 +730,17 @@ def phase_multichip(run: Run) -> None:
         say(f"  multichip: not run ({jax.device_count()} device)")
         return
     train, held_out = run.data()
+    # the tiles take their engine from the grid; the coordinate's own engine
+    # is left to the held-out scoring: "auto", which is ELL for rows this few
+    # (a routed engine there is dispatched eagerly and compiles at every call)
     estimator = glmix_estimator(
-        run.size, run.sparse_engine,
+        run.size, "auto",
         parallel=ParallelConfiguration(n_data=2, n_feat=2, engine="fused"),
     )
+    # under the scope a fit opens, a device_sync span waits for the grid's
+    # last device as for the first
+    with estimator._grid_barrier():
+        barrier_waits_for_compute_stream(run, estimator._mesh.devices[-1, -1])
     # GameEstimator.fit is these two calls; made by hand to keep hold of the
     # coordinates, whose placement is what this phase is about
     built = {}
@@ -741,7 +750,23 @@ def phase_multichip(run: Run) -> None:
             built[cid] = estimator._build_coordinate(cid, cfg, train)
         return estimator._run_fit(built, train, held_out, None, None)
 
-    _fit_and_report(run, estimator, fit)
+    first = _fit_and_report(run, estimator, fit)
+
+    # a second fit on the same coordinates traces, lowers and compiles
+    # nothing: what PR 21's 115 programs an iteration would have failed
+    t0 = time.perf_counter()
+    again = estimator._run_fit(built, train, held_out, None, None)
+    t1 = time.perf_counter()
+    compile_s, programs = compile_window(t0, t1)
+    say(f"  second fit {t1 - t0:.1f}s, compile {compile_s:.2f}s in {programs} program(s)")
+    check(
+        compile_s == 0.0 and programs == 0,
+        f"the second grid fit compiled: {compile_s:.2f}s in {programs} program(s)",
+    )
+    check(
+        [v for _, v in again.objective_history] == [v for _, v in first.objective_history],
+        "the second grid fit read other objectives than the first",
+    )
 
     grid = set(estimator._mesh.devices.ravel().tolist())
     check(len(grid) == 4, f"mesh has {len(grid)} devices")

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from photon_ml_tpu.parallel.mesh import fetch_global
+from photon_ml_tpu.parallel.mesh import fetch_global, mesh_attrs
 
 from photon_ml_tpu.data.random_effect import RandomEffectDataset
 from photon_ml_tpu.estimators.model_training import train_glm
@@ -338,7 +338,7 @@ class RandomEffectCoordinate(Coordinate):
         self, ds: RandomEffectDataset, model: Optional[RandomEffectModel]
     ) -> RandomEffectModel:
         stats: list = []
-        with span("re/train", buckets=len(ds.buckets)):
+        with span("re/train", buckets=len(ds.buckets), **mesh_attrs(self.mesh)):
             new_model, results = train_random_effects(
                 ds, self.task, self.configuration, initial_model=model,
                 compute_variances=self.compute_variances, stats_out=stats,

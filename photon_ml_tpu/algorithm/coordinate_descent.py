@@ -61,7 +61,8 @@ from photon_ml_tpu.algorithm.coordinate import Coordinate
 from photon_ml_tpu.algorithm.schedule import SCHEDULES, ScheduleExecutor
 from photon_ml_tpu.evaluation.evaluators import nan_aware_better_than
 from photon_ml_tpu.opt.tracking import TransferStats
-from photon_ml_tpu.telemetry import note_jit_trace, span
+from photon_ml_tpu.telemetry import get_registry, note_jit_trace, span
+from photon_ml_tpu.telemetry.metrics import MESH_FETCH_BYTES
 
 logger = logging.getLogger("photon_ml_tpu")
 
@@ -219,6 +220,19 @@ class CoordinateDescent:
                 ),
             )
         )
+
+    def _validate_counting_fetches(self, models, validating) -> float:
+        """The held-out metric of ``models``; the ``cd/validate`` span is
+        told the bytes its scoring gathered to the host (``fetch_bytes``:
+        the counter ``mesh.fetch_bytes``, which counts while the tracer is
+        on)."""
+        registry = get_registry()
+        before = registry.counter_value(MESH_FETCH_BYTES)
+        metric = float(self.validate(models))
+        validating.set_attrs(
+            fetch_bytes=int(registry.counter_value(MESH_FETCH_BYTES) - before)
+        )
+        return metric
 
     def _record_progress(
         self,
@@ -493,8 +507,12 @@ class CoordinateDescent:
                             obj, loss_val, reg,
                         )
                     if self.validate is not None:
-                        with span("cd/validate", coordinate=cid, outer=outer):
-                            metric = float(self.validate(models))
+                        with span(
+                            "cd/validate", coordinate=cid, outer=outer
+                        ) as validating:
+                            metric = self._validate_counting_fetches(
+                                models, validating
+                            )
                             validation_history.append((cid, metric))
                             if self.progress is not None:
                                 self.progress.record_validation(
@@ -662,8 +680,8 @@ class CoordinateDescent:
                     obj, loss_val, reg,
                 )
             if self.validate is not None:
-                with span("cd/validate", coordinate=cid, outer=outer):
-                    metric = float(self.validate(models))
+                with span("cd/validate", coordinate=cid, outer=outer) as validating:
+                    metric = self._validate_counting_fetches(models, validating)
                     validation_history.append((cid, metric))
                     if self.progress is not None:
                         self.progress.record_validation(outer, cid, metric)

@@ -47,7 +47,9 @@ from photon_ml_tpu.models.game import CoordinateMeta, GameModel
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.ops.data import LabeledData
 from photon_ml_tpu.opt.config import GlmOptimizationConfiguration
+from photon_ml_tpu.parallel.mesh import mesh_attrs
 from photon_ml_tpu.telemetry import span
+from photon_ml_tpu.telemetry.span import barrier_over
 from photon_ml_tpu.types import TaskType
 
 logger = logging.getLogger("photon_ml_tpu")
@@ -277,7 +279,8 @@ class GameEstimator:
         self, cid: str, cfg: CoordinateConfiguration, data: GameData
     ) -> Coordinate:
         with span(
-            "game/build_coordinate", coordinate=cid, kind=type(cfg).__name__
+            "game/build_coordinate", coordinate=cid, kind=type(cfg).__name__,
+            **mesh_attrs(self._mesh),
         ):
             return self._build_coordinate_impl(cid, cfg, data)
 
@@ -518,7 +521,7 @@ class GameEstimator:
                 f"coordinate {cid!r} is factored — single-coordinate re-solve "
                 "supports fixed-effect and plain random-effect coordinates"
             )
-        with span(
+        with self._grid_barrier(), span(
             "game/resolve_coordinate", coordinate=cid, num_rows=data.num_rows
         ):
             return self._resolve_coordinate_impl(
@@ -837,7 +840,18 @@ class GameEstimator:
             )
         return dataclasses.replace(coord, configuration=opt)
 
-    def _run_fit(
+    def _grid_barrier(self):
+        """While open, ``device_sync`` spans wait for every device of the
+        grid (for the default device where there is none)."""
+        return barrier_over(
+            () if self._mesh is None else self._mesh.devices.ravel().tolist()
+        )
+
+    def _run_fit(self, *args, **kwargs) -> GameFit:
+        with self._grid_barrier():
+            return self._run_fit_impl(*args, **kwargs)
+
+    def _run_fit_impl(
         self,
         coordinates: Dict[str, Coordinate],
         data: GameData,

@@ -34,6 +34,7 @@ from photon_ml_tpu.opt.lbfgs import HISTORY_LAYOUT
 from photon_ml_tpu.opt.solve import solve, solver_kind
 from photon_ml_tpu.opt.state import SolveResult
 from photon_ml_tpu.ops.data import LabeledData
+from photon_ml_tpu.parallel.mesh import mesh_attrs
 from photon_ml_tpu.telemetry import note_jit_trace
 from photon_ml_tpu.telemetry.span import get_tracer, span
 from photon_ml_tpu.types import TaskType
@@ -149,6 +150,8 @@ def train_glm(
             w = initial_model.coefficients.means
             if data.norm is not None:
                 w = data.norm.inverse_transform_model_coefficients(w, intercept_index)
+        elif hasattr(data.features, "zero_coefficients"):
+            w = data.features.zero_coefficients()  # laid out over a mesh
         else:
             w = jnp.zeros((dim,), dtype=jnp.float32)
 
@@ -193,6 +196,7 @@ def train_glm(
         # what a traced run's glm/solve spans say of the curvature history
         kind = solver_kind(configuration, 1.0 if use_l1 else 0.0)
         layout = "none" if kind == "tron" else HISTORY_LAYOUT
+        on_mesh = mesh_attrs(getattr(data.features, "mesh", None))
         # the objective's functions are the same objects at every call, so
         # this wrapper finds the program an earlier call's wrapper compiled
         hess_diag = jax.jit(objective.hessian_diag) if compute_variances else None
@@ -204,7 +208,9 @@ def train_glm(
         for lam in sweep:
             l2 = jnp.float32(reg.l2_weight(lam))
             l1 = jnp.float32(reg.l1_weight(lam))
-            with span("glm/solve", regularization_weight=float(lam)) as solving:
+            with span(
+                "glm/solve", regularization_weight=float(lam), **on_mesh
+            ) as solving:
                 result = solver(w, data, l2, l1, box_constraints)
                 if get_tracer().enabled:
                     # a traced run waits for the solve here, so that the span

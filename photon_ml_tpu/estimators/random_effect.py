@@ -32,6 +32,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 import numpy as np
 
 from photon_ml_tpu.parallel.mesh import fetch_global
@@ -108,6 +109,18 @@ def _tile_lanes(num_entities: int, min_lanes: int) -> int:
 def _is_multi_device(x) -> bool:
     sharding = getattr(x, "sharding", None)
     return sharding is not None and len(sharding.device_set) > 1
+
+
+def _zero_start(bucket: ReBucket) -> jax.Array:
+    """The zero model of one bucket, laid out as the bucket is: over a mesh
+    the lanes are sharded as the solve hands them back, so that a solve from
+    nothing and a warm-started one are one program."""
+    shape = (bucket.num_entities, bucket.local_dim)
+    sharding = getattr(bucket.X, "sharding", None)
+    if _is_multi_device(bucket.X) and isinstance(sharding, NamedSharding):
+        lanes = NamedSharding(sharding.mesh, PartitionSpec(sharding.spec[0], None))
+        return jnp.zeros(shape, jnp.float32, device=lanes)
+    return jnp.zeros(shape, dtype=jnp.float32)
 
 
 class _RePrograms(NamedTuple):
@@ -370,9 +383,7 @@ def train_random_effects(
             return _fit_entity_axis(
                 initial_model.coefficients[b], bucket.num_entities
             )
-        return jnp.zeros(
-            (bucket.num_entities, bucket.local_dim), dtype=jnp.float32
-        )
+        return _zero_start(bucket)
 
     def _solve_one(b, bucket, w0, use_adaptive):
         if use_adaptive:

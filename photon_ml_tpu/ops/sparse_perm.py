@@ -352,11 +352,15 @@ def plan_column_layout(
         cands.append(p)
         p *= 2
     cands.append(kp_full)  # the uncapped candidate (spill 0), ALWAYS kept
+    # columns by degree: a cap's spill from the histogram, one pass over the
+    # columns in all and not three a candidate (21 s of a 2 x 2 grid's 1.6e8)
+    degree_hist = np.bincount(np.asarray(col_counts, dtype=np.int64))
+    degrees = np.arange(degree_hist.size, dtype=np.int64)
     caps = []  # (cap, spill_cost)
     for p in cands:
         spill = (
             0 if p >= kp_full
-            else int(np.maximum(col_counts - p, 0).sum())
+            else int((np.maximum(degrees - p, 0) * degree_hist).sum())
         )
         if spill <= max_spill:
             caps.append((p, spill * _spill_slot_cost() * spill_scale))

@@ -23,6 +23,9 @@ permutation-routed Benes engine (TPU) or the ELL gather layout (CPU tests)
 
 from __future__ import annotations
 
+import contextlib
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Tuple
 
 import jax
@@ -37,13 +40,25 @@ from photon_ml_tpu.ops.features import EllFeatures
 from photon_ml_tpu.ops.sparse_perm import (
     _assemble,
     coalesce_coo,
+    default_plan_cache,
     select_hot_cols,
     split_hot_entries,
 )
 from photon_ml_tpu.parallel.mesh import place as place_global, shard_map
+from photon_ml_tpu.telemetry.span import span
 
 DATA_AXIS = "data"
 FEAT_AXIS = "feat"
+# A feat shard's width is a multiple of this. The solvers keep their history
+# as [m, d / 128, 128] (opt/lbfgs.py history_zeros); reshaping a feat-sharded
+# [d] vector to [d / 128, 128] stays on its device only where every shard
+# holds whole rows of 128, and costs four collective-permutes of a row's
+# halo a solve where it does not (docs/SCALING.md).
+COLUMN_MULTIPLE = 128
+# How many tiles route (or read their plans back) and upload at once: the
+# native colorer and numpy's bulk passes run outside the interpreter lock,
+# and a tile in flight holds a few GB of host arrays.
+_MAX_TILE_WORKERS = 4
 
 
 def grid_mesh(
@@ -127,6 +142,15 @@ class GridShardedFeatures:
         )(self.shards, c2)
         return out.reshape(-1)
 
+    def zero_coefficients(self) -> jax.Array:
+        """The zero model, feat-sharded as a solve hands its result back:
+        a solve from nothing and a warm-started one are then one program
+        (an uncommitted start lowers a second)."""
+        return jnp.zeros(
+            (self.num_cols_,), jnp.float32,
+            device=jax.sharding.NamedSharding(self.mesh, P(FEAT_AXIS)),
+        )
+
     def row_norms_sq(self) -> jax.Array:
         def local_rn(shards):
             tile = jax.tree.map(lambda a: a[0, 0], shards)
@@ -171,8 +195,10 @@ def grid_from_coo(
     identically.
 
     Rows pad to a multiple of the data-axis size, columns to a multiple of
-    the feat-axis size; callers padding labels/weights must give padding
-    rows weight 0 (padded columns are simply never touched).
+    ``COLUMN_MULTIPLE`` x the feat-axis size; callers padding labels/weights
+    must give padding rows weight 0 (padded columns are simply never
+    touched). Tiles are built a few at a time, each with its own device as
+    the default one: span ``grid/build_tile``.
     """
     if engine not in ("benes", "ell", "fused"):
         raise ValueError(f"unknown engine {engine!r}; expected benes/ell/fused")
@@ -217,7 +243,7 @@ def grid_from_coo(
         )
 
     n_loc = -(-n // n_dd)
-    d_loc = -(-d // n_df)
+    d_loc = -(-d // (n_df * COLUMN_MULTIPLE)) * COLUMN_MULTIPLE
     dd_of = rows // n_loc
     df_of = cols // d_loc
 
@@ -309,21 +335,32 @@ def grid_from_coo(
             [tile_col_counts[key] for key in sorted(tile_col_counts)]
         )
 
+        block_k: dict = {}
+
         def _grid_row_block_k(t: int) -> int:
             """Pinned per-block ELL width for a t-way column split: the max
             nnz any tile-local row holds within one column block, over ALL
             tiles (blocks stack across tiles, so the pin is the global
-            max). Same refinement as sparse_perm.make_row_block_k."""
+            max). Same refinement as sparse_perm.make_row_block_k: memoized
+            per t (the planner asks once a candidate cap), counted in one
+            pass where the (row, block) bins are no more than a few to an
+            entry and by a sort where they would outgrow O(nnz) memory."""
+            if t in block_k:
+                return block_k[t]
             d_bb_t = -(-d_loc // t)
             k_max = 1
             for tr, tc, _tv, _hm in tiles_cold.values():
                 if not tr.size:
                     continue
                 key2 = tr.astype(np.int64) * t + tc // d_bb_t
-                _, cnts = np.unique(key2, return_counts=True)
+                if n_loc * t <= 4 * key2.size:
+                    cnts = np.bincount(key2)
+                else:
+                    _, cnts = np.unique(key2, return_counts=True)
                 k_max = max(k_max, int(cnts.max()))
             if engine == "fused":
                 k_max = 1 << max(k_max - 1, 0).bit_length()
+            block_k[t] = k_max
             return k_max
 
         # all_counts spans every tile while n_loc/d_loc describe one tile:
@@ -479,42 +516,83 @@ def grid_from_coo(
             )
         return ell
 
-    built = {}
-    if addressable is not None:
-        for pos in sorted(addressable):
-            built[pos] = _build_tile(*pos)
-        template = built[min(built)]
-    structs = []
-    for dd in range(n_dd):
-        row_structs = []
-        for df in range(n_df):
-            if addressable is None:
-                row_structs.append(_build_tile(dd, df))
-            else:
-                row_structs.append(built.get((dd, df), template))
-        structs.append(row_structs)
+    # Each tile is built with its own device as the default one, so what its
+    # builder uploads lands there: the global array is assembled from the
+    # per-device pieces, no tile ever sits on the first device and none is
+    # stacked with its siblings on the host.
+    # Grid positions of other processes' devices are never built; a process
+    # with no device on the mesh builds one tile for its shapes alone.
+    positions = sorted(addressable) if addressable is not None else [
+        (dd, df) for dd in range(n_dd) for df in range(n_df)
+    ]
 
-    # Stack on HOST (np) so the full global array never materializes on any
-    # device; placement uploads only each process's addressable shards.
-    stacked = jax.tree.map(
-        lambda *xs: np.stack([np.asarray(x) for x in xs]),
-        *[
-            jax.tree.map(lambda *ys: np.stack([np.asarray(y) for y in ys]), *row)
-            for row in structs
-        ],
-    )
-    stacked = jax.tree.map(
-        lambda a: place_global(
-            a, mesh, P(DATA_AXIS, FEAT_AXIS, *([None] * (a.ndim - 2)))
-        ),
-        stacked,
-    )
+    def _build_and_upload(pos):
+        dd, df = pos
+        device = mesh.devices[dd, df]
+        local = device.process_index == jax.process_index()
+        with span("grid/build_tile", dd=dd, df=df) as building, (
+            jax.default_device(device) if local else contextlib.nullcontext()
+        ):
+            plans_before = _plan_files(plan_dir)
+            tile = _build_tile(dd, df)
+            slots = _tile_slots(tile)
+            # read back, not routed: the tile has plans and no plan file was
+            # published while it was built (tiles built at once share the
+            # reading; a pattern's tiles are routed in one run, or none is)
+            building.set_attrs(
+                slots=slots,
+                plan_cached=bool(slots and plan_dir)
+                and _plan_files(plan_dir) == plans_before,
+            )
+            leaves, treedef = jax.tree.flatten(tile)
+            del tile
+            pieces = jax.block_until_ready(
+                [jnp.asarray(leaf)[None, None] for leaf in leaves]
+            )
+        return treedef, pieces
+
+    plan_dir = None
+    if engine in ("benes", "fused"):
+        routing._load_native()  # once, before any worker asks for it
+        plan_dir = default_plan_cache() if plan_cache is None else plan_cache
+    workers = max(1, min(len(positions), _MAX_TILE_WORKERS, os.cpu_count() or 1))
+    with ThreadPoolExecutor(workers, thread_name_prefix="grid-tile") as pool:
+        built = list(pool.map(_build_and_upload, positions))
+
+    treedef = built[0][0]
+    if any(t != treedef for t, _ in built):
+        raise AssertionError("grid tiles built to different structures")
+    global_leaves = []
+    for i, first in enumerate(built[0][1]):
+        shape = (n_dd, n_df) + first.shape[2:]
+        sharding = jax.sharding.NamedSharding(
+            mesh, P(DATA_AXIS, FEAT_AXIS, *([None] * (len(shape) - 2)))
+        )
+        global_leaves.append(jax.make_array_from_single_device_arrays(
+            shape, sharding,
+            [pieces[i] for (dd, df), (_, pieces) in zip(positions, built)
+             if mesh.devices[dd, df].process_index == jax.process_index()],
+        ))
     return GridShardedFeatures(
-        shards=stacked,
+        shards=jax.tree.unflatten(treedef, global_leaves),
         mesh=mesh,
         num_rows_=int(n_loc * n_dd),
         num_cols_=int(d_loc * n_df),
     )
+
+
+def _plan_files(plan_dir: Optional[str]) -> int:
+    """How many files the plan cache holds (0 where it is off or empty)."""
+    if not plan_dir or not os.path.isdir(plan_dir):
+        return 0
+    return len(os.listdir(plan_dir))
+
+
+def _tile_slots(tile) -> int:
+    """Routed slots of one tile, summed over its column blocks (0 for the
+    ELL engine, which routes nothing)."""
+    blocks = getattr(tile, "blocks", (tile,))
+    return sum(int(b.plan.size) for b in blocks if hasattr(b, "plan"))
 
 
 def _ell_tile(tr, tc, tv, n_loc: int, d_loc: int, K: int) -> EllFeatures:

@@ -38,6 +38,17 @@ def shard_map(f, mesh, in_specs, out_specs):
     )
 
 
+def mesh_attrs(mesh: Optional[Mesh]) -> dict:
+    """What a span says of the mesh its work runs on: ``mesh`` (the axis
+    sizes, "2x2") and ``devices``; nothing where there is no mesh."""
+    if mesh is None:
+        return {}
+    return {
+        "mesh": "x".join(str(n) for n in mesh.devices.shape),
+        "devices": int(mesh.devices.size),
+    }
+
+
 def data_parallel_mesh(
     num_devices: Optional[int] = None, devices: Optional[Sequence[jax.Device]] = None
 ) -> Mesh:

@@ -380,6 +380,24 @@ def note_jit_trace(program: str, kind: str = "") -> None:
     _REGISTRY.count(f"jit.traces.{key}")
 
 
+MESH_FETCH_BYTES = "mesh.fetch_bytes"
+_counting_fetches = False
+
+
+def count_mesh_fetches() -> None:
+    """Feed the counter ``mesh.fetch_bytes`` with the bytes of every device
+    array that ``parallel.mesh.fetch_global`` brings to the host (a sharded
+    model gathered for the held-out scoring, a checkpoint). Idempotent; the
+    tracer turns it on."""
+    global _counting_fetches
+    if _counting_fetches:
+        return
+    from photon_ml_tpu.parallel.mesh import add_fetch_observer
+
+    add_fetch_observer(lambda nbytes: _REGISTRY.count(MESH_FETCH_BYTES, nbytes))
+    _counting_fetches = True
+
+
 def jit_trace_counts() -> Dict[str, int]:
     """Per-program trace counts recorded via :func:`note_jit_trace`."""
     snap = _REGISTRY.snapshot()["counters"]

@@ -26,6 +26,7 @@ phases, ``telemetry/compile_spans.py``) enter through
 """
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import dataclasses
 import functools
@@ -43,6 +44,7 @@ __all__ = [
     "union_seconds",
     "enable_tracing",
     "disable_tracing",
+    "barrier_over",
 ]
 
 
@@ -89,30 +91,64 @@ _CURRENT: contextvars.ContextVar[Optional["_LiveSpan"]] = contextvars.ContextVar
 )
 
 
-@functools.lru_cache(maxsize=1)
-def _barrier_program():
-    """(a trivial jitted program, its operand on the default device),
-    compiled here so that no span ever holds its compile."""
+# The devices a ``device_sync`` span waits for: () is the default device
+# alone. Code that spreads work over a mesh widens it with ``barrier_over``
+# for as long as that work lasts.
+_BARRIER_DEVICES: contextvars.ContextVar[tuple] = contextvars.ContextVar(
+    "photon_ml_tpu_barrier_devices", default=()
+)
+
+
+@contextlib.contextmanager
+def barrier_over(devices):
+    """While open (in this context), every ``device_sync`` span waits for
+    all of ``devices`` (an iterable of ``jax.Device``), and for the default
+    device alone again once it has closed. The barrier's program for them
+    is compiled on the way in when the tracer is on, so that no
+    ``device_sync`` span ever holds its compile."""
+    devices = tuple(devices)
+    if _TRACER.enabled and _TRACER.device_sync:
+        _barrier_program(devices)
+    token = _BARRIER_DEVICES.set(devices)
+    try:
+        yield
+    finally:
+        _BARRIER_DEVICES.reset(token)
+
+
+@functools.lru_cache(maxsize=4)
+def _barrier_program(devices: tuple = ()):
+    """(a trivial jitted program, an operand of it on the default device or
+    on each of ``devices``), run once on each here so that no span ever
+    holds its compile. One single-device program a device, not one program
+    over them all: the CPU backend does not order a multi-device program
+    after a single-device one on the same device (the barrier returned 7 ms
+    after a 500 ms program was dispatched to a grid's last device), and
+    single-device programs on one device it runs in order, as the chip does."""
     import jax
     import jax.numpy as jnp
 
     program = jax.jit(lambda x: x + 1)
-    operand = jnp.zeros((), jnp.float32)
-    jax.block_until_ready(program(operand))
-    return program, operand
+    zero = jnp.zeros((), jnp.float32)
+    operands = tuple(jax.device_put(zero, d) for d in devices) or (zero,)
+    jax.block_until_ready([program(operand) for operand in operands])
+    return program, operands
 
 
 def _device_barrier() -> None:
-    """Block until the work dispatched so far to the default device has
-    retired. The device's compute stream runs programs in order, so a
-    trivial program dispatched now ends after them. (A host-to-device copy
-    does not wait for the compute stream: ``device_put(0.0)`` returned 1-6 ms
-    after a 496 ms program was dispatched, this barrier after 495.9 ms; chip,
-    PR 26, log 1. ``chip_smoke.py``'s engine phase repeats the check.)"""
+    """Block until the work dispatched so far has retired on every device
+    that holds work: the default device, or the devices of the innermost
+    open ``barrier_over``. A device's compute stream runs programs in
+    order, so a trivial program dispatched now to each of them ends after
+    what was dispatched before. (A host-to-device copy does not wait for the
+    compute stream: ``device_put(0.0)`` returned 1-6 ms after a 496 ms
+    program was dispatched, this barrier after 495.9 ms; chip, PR 26, log 1.
+    ``chip_smoke.py`` repeats the check in its engine phase and, on the
+    grid's last device, in its multichip phase.)"""
     import jax
 
-    program, operand = _barrier_program()
-    jax.block_until_ready(program(operand))
+    program, operands = _barrier_program(_BARRIER_DEVICES.get())
+    jax.block_until_ready([program(operand) for operand in operands])
 
 
 class _LiveSpan:
@@ -332,12 +368,14 @@ def timed_span(name: str, **attrs) -> _LiveSpan:
 def enable_tracing(device_sync: bool = True, clear: bool = True) -> Tracer:
     """Turn on the global tracer (optionally clearing prior spans). The
     first call also registers the listeners that turn JAX's compile phases
-    into spans (``compile_spans``)."""
-    from photon_ml_tpu.telemetry import compile_spans
+    into spans (``compile_spans``) and the one that counts the bytes
+    ``fetch_global`` brings to the host (``mesh.fetch_bytes``)."""
+    from photon_ml_tpu.telemetry import compile_spans, metrics
 
     compile_spans.register()
+    metrics.count_mesh_fetches()
     if device_sync:
-        _barrier_program()
+        _barrier_program(_BARRIER_DEVICES.get())
     if clear:
         _TRACER.clear()
     _TRACER.device_sync = device_sync
