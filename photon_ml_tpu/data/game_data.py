@@ -128,9 +128,11 @@ class GameData:
         if rows is None:
             import jax.numpy as jnp
 
-            rows = tuple(
+            from photon_ml_tpu.telemetry.span import upload
+
+            rows = upload("rows", lambda: tuple(
                 jnp.asarray(a) for a in (self.labels, self.weights, self.offsets)
-            )
+            ))
             self._device_rows = rows
         return rows
 

@@ -38,6 +38,7 @@ from photon_ml_tpu.utils.nativesort import lexsort_pairs
 from flax import struct
 
 from photon_ml_tpu.projector import ProjectorType, RandomProjectionMatrix
+from photon_ml_tpu.telemetry.span import span, upload
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,7 +184,7 @@ class RandomEffectDataset:
         if self.row_gather is None:
             from photon_ml_tpu.parallel.mesh import fetch_global
 
-            self.row_gather = _build_row_gather(
+            self.row_gather = jnp.asarray(_build_row_gather(
                 self.num_rows,
                 [
                     (fetch_global(b.sample_pos), fetch_global(b.weights))
@@ -193,7 +194,7 @@ class RandomEffectDataset:
                     None if p is None else np.asarray(fetch_global(p.sample_pos))
                     for p in self.passive
                 ],
-            )
+            ))
         return self.row_gather
 
     def update_offsets_device(self, offsets: jax.Array) -> "RandomEffectDataset":
@@ -215,7 +216,7 @@ def _build_row_gather(
     num_rows: int,
     actives: List[Tuple[np.ndarray, np.ndarray]],
     passive_pos: List[Optional[np.ndarray]],
-) -> jax.Array:
+) -> np.ndarray:
     """Invert the (sample_pos, weights>0) scatter into a row -> source-slot
     index over the concatenation [active_b0 | passive_b0 | active_b1 | ...]
     plus one trailing zero slot (rows outside every bucket gather 0.0).
@@ -236,7 +237,7 @@ def _build_row_gather(
                 base + np.arange(sp.size, dtype=np.int32)
             )
             base += sp.size
-    return jnp.asarray(inv)
+    return inv
 
 
 @jax.jit
@@ -438,7 +439,29 @@ def build_random_effect_dataset(
     entity_ids: per-row entity key (len n). feature_*: COO triplets over the
     global feature space. Rows with entities are ALL consumed: up to the active
     cap into solver blocks, the remainder into passive (score-only) rows.
+    The host work is the span ``re/build_dataset`` (``entities``,
+    ``buckets``); the buckets' upload after it is a ``data/upload``.
     """
+    with span("re/build_dataset") as building:
+        host = _pack_random_effect_dataset(
+            entity_ids, feature_rows, feature_cols, feature_vals, global_dim,
+            labels, config, offsets, weights,
+        )
+        building.set_attrs(entities=host.num_entities, buckets=len(host.buckets))
+    buckets, passive, row_gather = upload("re_bucket", lambda: jax.tree.map(
+        jnp.asarray, (host.buckets, host.passive, host.row_gather)
+    ))
+    return dataclasses.replace(
+        host, buckets=buckets, passive=passive, row_gather=row_gather
+    )
+
+
+def _pack_random_effect_dataset(
+    entity_ids, feature_rows, feature_cols, feature_vals, global_dim, labels,
+    config, offsets, weights,
+) -> RandomEffectDataset:
+    """:func:`build_random_effect_dataset`'s work on the host: the dataset
+    with numpy arrays where the device's will be."""
     n = len(entity_ids)
     labels = np.asarray(labels, dtype=np.float32)
     offsets = np.zeros(n, dtype=np.float32) if offsets is None else np.asarray(offsets, dtype=np.float32)
@@ -693,20 +716,15 @@ def build_random_effect_dataset(
 
         buckets.append(
             ReBucket(
-                X=jnp.asarray(X),
-                labels=jnp.asarray(lab),
-                offsets=jnp.asarray(off),
-                weights=jnp.asarray(wt),
-                sample_pos=jnp.asarray(pos),
-                proj_indices=jnp.asarray(pidx),
-                proj_valid=jnp.asarray(pval),
+                X=X, labels=lab, offsets=off, weights=wt, sample_pos=pos,
+                proj_indices=pidx, proj_valid=pval,
             )
         )
         passives.append(
             RePassiveRows(
-                X=jnp.asarray(pX),
-                entity_index=jnp.asarray(new_e[e_pas_g[pm]].astype(np.int32)),
-                sample_pos=jnp.asarray(pas_b.astype(np.int32)),
+                X=pX,
+                entity_index=new_e[e_pas_g[pm]].astype(np.int32),
+                sample_pos=pas_b.astype(np.int32),
             )
             if n_pas
             else None

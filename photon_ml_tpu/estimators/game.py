@@ -49,7 +49,7 @@ from photon_ml_tpu.ops.data import LabeledData
 from photon_ml_tpu.opt.config import GlmOptimizationConfiguration
 from photon_ml_tpu.parallel.mesh import mesh_attrs
 from photon_ml_tpu.telemetry import span
-from photon_ml_tpu.telemetry.span import barrier_over
+from photon_ml_tpu.telemetry.span import barrier_over, upload
 from photon_ml_tpu.types import TaskType
 
 logger = logging.getLogger("photon_ml_tpu")
@@ -291,11 +291,12 @@ class GameEstimator:
         if isinstance(cfg, FixedEffectCoordinateConfiguration):
             if self.parallel is not None:
                 return self._build_grid_fixed_effect(cfg, data)
+            features = data.sparse_features(cfg.feature_shard, engine=cfg.sparse_engine)
+            labels, offsets, weights = upload("rows", lambda: tuple(
+                jnp.asarray(a) for a in (data.labels, data.offsets, data.weights)
+            ))
             labeled = LabeledData.create(
-                data.sparse_features(cfg.feature_shard, engine=cfg.sparse_engine),
-                jnp.asarray(data.labels),
-                offsets=jnp.asarray(data.offsets),
-                weights=jnp.asarray(data.weights),
+                features, labels, offsets=offsets, weights=weights,
                 norm=self.normalization.get(cfg.feature_shard),
             )
             return FixedEffectCoordinate(
@@ -335,9 +336,10 @@ class GameEstimator:
             # entity-axis sharding over every device of the grid — for the
             # factored coordinate too (its latent datasets derive from these
             # arrays, so the per-entity solves inherit the placement)
-            re_ds = place_dataset(
-                pad_entities_to_multiple(re_ds, n_dev), mesh, mesh_axes
-            )
+            padded = pad_entities_to_multiple(re_ds, n_dev)
+            re_ds = dataclasses.replace(padded, buckets=upload(
+                "re_bucket", lambda: place_dataset(padded, mesh, mesh_axes).buckets
+            ))
         if isinstance(cfg, FactoredRandomEffectCoordinateConfiguration):
             return FactoredRandomEffectCoordinate(
                 dataset=re_ds,
@@ -395,12 +397,11 @@ class GameEstimator:
                 shift = jnp.pad(jnp.asarray(shift), (0, gf.dim - d))
             norm = norm.replace(factor=factor, shift=shift)
 
+        labels, offsets, weights = upload("rows", lambda: tuple(
+            pad_rows(a) for a in (data.labels, data.offsets, data.weights)
+        ))
         labeled = LabeledData(
-            features=gf,
-            labels=pad_rows(data.labels),
-            offsets=pad_rows(data.offsets),
-            weights=pad_rows(data.weights),
-            norm=norm,
+            features=gf, labels=labels, offsets=offsets, weights=weights, norm=norm,
         )
         return FixedEffectCoordinate(
             data=labeled,

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from photon_ml_tpu.telemetry.span import upload
+
 
 @struct.dataclass
 class DenseFeatures:
@@ -221,8 +223,7 @@ def from_scipy_like(rows, cols, vals, shape, max_nnz: int | None = None) -> EllF
     silent truncation would train a wrong model.
     """
     values, indices = pack_ell_host(rows, cols, vals, shape, max_nnz)
-    return EllFeatures(
-        values=jnp.asarray(values),
-        indices=jnp.asarray(indices),
-        num_cols=int(shape[1]),
+    values, indices = upload(
+        "features", lambda: (jnp.asarray(values), jnp.asarray(indices))
     )
+    return EllFeatures(values=values, indices=indices, num_cols=int(shape[1]))

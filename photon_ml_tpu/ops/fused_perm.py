@@ -58,6 +58,7 @@ from photon_ml_tpu.ops.features import DenseFeatures
 from photon_ml_tpu.ops.pallas_kernels import pallas_available
 from photon_ml_tpu.ops.permute_net import DevicePlan, apply_plan, device_plan
 from photon_ml_tpu.ops.routing import LANES
+from photon_ml_tpu.telemetry.span import span, upload
 
 from jax.experimental import pallas as pl
 
@@ -713,36 +714,32 @@ def from_coo(
         )
     n, d = shape
     size_floor = max(size_floor, MIN_FUSED_SIZE)
-    rows, cols, vals, hot_matrix, hot_ids, row_counts, col_counts = (
-        prepare_cold_entries(
-            rows, cols, vals, shape, max_nnz_row, hot_col_threshold, max_hot_cols
-        )
-    )
-    nnz = rows.size
-    K = max(
-        _next_pow2(int(row_counts.max()) if nnz else 1),
-        _next_pow2(int(max_nnz_row)) if max_nnz_row is not None else 1,
-        1,
-    )
-    KP = max(_next_pow2(int(col_counts.max()) if nnz else 1), 1)
-    spill = (None, None, None)
-    # pinned paddings promise shape stability across sibling shards: the
-    # layout planner must not replace the flat layout behind them
-    if nnz and not pin_k and not pin_kp:
-        cap, t = resolve_layout(
-            kp_cap, col_split, col_counts, n, d, K, KP,
-            size_floor=size_floor,
-            row_block_k=make_row_block_k(rows, cols, n, d, pow2=True),
-        )
-        if t > 1:
-            import functools
-
-            return build_column_split(
-                functools.partial(from_coo, payload_dtype=payload_dtype),
-                rows, cols, vals, n, d, t, cap,
-                hot_matrix, hot_ids, plan_cache,
+    with span("route/layout", nnz=int(np.size(rows)), blocks=1) as laying:
+        rows, cols, vals, hot_matrix, hot_ids, row_counts, col_counts = (
+            prepare_cold_entries(
+                rows, cols, vals, shape, max_nnz_row, hot_col_threshold,
+                max_hot_cols,
             )
-        if cap is not None:
+        )
+        nnz = rows.size
+        K = max(
+            _next_pow2(int(row_counts.max()) if nnz else 1),
+            _next_pow2(int(max_nnz_row)) if max_nnz_row is not None else 1,
+            1,
+        )
+        KP = max(_next_pow2(int(col_counts.max()) if nnz else 1), 1)
+        spill = (None, None, None)
+        cap, t = None, 1
+        # pinned paddings promise shape stability across sibling shards: the
+        # layout planner must not replace the flat layout behind them
+        if nnz and not pin_k and not pin_kp:
+            cap, t = resolve_layout(
+                kp_cap, col_split, col_counts, n, d, K, KP,
+                size_floor=size_floor,
+                row_block_k=make_row_block_k(rows, cols, n, d, pow2=True),
+            )
+        laying.set_attrs(blocks=t)
+        if t == 1 and cap is not None:
             rows, cols, vals, sr, sc, sv = split_spill_entries(
                 rows, cols, vals, col_counts, cap
             )
@@ -750,6 +747,14 @@ def from_coo(
             row_counts = np.bincount(rows, minlength=n)
             col_counts = np.minimum(col_counts, cap)
             KP = cap
+    if t > 1:
+        import functools
+
+        return build_column_split(
+            functools.partial(from_coo, payload_dtype=payload_dtype),
+            rows, cols, vals, n, d, t, cap,
+            hot_matrix, hot_ids, plan_cache,
+        )
     for name, pin, needed in (("pin_k", pin_k, K), ("pin_kp", pin_kp, KP)):
         if not pin:
             continue
@@ -797,30 +802,32 @@ def assemble(
                 "cross-tile pad that large); use engine='benes' for this shard"
             )
 
-    from photon_ml_tpu.ops.sparse_perm import route_layout
+    from photon_ml_tpu.ops.sparse_perm import _hot_arrays, _spill_arrays, route_layout
 
     ell_pos, _, plan, plan_inv, S = route_layout(
         rows, cols, n, d, K, KP, plan_cache, size_floor, row_counts, col_counts
     )
 
-    ell_flat = np.zeros(S, dtype=np.float32)
-    ell_flat[ell_pos] = vals
+    with span("route/place"):
+        ell_flat = np.zeros(S, dtype=np.float32)
+        ell_flat[ell_pos] = vals
 
-    from photon_ml_tpu.ops.sparse_perm import _spill_arrays
+    def features():
+        hm, hc = _hot_arrays(hot_matrix, hot_ids)
+        sr, sc, sv = _spill_arrays(*spill)
+        return dict(
+            ell_flat=jnp.asarray(ell_flat), hot_matrix=hm, hot_cols=hc,
+            spill_rows=sr, spill_cols=sc, spill_vals=sv,
+        )
 
-    sr, sc, sv = _spill_arrays(*spill)
     return FusedBenesFeatures(
-        ell_flat=jnp.asarray(ell_flat),
-        plan=device_plan(plan),
-        plan_inv=device_plan(plan_inv),
-        hot_matrix=None if hot_matrix is None else jnp.asarray(hot_matrix),
-        hot_cols=None if hot_ids is None else jnp.asarray(hot_ids, dtype=jnp.int32),
+        **upload("features", features),
+        **upload("plan", lambda: dict(
+            plan=device_plan(plan), plan_inv=device_plan(plan_inv)
+        )),
         num_rows_=int(n),
         num_cols_=int(d),
         ell_k=int(K),
         csc_k=int(KP),
-        spill_rows=sr,
-        spill_cols=sc,
-        spill_vals=sv,
         payload_dtype=payload_dtype,
     )

@@ -42,6 +42,7 @@ import numpy as np
 from flax import struct
 
 from photon_ml_tpu.ops import routing
+from photon_ml_tpu.telemetry.span import span, upload
 from photon_ml_tpu.utils.nativesort import lexsort_pairs
 from photon_ml_tpu.ops.permute_net import DevicePlan, apply_plan, device_plan
 
@@ -458,30 +459,34 @@ def build_column_split(
 ) -> ColumnSplitFeatures:
     """Partition COLD entries into ``t`` column blocks and build each with
     ``builder`` (a from_coo-compatible callable); the hot side stays global.
-    Shared by the stage-by-stage and fused engines."""
+    Shared by the stage-by-stage and fused engines. The cut is host layout
+    work: a ``route/layout`` span a block."""
     d_b = -(-d // t)
     bounds = [min(b * d_b, d) for b in range(t + 1)]
-    blk_of = cols // d_b
+    with span("route/layout", nnz=int(rows.size), blocks=t):
+        blk_of = cols // d_b
     blocks = []
     for b in range(t):
         width = bounds[b + 1] - bounds[b]
-        m = blk_of == b
-        if width <= 0 or not m.any():
+        with span("route/layout", nnz=int(rows.size), blocks=t):
+            m = blk_of == b
+            cut = (rows[m], cols[m] - bounds[b], vals[m])
+        if width <= 0 or not cut[0].size:
             blocks.append(_ZeroColumnsBlock(num_rows_=n, num_cols_=max(width, 0)))
             continue
         blocks.append(
             builder(
-                rows[m], cols[m] - bounds[b], vals[m], (n, width),
-                plan_cache=plan_cache, max_hot_cols=0,
+                *cut, (n, width), plan_cache=plan_cache, max_hot_cols=0,
                 kp_cap=cap, col_split=1,
             )
         )
+    hot_side = (None, None) if hot_ids is None else upload(
+        "features", lambda: _hot_arrays(hot_matrix, hot_ids)
+    )
     return ColumnSplitFeatures(
         blocks=tuple(blocks),
-        hot_matrix=None if hot_matrix is None else jnp.asarray(hot_matrix),
-        hot_cols=(
-            None if hot_ids is None else jnp.asarray(hot_ids, dtype=jnp.int32)
-        ),
+        hot_matrix=hot_side[0],
+        hot_cols=hot_side[1],
         col_bounds=tuple(bounds),
         num_rows_=int(n),
         num_cols_=int(d),
@@ -566,39 +571,41 @@ def from_coo(
     :class:`ColumnSplitFeatures`); the result then is a ColumnSplitFeatures.
     """
     n, d = shape
-    rows, cols, vals, hot_matrix, hot_ids, row_counts, col_counts = (
-        prepare_cold_entries(
-            rows, cols, vals, shape, max_nnz_row, hot_col_threshold, max_hot_cols
+    with span("route/layout", nnz=int(np.size(rows)), blocks=1) as laying:
+        rows, cols, vals, hot_matrix, hot_ids, row_counts, col_counts = (
+            prepare_cold_entries(
+                rows, cols, vals, shape, max_nnz_row, hot_col_threshold,
+                max_hot_cols,
+            )
         )
-    )
-    nnz = rows.size
-    k_needed = int(row_counts.max()) if nnz else 1
-    # max_nnz_row doubles as a K floor so callers get shape-stable [n, K]
-    # ELL arrays across datasets (one jit compilation serves them all).
-    K = max(k_needed, int(max_nnz_row) if max_nnz_row is not None else 1, 1)
-    KP = max(int(col_counts.max()) if nnz else 1, 1)
+        nnz = rows.size
+        k_needed = int(row_counts.max()) if nnz else 1
+        # max_nnz_row doubles as a K floor so callers get shape-stable [n, K]
+        # ELL arrays across datasets (one jit compilation serves them all).
+        K = max(k_needed, int(max_nnz_row) if max_nnz_row is not None else 1, 1)
+        KP = max(int(col_counts.max()) if nnz else 1, 1)
 
-    cap, t = (None, 1)
-    if nnz:
-        cap, t = resolve_layout(
-            kp_cap, col_split, col_counts, n, d, K, KP,
-            row_block_k=make_row_block_k(rows, cols, n, d),
-        )
+        cap, t = (None, 1)
+        if nnz:
+            cap, t = resolve_layout(
+                kp_cap, col_split, col_counts, n, d, K, KP,
+                row_block_k=make_row_block_k(rows, cols, n, d),
+            )
+        laying.set_attrs(blocks=t)
+        spill = (None, None, None)
+        if t == 1 and cap is not None:
+            rows, cols, vals, sr, sc, sv = split_spill_entries(
+                rows, cols, vals, col_counts, cap
+            )
+            spill = (sr, sc, sv)
+            row_counts = np.bincount(rows, minlength=n)
+            col_counts = np.minimum(col_counts, cap)
+            KP = cap
     if t > 1:
         return build_column_split(
             from_coo, rows, cols, vals, n, d, t, cap,
             hot_matrix, hot_ids, plan_cache,
         )
-
-    spill = (None, None, None)
-    if cap is not None:
-        rows, cols, vals, sr, sc, sv = split_spill_entries(
-            rows, cols, vals, col_counts, cap
-        )
-        spill = (sr, sc, sv)
-        row_counts = np.bincount(rows, minlength=n)
-        col_counts = np.minimum(col_counts, cap)
-        KP = cap
 
     return _assemble(
         rows, cols, vals, n, d, K, KP, hot_matrix, hot_ids, plan_cache,
@@ -700,6 +707,13 @@ def split_spill_entries(rows, cols, vals, col_counts: np.ndarray, cap: int):
         rows[keep], cols[keep], vals[keep],
         rows[spill], cols[spill], vals[spill],
     )
+
+
+def _hot_arrays(hot_matrix, hot_ids):
+    """Device arrays for a hot side (None, None when there is none)."""
+    if hot_ids is None:
+        return None, None
+    return jnp.asarray(hot_matrix), jnp.asarray(hot_ids, dtype=jnp.int32)
 
 
 def _spill_arrays(spill_rows, spill_cols, spill_vals):
@@ -840,26 +854,30 @@ def route_layout(
 ):
     """Shared routing core for both permutation engines: validate pinned
     paddings, size the network, build slot positions and the (plan,
-    plan_inv) pair. Returns ``(ell_pos, csc_pos, plan, plan_inv, S)``."""
+    plan_inv) pair. Returns ``(ell_pos, csc_pos, plan, plan_inv, S)``.
+    Spans ``route/slot_perm`` (``slots``), ``route/plan`` and
+    ``route/place`` (the inverse plan)."""
     nnz = rows.size
-    if row_counts is None:
-        row_counts = (
-            np.bincount(rows, minlength=n) if nnz else np.zeros(n, np.int64)
-        )
-    if col_counts is None:
-        col_counts = (
-            np.bincount(cols, minlength=d) if nnz else np.zeros(d, np.int64)
-        )
-    assert not nnz or (
-        row_counts.max() <= K and col_counts.max() <= KP
-    ), "pinned paddings smaller than actual degrees"
     S = routing.valid_size(max(n * K, d * KP, size_floor, 1))
-
-    ell_pos, csc_pos, perm = build_slot_perm(
-        rows, cols, n, d, K, KP, S, row_counts, col_counts
-    )
+    with span("route/slot_perm", slots=S):
+        if row_counts is None:
+            row_counts = (
+                np.bincount(rows, minlength=n) if nnz else np.zeros(n, np.int64)
+            )
+        if col_counts is None:
+            col_counts = (
+                np.bincount(cols, minlength=d) if nnz else np.zeros(d, np.int64)
+            )
+        assert not nnz or (
+            row_counts.max() <= K and col_counts.max() <= KP
+        ), "pinned paddings smaller than actual degrees"
+        ell_pos, csc_pos, perm = build_slot_perm(
+            rows, cols, n, d, K, KP, S, row_counts, col_counts
+        )
     plan = _build_plan_cached(perm, plan_cache)
-    return ell_pos, csc_pos, plan, plan.invert(), S
+    with span("route/place"):
+        plan_inv = plan.invert()
+    return ell_pos, csc_pos, plan, plan_inv, S
 
 
 def _assemble(
@@ -890,24 +908,27 @@ def _assemble(
         rows, cols, n, d, K, KP, plan_cache, size_floor, row_counts, col_counts
     )
 
-    ell_values = np.zeros((n, K), dtype=np.float32)
-    ell_values.reshape(-1)[ell_pos] = vals
-    csc_values = np.zeros((d, KP), dtype=np.float32)
-    csc_values.reshape(-1)[csc_pos] = vals
+    with span("route/place"):
+        ell_values = np.zeros((n, K), dtype=np.float32)
+        ell_values.reshape(-1)[ell_pos] = vals
+        csc_values = np.zeros((d, KP), dtype=np.float32)
+        csc_values.reshape(-1)[csc_pos] = vals
 
-    sr, sc, sv = _spill_arrays(*spill)
+    def features():
+        hm, hc = _hot_arrays(hot_matrix, hot_ids)
+        sr, sc, sv = _spill_arrays(*spill)
+        return dict(
+            ell_values=jnp.asarray(ell_values), csc_values=jnp.asarray(csc_values),
+            hot_matrix=hm, hot_cols=hc, spill_rows=sr, spill_cols=sc, spill_vals=sv,
+        )
+
     return BenesSparseFeatures(
-        ell_values=jnp.asarray(ell_values),
-        csc_values=jnp.asarray(csc_values),
-        plan=device_plan(plan),
-        plan_inv=device_plan(plan_inv),
-        hot_matrix=None if hot_matrix is None else jnp.asarray(hot_matrix),
-        hot_cols=None if hot_ids is None else jnp.asarray(hot_ids, dtype=jnp.int32),
+        **upload("features", features),
+        **upload("plan", lambda: dict(
+            plan=device_plan(plan), plan_inv=device_plan(plan_inv)
+        )),
         num_rows_=int(n),
         num_cols_=int(d),
-        spill_rows=sr,
-        spill_cols=sc,
-        spill_vals=sv,
     )
 
 
@@ -929,10 +950,24 @@ def from_ell(ell, plan_cache: Optional[str] = None) -> BenesSparseFeatures:
 
 
 def _build_plan_cached(perm: np.ndarray, cache_dir: Optional[str]):
+    """The routed plan of ``perm``, read back from the plan cache where it
+    holds one, else routed (and written there). Span ``route/plan``:
+    ``cached`` says which, ``bytes`` is the plan file's size (0 where the
+    cache is off)."""
+    with span("route/plan", slots=int(perm.shape[0])) as planning:
+        plan, cached, path = _plan_of(perm, cache_dir)
+        planning.set_attrs(
+            cached=cached, bytes=path.stat().st_size if path is not None else 0
+        )
+    return plan
+
+
+def _plan_of(perm: np.ndarray, cache_dir: Optional[str]):
+    """(plan, read from the cache, its file or None)."""
     if cache_dir is None:
         cache_dir = default_plan_cache()
     if not cache_dir:  # None or "" — disabled
-        return routing.build_plan(perm)
+        return routing.build_plan(perm), False, None
     import hashlib
     from pathlib import Path
 
@@ -946,7 +981,7 @@ def _build_plan_cached(perm: np.ndarray, cache_dir: Optional[str]):
         except Exception:
             plan = None  # unreadable/foreign entry: rebuild and overwrite
         if plan is not None:
-            return plan
+            return plan, True, path
 
     plan = routing.build_plan(perm)
     arrays = {"size": np.int64(plan.size)}
@@ -990,7 +1025,7 @@ def _build_plan_cached(perm: np.ndarray, cache_dir: Optional[str]):
         os.unlink(str(Path(cache_dir) / f"benesplan_{perm.shape[0]}_{h}.npz"))
     except OSError:
         pass
-    return plan
+    return plan, False, path
 
 
 def _load_plan_file(path) -> routing.PermPlan:

@@ -39,13 +39,13 @@ from photon_ml_tpu.utils.nativesort import lexsort_pairs
 from photon_ml_tpu.ops.features import EllFeatures
 from photon_ml_tpu.ops.sparse_perm import (
     _assemble,
+    _hot_arrays,
     coalesce_coo,
-    default_plan_cache,
     select_hot_cols,
     split_hot_entries,
 )
 from photon_ml_tpu.parallel.mesh import place as place_global, shard_map
-from photon_ml_tpu.telemetry.span import span
+from photon_ml_tpu.telemetry.span import span, upload
 
 DATA_AXIS = "data"
 FEAT_AXIS = "feat"
@@ -197,8 +197,10 @@ def grid_from_coo(
     Rows pad to a multiple of the data-axis size, columns to a multiple of
     ``COLUMN_MULTIPLE`` x the feat-axis size; callers padding labels/weights
     must give padding rows weight 0 (padded columns are simply never
-    touched). Tiles are built a few at a time, each with its own device as
-    the default one: span ``grid/build_tile``.
+    touched). The planning before the tiles is a ``route/layout`` span;
+    tiles are built a few at a time, each with its own device as the
+    default one: span ``grid/build_tile``, whose ``route/*`` and
+    ``data/upload`` children are the tile's own.
     """
     if engine not in ("benes", "ell", "fused"):
         raise ValueError(f"unknown engine {engine!r}; expected benes/ell/fused")
@@ -210,7 +212,6 @@ def grid_from_coo(
     n, d = shape
     n_dd = mesh.shape[DATA_AXIS]
     n_df = mesh.shape[FEAT_AXIS]
-    rows, cols, vals = coalesce_coo(rows, cols, vals, n, d)
 
     if n_dd == 1 and n_df == 1 and engine in ("benes", "fused"):
         # Single-tile grid: delegate to the full single-device builder so
@@ -231,17 +232,160 @@ def grid_from_coo(
             hot_col_threshold=hot_col_threshold, max_hot_cols=max_hot_cols,
             kp_cap=kp_cap, col_split=col_split, **single_kw,
         )
-        stacked = jax.tree.map(
+        stacked = upload("tile", lambda: jax.tree.map(
             lambda a: place_global(
                 np.asarray(a)[None, None], mesh,
                 P(DATA_AXIS, FEAT_AXIS, *([None] * np.asarray(a).ndim)),
             ),
             tile,
-        )
+        ))
         return GridShardedFeatures(
             shards=stacked, mesh=mesh, num_rows_=int(n), num_cols_=int(d)
         )
 
+    with span("route/layout", nnz=int(np.size(rows))) as laying:
+        (n_loc, d_loc, tiles_cold, tile_hot, h_common, K, KP, col_blocks, k_blk,
+         block_spill, tile_spill) = _plan_tiles(
+            rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
+            max_hot_cols, kp_cap, col_split,
+        )
+        laying.set_attrs(blocks=col_blocks)
+
+    # In a multi-process cluster, only build (route!) the tiles whose device
+    # belongs to this process — the expensive per-tile routing is O(local
+    # share), not O(global). Non-addressable grid positions reuse one built
+    # tile as a shape template: their content never reaches any device (the
+    # placement callback only reads addressable blocks). K/KP/h_common come
+    # from the GLOBAL degree loop above, so all processes agree on shapes.
+    multiproc = jax.process_count() > 1
+    if multiproc:
+        pidx = jax.process_index()
+        addressable = {
+            (dd, df)
+            for dd in range(n_dd)
+            for df in range(n_df)
+            if mesh.devices[dd, df].process_index == pidx
+        }
+        if not addressable:
+            addressable = {(0, 0)}  # off-mesh process: one template tile
+    else:
+        addressable = None  # build everything
+
+    def _build_tile(dd, df):
+        tr, tc, tv, hm = tiles_cold[dd, df]
+        hot_ids = tile_hot[dd, df] if h_common else None
+        if engine in ("benes", "fused"):
+            assembler = _assemble
+            asm_kw = {}
+            if engine == "fused":
+                from photon_ml_tpu.ops import fused_perm
+
+                assembler = fused_perm.assemble
+                asm_kw = {"payload_dtype": payload_dtype}
+            if col_blocks > 1:
+                # pinned per-block layout: every (tile, block) shares
+                # (k_blk, KP, S_b, spill length), so tiles stack
+                # leaf-by-leaf; k_blk is the per-block ELL width (each
+                # block holds only its columns' entries, so it is smaller
+                # than the full-tile K — the planner priced it this way)
+                from photon_ml_tpu.ops.sparse_perm import ColumnSplitFeatures
+
+                d_bb = -(-d_loc // col_blocks)
+                S_b = routing.valid_size(max(n_loc * k_blk, d_bb * KP, 1))
+                blocks = []
+                for b, (btr, btc, btv, spill) in enumerate(
+                    block_spill[dd, df]
+                ):
+                    blocks.append(assembler(
+                        btr, btc, btv, n_loc, d_bb, k_blk, KP, None, None,
+                        plan_cache, size_floor=S_b, spill=spill, **asm_kw,
+                    ))
+                hot_side = (None, None) if hot_ids is None else upload(
+                    "tile", lambda: _hot_arrays(hm, hot_ids)
+                )
+                return ColumnSplitFeatures(
+                    blocks=tuple(blocks),
+                    hot_matrix=hot_side[0],
+                    hot_cols=hot_side[1],
+                    col_bounds=tuple(
+                        min(b * d_bb, d_loc) for b in range(col_blocks + 1)
+                    ),
+                    num_rows_=int(n_loc),
+                    num_cols_=int(d_loc),
+                )
+            S = routing.valid_size(max(n_loc * K, d_loc * KP, 1))
+            return assembler(
+                tr, tc, tv, n_loc, d_loc, K, KP, hm, hot_ids,
+                plan_cache, size_floor=S, spill=tile_spill[dd, df], **asm_kw,
+            )
+        ell = _ell_tile(tr, tc, tv, n_loc, d_loc, K)
+        if h_common:
+            hot_matrix, hot_cols = upload("tile", lambda: _hot_arrays(hm, hot_ids))
+            return _EllWithHot(ell=ell, hot_matrix=hot_matrix, hot_cols=hot_cols)
+        return ell
+
+    # Each tile is built with its own device as the default one, so what its
+    # builder uploads lands there: the global array is assembled from the
+    # per-device pieces, no tile ever sits on the first device and none is
+    # stacked with its siblings on the host.
+    # Grid positions of other processes' devices are never built; a process
+    # with no device on the mesh builds one tile for its shapes alone.
+    positions = sorted(addressable) if addressable is not None else [
+        (dd, df) for dd in range(n_dd) for df in range(n_df)
+    ]
+
+    def _build_and_upload(pos):
+        dd, df = pos
+        device = mesh.devices[dd, df]
+        local = device.process_index == jax.process_index()
+        with span("grid/build_tile", dd=dd, df=df) as building, (
+            jax.default_device(device) if local else contextlib.nullcontext()
+        ):
+            tile = _build_tile(dd, df)
+            building.set_attrs(slots=_tile_slots(tile))
+            leaves, treedef = jax.tree.flatten(tile)
+            del tile
+            pieces = jax.block_until_ready(
+                [jnp.asarray(leaf)[None, None] for leaf in leaves]
+            )
+        return treedef, pieces
+
+    if engine in ("benes", "fused"):
+        routing._load_native()  # once, before any worker asks for it
+    workers = max(1, min(len(positions), _MAX_TILE_WORKERS, os.cpu_count() or 1))
+    with ThreadPoolExecutor(workers, thread_name_prefix="grid-tile") as pool:
+        built = list(pool.map(_build_and_upload, positions))
+
+    treedef = built[0][0]
+    if any(t != treedef for t, _ in built):
+        raise AssertionError("grid tiles built to different structures")
+    global_leaves = []
+    for i, first in enumerate(built[0][1]):
+        shape = (n_dd, n_df) + first.shape[2:]
+        sharding = jax.sharding.NamedSharding(
+            mesh, P(DATA_AXIS, FEAT_AXIS, *([None] * (len(shape) - 2)))
+        )
+        global_leaves.append(jax.make_array_from_single_device_arrays(
+            shape, sharding,
+            [pieces[i] for (dd, df), (_, pieces) in zip(positions, built)
+             if mesh.devices[dd, df].process_index == jax.process_index()],
+        ))
+    return GridShardedFeatures(
+        shards=jax.tree.unflatten(treedef, global_leaves),
+        mesh=mesh,
+        num_rows_=int(n_loc * n_dd),
+        num_cols_=int(d_loc * n_df),
+    )
+
+
+def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
+                max_hot_cols, kp_cap, col_split):
+    """:func:`grid_from_coo`'s planning before any tile is built: the
+    entries coalesced and cut into tiles, each tile's hot side, the common
+    paddings and the column layout with its spills. Returns ``(n_loc,
+    d_loc, tiles_cold, tile_hot, h_common, K, KP, col_blocks, k_blk,
+    block_spill, tile_spill)``."""
+    rows, cols, vals = coalesce_coo(rows, cols, vals, n, d)
     n_loc = -(-n // n_dd)
     d_loc = -(-d // (n_df * COLUMN_MULTIPLE)) * COLUMN_MULTIPLE
     dd_of = rows // n_loc
@@ -439,153 +583,8 @@ def grid_from_coo(
                     )
             else:
                 tile_spill = {key: (None, None, None) for key in tiles_cold}
-
-    # In a multi-process cluster, only build (route!) the tiles whose device
-    # belongs to this process — the expensive per-tile routing is O(local
-    # share), not O(global). Non-addressable grid positions reuse one built
-    # tile as a shape template: their content never reaches any device (the
-    # placement callback only reads addressable blocks). K/KP/h_common come
-    # from the GLOBAL degree loop above, so all processes agree on shapes.
-    multiproc = jax.process_count() > 1
-    if multiproc:
-        pidx = jax.process_index()
-        addressable = {
-            (dd, df)
-            for dd in range(n_dd)
-            for df in range(n_df)
-            if mesh.devices[dd, df].process_index == pidx
-        }
-        if not addressable:
-            addressable = {(0, 0)}  # off-mesh process: one template tile
-    else:
-        addressable = None  # build everything
-
-    def _build_tile(dd, df):
-        tr, tc, tv, hm = tiles_cold[dd, df]
-        hot_ids = tile_hot[dd, df] if h_common else None
-        if engine in ("benes", "fused"):
-            assembler = _assemble
-            asm_kw = {}
-            if engine == "fused":
-                from photon_ml_tpu.ops import fused_perm
-
-                assembler = fused_perm.assemble
-                asm_kw = {"payload_dtype": payload_dtype}
-            if col_blocks > 1:
-                # pinned per-block layout: every (tile, block) shares
-                # (k_blk, KP, S_b, spill length), so tiles stack
-                # leaf-by-leaf; k_blk is the per-block ELL width (each
-                # block holds only its columns' entries, so it is smaller
-                # than the full-tile K — the planner priced it this way)
-                from photon_ml_tpu.ops.sparse_perm import ColumnSplitFeatures
-
-                d_bb = -(-d_loc // col_blocks)
-                S_b = routing.valid_size(max(n_loc * k_blk, d_bb * KP, 1))
-                blocks = []
-                for b, (btr, btc, btv, spill) in enumerate(
-                    block_spill[dd, df]
-                ):
-                    blocks.append(assembler(
-                        btr, btc, btv, n_loc, d_bb, k_blk, KP, None, None,
-                        plan_cache, size_floor=S_b, spill=spill, **asm_kw,
-                    ))
-                return ColumnSplitFeatures(
-                    blocks=tuple(blocks),
-                    hot_matrix=None if hm is None else jnp.asarray(hm),
-                    hot_cols=(
-                        None if hot_ids is None
-                        else jnp.asarray(hot_ids, dtype=jnp.int32)
-                    ),
-                    col_bounds=tuple(
-                        min(b * d_bb, d_loc) for b in range(col_blocks + 1)
-                    ),
-                    num_rows_=int(n_loc),
-                    num_cols_=int(d_loc),
-                )
-            S = routing.valid_size(max(n_loc * K, d_loc * KP, 1))
-            return assembler(
-                tr, tc, tv, n_loc, d_loc, K, KP, hm, hot_ids,
-                plan_cache, size_floor=S, spill=tile_spill[dd, df], **asm_kw,
-            )
-        ell = _ell_tile(tr, tc, tv, n_loc, d_loc, K)
-        if h_common:
-            return _EllWithHot(
-                ell=ell,
-                hot_matrix=jnp.asarray(hm),
-                hot_cols=jnp.asarray(hot_ids, dtype=jnp.int32),
-            )
-        return ell
-
-    # Each tile is built with its own device as the default one, so what its
-    # builder uploads lands there: the global array is assembled from the
-    # per-device pieces, no tile ever sits on the first device and none is
-    # stacked with its siblings on the host.
-    # Grid positions of other processes' devices are never built; a process
-    # with no device on the mesh builds one tile for its shapes alone.
-    positions = sorted(addressable) if addressable is not None else [
-        (dd, df) for dd in range(n_dd) for df in range(n_df)
-    ]
-
-    def _build_and_upload(pos):
-        dd, df = pos
-        device = mesh.devices[dd, df]
-        local = device.process_index == jax.process_index()
-        with span("grid/build_tile", dd=dd, df=df) as building, (
-            jax.default_device(device) if local else contextlib.nullcontext()
-        ):
-            plans_before = _plan_files(plan_dir)
-            tile = _build_tile(dd, df)
-            slots = _tile_slots(tile)
-            # read back, not routed: the tile has plans and no plan file was
-            # published while it was built (tiles built at once share the
-            # reading; a pattern's tiles are routed in one run, or none is)
-            building.set_attrs(
-                slots=slots,
-                plan_cached=bool(slots and plan_dir)
-                and _plan_files(plan_dir) == plans_before,
-            )
-            leaves, treedef = jax.tree.flatten(tile)
-            del tile
-            pieces = jax.block_until_ready(
-                [jnp.asarray(leaf)[None, None] for leaf in leaves]
-            )
-        return treedef, pieces
-
-    plan_dir = None
-    if engine in ("benes", "fused"):
-        routing._load_native()  # once, before any worker asks for it
-        plan_dir = default_plan_cache() if plan_cache is None else plan_cache
-    workers = max(1, min(len(positions), _MAX_TILE_WORKERS, os.cpu_count() or 1))
-    with ThreadPoolExecutor(workers, thread_name_prefix="grid-tile") as pool:
-        built = list(pool.map(_build_and_upload, positions))
-
-    treedef = built[0][0]
-    if any(t != treedef for t, _ in built):
-        raise AssertionError("grid tiles built to different structures")
-    global_leaves = []
-    for i, first in enumerate(built[0][1]):
-        shape = (n_dd, n_df) + first.shape[2:]
-        sharding = jax.sharding.NamedSharding(
-            mesh, P(DATA_AXIS, FEAT_AXIS, *([None] * (len(shape) - 2)))
-        )
-        global_leaves.append(jax.make_array_from_single_device_arrays(
-            shape, sharding,
-            [pieces[i] for (dd, df), (_, pieces) in zip(positions, built)
-             if mesh.devices[dd, df].process_index == jax.process_index()],
-        ))
-    return GridShardedFeatures(
-        shards=jax.tree.unflatten(treedef, global_leaves),
-        mesh=mesh,
-        num_rows_=int(n_loc * n_dd),
-        num_cols_=int(d_loc * n_df),
-    )
-
-
-def _plan_files(plan_dir: Optional[str]) -> int:
-    """How many files the plan cache holds (0 where it is off or empty)."""
-    if not plan_dir or not os.path.isdir(plan_dir):
-        return 0
-    return len(os.listdir(plan_dir))
+    return (n_loc, d_loc, tiles_cold, tile_hot, h_common, K, KP, col_blocks,
+            k_blk, block_spill, tile_spill)
 
 
 def _tile_slots(tile) -> int:
@@ -607,9 +606,10 @@ def _ell_tile(tr, tc, tv, n_loc: int, d_loc: int, K: int) -> EllFeatures:
     indices = np.zeros((n_loc, K), dtype=np.int32)
     values[tr, slots] = tv
     indices[tr, slots] = tc
-    return EllFeatures(
-        values=jnp.asarray(values), indices=jnp.asarray(indices), num_cols=d_loc
+    values, indices = upload(
+        "tile", lambda: (jnp.asarray(values), jnp.asarray(indices))
     )
+    return EllFeatures(values=values, indices=indices, num_cols=d_loc)
 
 
 @struct.dataclass

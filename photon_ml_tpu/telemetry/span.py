@@ -42,6 +42,7 @@ __all__ = [
     "span",
     "timed_span",
     "union_seconds",
+    "upload",
     "enable_tracing",
     "disable_tracing",
     "barrier_over",
@@ -355,6 +356,29 @@ def span(name: str, device_sync: bool = False, **attrs):
     if not t.enabled:
         return NOOP_SPAN
     return _LiveSpan(t, name, device_sync and t.device_sync, attrs)
+
+
+def upload(what: str, put):
+    """``put()``, which hands host arrays to a device and returns what it
+    made there, inside a span ``data/upload`` (``what``: ``features``,
+    ``plan``, ``re_bucket``, ``rows``, ``tile``) while the tracer is on. The
+    span then waits for those arrays with ``jax.block_until_ready``, not
+    with the span barrier: a host-to-device copy does not follow the
+    compute stream the barrier orders. Its ``bytes`` are what
+    landed on the devices, every replica counted. With the tracer off this
+    is ``put()``, and nothing waits that did not wait before."""
+    if not _TRACER.enabled:
+        return put()
+    import jax
+
+    with span("data/upload", what=what) as uploading:
+        out = put()
+        arrays = [a for a in jax.tree.leaves(out) if isinstance(a, jax.Array)]
+        jax.block_until_ready(arrays)
+        uploading.set_attrs(bytes=sum(
+            shard.data.nbytes for a in arrays for shard in a.addressable_shards
+        ))
+    return out
 
 
 def timed_span(name: str, **attrs) -> _LiveSpan:

@@ -307,9 +307,9 @@ def _random_coo(n, d, k, seed=0):
 
 
 def test_a_tile_says_whether_its_plans_were_read_back(tmp_path):
-    """``grid/build_tile``: one span a tile, its routed slots, and
-    ``plan_cached`` false on the build that routes and true on the next,
-    which finds the plan files."""
+    """``grid/build_tile``: one span a tile, its routed slots, and the
+    ``cached`` attr of the tile's own ``route/plan`` children false on the
+    build that routes and true on the next, which reads the plan files."""
     n, d = 64, 2048
     rows, cols, vals = _random_coo(n, d, 6)
     tracer = enable_tracing(device_sync=False)
@@ -318,15 +318,22 @@ def test_a_tile_says_whether_its_plans_were_read_back(tmp_path):
             grid_from_coo(rows, cols, vals, (n, d), grid_mesh(2, 2), engine="benes",
                           plan_cache=str(tmp_path))
         grid_from_coo(rows, cols, vals, (n, d), grid_mesh(2, 2), engine="ell")
-        tiles = [s.attrs for s in tracer.spans() if s.name == "grid/build_tile"]
+        spans = tracer.spans()
     finally:
         disable_tracing()
+    tiles = [s for s in spans if s.name == "grid/build_tile"]
+    read_back = {}  # a tile's span id -> `cached` of each of its plans
+    for s in spans:
+        if s.name == "route/plan":
+            read_back.setdefault(s.parent_id, []).append(s.attrs["cached"])
     positions = [(dd, df) for dd in range(2) for df in range(2)]
     for build, cached in enumerate([False, True]):
         mine = tiles[4 * build: 4 * build + 4]
-        assert sorted((t["dd"], t["df"]) for t in mine) == positions
-        assert all(t["slots"] > 0 and t["plan_cached"] is cached for t in mine)
-    assert all(t["slots"] == 0 and t["plan_cached"] is False for t in tiles[8:])
+        assert sorted((t.attrs["dd"], t.attrs["df"]) for t in mine) == positions
+        assert all(t.attrs["slots"] > 0 for t in mine)
+        assert all(read_back[t.span_id] and set(read_back[t.span_id]) == {cached}
+                   for t in mine)
+    assert all(t.attrs["slots"] == 0 and t.span_id not in read_back for t in tiles[8:])
 
 
 @pytest.mark.parametrize("n,k", [(64, 6), (512, 1)], ids=["counted", "sorted"])
