@@ -14,9 +14,16 @@ blocks of the RandomEffectDataset. Step (a) projects each bucket through B on
 device (one einsum: X @ B[proj_indices]) and reuses the vmap'd RE trainer in
 latent space. Step (b) never materializes kron(x, v): :class:`KronFeatures`
 implements the three linear maps (matvec / rmatvec / rmatvec_sq) of the
-implicit [n, d·k] design matrix as fused einsums + one scatter-add into the
-[d, k] gradient — so the existing L-BFGS/TRON solvers run unchanged over
-vec(B).
+implicit [n, d·k] design matrix, so the existing L-BFGS/TRON solvers run
+unchanged over vec(B). The maps run over an item-tiled slot layout
+(:class:`KronTiles`), built once per coordinate from the buckets'
+``proj_indices``: every real (entity, local column) slot sorted by its global
+column and cut into tiles of ``TILE`` slots that all belong to one column.
+An evaluation then reads each block once in a dense pass, moves one scalar a
+slot between the two orders (a gather of 128-lane rows, :func:`_take`), and
+sums a tile's slots against the latents laid into tile order once per solve;
+the [d, k] gradient takes one row a tile (about ``slots / TILE + d`` rows),
+not one a slot.
 
 Both steps are kept programs: ``_project`` (the projection's einsums; the
 buckets and B are arguments) is one jitted function for the process, and
@@ -32,7 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +81,87 @@ class MFOptimizationConfiguration:
             raise ValueError("num_iterations must be >= 1")
 
 
+TILE = 128  # slots a tile: one lane row of the chip's vector unit
+
+
+@struct.dataclass
+class KronTiles:
+    """The item-tiled slot layout of a coordinate's buckets (device arrays).
+
+    A slot is one (entity, local column) of a bucket, numbered flat over the
+    buckets' ``[E_b, D_b]`` in bucket order. The real slots (``proj_valid``)
+    sorted by their global column and cut into tiles of ``TILE``: every tile
+    belongs to one column, a column's last tile padded with dead positions.
+    Dead positions name the slot one past the last and the entity one past
+    the last, which the maps read as zeros."""
+
+    slot_of: jax.Array       # [tiles, TILE] flat slot of each position
+    entity_of: jax.Array     # [tiles, TILE] flat entity of each position
+    item_of_tile: jax.Array  # [tiles] global column, ascending
+    pos_of_slot: jax.Array   # [slots] position in the flat [tiles * TILE]; dead: one past
+
+
+def build_kron_tiles(
+    pidxs: List[np.ndarray], pvals: List[np.ndarray], tile: int = TILE
+) -> KronTiles:
+    """The layout of buckets with host ``proj_indices`` / ``proj_valid``
+    (``[E_b, D_b]`` each). A stable sort
+    keeps a column's slots in slot order, so the layout, and the order every
+    map sums in, is a function of the buckets alone."""
+    slots, items, entities = [], [], []
+    slot_base = entity_base = 0
+    for pidx, pval in zip(pidxs, pvals):
+        e_n, d_n = pidx.shape
+        e, d = np.nonzero(pval)
+        slots.append(slot_base + e * d_n + d)
+        items.append(pidx[e, d])
+        entities.append(entity_base + e)
+        slot_base += e_n * d_n
+        entity_base += e_n
+    order = np.argsort(np.concatenate(items), kind="stable")
+    slots = np.concatenate(slots)[order]
+    items = np.concatenate(items)[order]
+    entities = np.concatenate(entities)[order]
+    columns, first, counts = np.unique(items, return_index=True, return_counts=True)
+    if columns.size == 0:  # no real slot: one dead tile
+        columns, first, counts = np.zeros(1, np.int64), np.zeros(1, np.int64), np.zeros(1, np.int64)
+    tiles_of = np.maximum(-(-counts // tile), 1)
+    first_tile = np.cumsum(tiles_of) - tiles_of
+    pos = (np.repeat(first_tile, counts) * tile
+           + np.arange(items.size) - np.repeat(first, counts))
+    n_pos = int(tiles_of.sum()) * tile
+    slot_of = np.full(n_pos, slot_base, np.int32)
+    slot_of[pos] = slots
+    entity_of = np.full(n_pos, entity_base, np.int32)
+    entity_of[pos] = entities
+    pos_of_slot = np.full(slot_base, n_pos, np.int32)
+    pos_of_slot[slots] = pos
+    return KronTiles(
+        slot_of=jnp.asarray(slot_of.reshape(-1, tile)),
+        entity_of=jnp.asarray(entity_of.reshape(-1, tile)),
+        item_of_tile=jnp.asarray(np.repeat(columns, tiles_of).astype(np.int32)),
+        pos_of_slot=jnp.asarray(pos_of_slot),
+    )
+
+
+def _flat_with_zero(parts: List[jax.Array]) -> jax.Array:
+    """The arrays flattened end to end, and one zero after them: what a dead
+    position of the layout reads."""
+    flat = [p.reshape(-1) for p in parts]
+    return jnp.concatenate(flat + [jnp.zeros((1,), flat[0].dtype)])
+
+
+def _take(flat: jax.Array, idx: jax.Array) -> jax.Array:
+    """``flat[idx]`` as a gather of whole 128-lane rows and a one-hot select
+    of the lane, which adds zeros and so is exact. On a TPU v5e a gather of
+    single elements takes about 7 ns an element at a million of them, and
+    this form 2 - 3 ms for the million."""
+    lanes = 128
+    rows = jnp.pad(flat, (0, -flat.shape[0] % lanes)).reshape(-1, lanes)
+    hit = (idx % lanes)[..., None] == jnp.arange(lanes)
+    return jnp.sum(jnp.where(hit, rows[idx // lanes], 0), axis=-1)
+
+
 @struct.dataclass
 class KronFeatures:
     """Implicit design matrix of the projection-matrix solve.
@@ -83,13 +171,29 @@ class KronFeatures:
     x_value-at-global-col-c times latent[e, j]. Bucket blocks are carried as
     parallel lists; rows are the concatenation of all buckets' flattened
     [E*S] axes (padding rows have weight 0 upstream).
+
+    The maps run over the item-tiled layout ``tiles``; ``vs`` holds the
+    latents laid into it, ``[tiles, k, TILE]`` (zero at dead positions):
+    make the features with :meth:`build`, once per solve. They are
+    elementwise products and sums in float32 (no matmul, so no
+    reduced-precision pass), in an order fixed by the layout.
     """
 
     xs: List[jax.Array]        # per bucket [E, S, D] local features
-    pidxs: List[jax.Array]     # per bucket [E, D] global col per local col
     latents: List[jax.Array]   # per bucket [E, k]
+    tiles: KronTiles
+    vs: jax.Array              # [tiles, k, TILE]
     d_global: int = struct.field(pytree_node=False)
     k: int = struct.field(pytree_node=False)
+
+    @classmethod
+    def build(cls, xs, latents, tiles: KronTiles, d_global: int, k: int):
+        """The maps over ``tiles``, the latents gathered into tile order here
+        (they are constant for a solve: no evaluation gathers them again)."""
+        # one zero row after the entities: what a dead position reads
+        v = jnp.concatenate(list(latents) + [jnp.zeros((1, k), latents[0].dtype)])
+        vs = jnp.transpose(v[tiles.entity_of], (0, 2, 1))
+        return cls(xs=xs, latents=latents, tiles=tiles, vs=vs, d_global=d_global, k=k)
 
     @property
     def num_rows(self) -> int:
@@ -101,35 +205,39 @@ class KronFeatures:
 
     def matvec(self, w: jax.Array) -> jax.Array:
         B = w.reshape(self.d_global, self.k)
-        outs = []
-        for x, pidx, v in zip(self.xs, self.pidxs, self.latents):
-            # z[e,s] = x[e,s,:] . (B[pidx[e]] @ v[e]); padding cols have
-            # x == 0 so their (arbitrary) B[0] gather contributes nothing
-            z = jnp.einsum("esd,edk,ek->es", x, B[pidx], v)
-            outs.append(z.reshape(-1))
+        # u[slot] = B[item] . v[entity], a tile at a time: one row of B a tile
+        u_tiles = jnp.sum(self.vs * B[self.tiles.item_of_tile][:, :, None], axis=1)
+        u_all = _take(_flat_with_zero([u_tiles]), self.tiles.pos_of_slot)
+        outs, start = [], 0
+        for x in self.xs:
+            e_n, _, d_n = x.shape
+            u = u_all[start : start + e_n * d_n].reshape(e_n, d_n)
+            start += e_n * d_n
+            outs.append(jnp.sum(x * u[:, None, :], axis=-1).reshape(-1))
         return jnp.concatenate(outs)
 
     def rmatvec(self, c: jax.Array) -> jax.Array:
-        grad = jnp.zeros((self.d_global, self.k), dtype=c.dtype)
-        start = 0
-        for x, pidx, v in zip(self.xs, self.pidxs, self.latents):
-            e_n, s_n = x.shape[0], x.shape[1]
-            cb = c[start : start + e_n * s_n].reshape(e_n, s_n)
-            start += e_n * s_n
-            contrib = jnp.einsum("es,esd,ek->edk", cb, x, v)
-            grad = grad.at[pidx].add(contrib)
-        return grad.reshape(-1)
+        return self._rmatvec(c, squared=False)
 
     def rmatvec_sq(self, c: jax.Array) -> jax.Array:
-        out = jnp.zeros((self.d_global, self.k), dtype=c.dtype)
-        start = 0
-        for x, pidx, v in zip(self.xs, self.pidxs, self.latents):
-            e_n, s_n = x.shape[0], x.shape[1]
+        return self._rmatvec(c, squared=True)
+
+    def _rmatvec(self, c: jax.Array, squared: bool) -> jax.Array:
+        # a[slot] = sum_s c[e, s] x[e, s, d]: one dense pass over each block
+        parts, start = [], 0
+        for x in self.xs:
+            e_n, s_n, _ = x.shape
             cb = c[start : start + e_n * s_n].reshape(e_n, s_n)
             start += e_n * s_n
-            contrib = jnp.einsum("es,esd,ek->edk", cb, x * x, v * v)
-            out = out.at[pidx].add(contrib)
-        return out.reshape(-1)
+            parts.append(jnp.sum(cb[:, :, None] * (x * x if squared else x), axis=1))
+        a_tiles = _take(_flat_with_zero(parts), self.tiles.slot_of)
+        vs = self.vs * self.vs if squared else self.vs
+        per_tile = jnp.sum(vs * a_tiles[:, None, :], axis=-1)  # [tiles, k]
+        grad = jax.ops.segment_sum(
+            per_tile, self.tiles.item_of_tile, num_segments=self.d_global,
+            indices_are_sorted=True,
+        )
+        return grad.reshape(-1)
 
     def row_norms_sq(self) -> jax.Array:
         outs = []
@@ -244,10 +352,10 @@ def _latent_dataset(
 def _matrix_solve_program(
     task: TaskType, optimizer_config: OptimizerConfig, use_l1: bool
 ) -> Callable[..., SolveResult]:
-    """The jitted step (b) of one static key: ``(B0, xs, pidxs, latents,
-    labels, offsets, weights, l2, l1) -> SolveResult`` over vec(B), the
-    per-bucket blocks as lists. Kept for the process in this cache of its
-    own: ``train_glm``'s ``_solve_program`` keeps ONE program (a kept
+    """The jitted step (b) of one static key: ``(B0, xs, latents, labels,
+    offsets, weights, l2, l1, tiles) -> SolveResult`` over vec(B), the
+    per-bucket blocks as lists and ``tiles`` the coordinate's
+    :class:`KronTiles`. Kept for the process in this cache of its own: ``train_glm``'s ``_solve_program`` keeps ONE program (a kept
     program keeps its device reservation), and in a GAME fit that one is the
     fixed effect's — a matrix solve routed through it would evict it at
     every update. Everything that varies between calls is an argument, the
@@ -257,12 +365,10 @@ def _matrix_solve_program(
     configuration = GlmOptimizationConfiguration(optimizer_config=optimizer_config)
     kind = solver_kind(configuration, 1.0 if use_l1 else 0.0)
 
-    def mf_matrix_solve(B0, xs, pidxs, latents, labels, offsets, weights, l2, l1):
+    def mf_matrix_solve(B0, xs, latents, labels, offsets, weights, l2, l1, tiles):
         note_jit_trace("mf_matrix_solve", kind)  # fires only on a (re)trace
-        feats = KronFeatures(
-            xs=xs, pidxs=pidxs, latents=latents,
-            d_global=B0.shape[0], k=B0.shape[1],
-        )
+        d_global, k = B0.shape
+        feats = KronFeatures.build(xs, latents, tiles, d_global, k)
         data = LabeledData(
             features=feats,
             labels=jnp.concatenate([a.reshape(-1) for a in labels]),
@@ -304,6 +410,10 @@ class FactoredRandomEffectCoordinate(Coordinate):
     last_solver_stats: list = dataclasses.field(default_factory=list, repr=False)
     # base_offsets uploaded once for the device-plane updates
     _base_offsets_dev: Optional[jax.Array] = dataclasses.field(
+        default=None, repr=False
+    )
+    # the item-tiled slot layout of the matrix solve, built at the first solve
+    _kron_tiles: Optional[KronTiles] = dataclasses.field(
         default=None, repr=False
     )
 
@@ -419,6 +529,7 @@ class FactoredRandomEffectCoordinate(Coordinate):
         cfg = self.matrix_configuration
         use_l1 = cfg.l1_weight > 0
         solver = _matrix_solve_program(self.task, cfg.optimizer_config, use_l1)
+        tiles = self._layout(ds)
         # not a ``glm/solve`` span: those are the sparse fixed-effect solves,
         # and what reads them counts two maps over that matrix an evaluation
         with span(
@@ -427,13 +538,13 @@ class FactoredRandomEffectCoordinate(Coordinate):
             result = solver(
                 B,
                 [b.X for b in ds.buckets],
-                [b.proj_indices for b in ds.buckets],
                 list(latent_model.coefficients),
                 [b.labels for b in ds.buckets],
                 [b.offsets for b in ds.buckets],
                 [b.weights for b in ds.buckets],
                 jnp.float32(cfg.l2_weight),
                 jnp.float32(cfg.l1_weight),
+                tiles,
             )
             if get_tracer().enabled:
                 # a traced run waits for the solve here, so that the span
@@ -445,6 +556,23 @@ class FactoredRandomEffectCoordinate(Coordinate):
                     coefficients=int(B.size),
                 )
         return result.w.reshape(B.shape)
+
+    def _layout(self, ds: RandomEffectDataset) -> KronTiles:
+        """The matrix solve's item-tiled layout of ``ds``'s buckets, built
+        from their index maps at the first solve and kept: an update's
+        buckets change only in their offsets, never in their slots."""
+        slots = sum(int(np.prod(b.proj_indices.shape)) for b in ds.buckets)
+        if self._kron_tiles is None:
+            self._kron_tiles = build_kron_tiles(
+                [np.asarray(b.proj_indices) for b in ds.buckets],
+                [np.asarray(b.proj_valid) for b in ds.buckets],
+            )
+        elif self._kron_tiles.pos_of_slot.shape[0] != slots:
+            raise ValueError(
+                f"the buckets hold {slots} slots and the kept layout "
+                f"{self._kron_tiles.pos_of_slot.shape[0]}"
+            )
+        return self._kron_tiles
 
     def score(self, model: FactoredRandomEffectModel) -> np.ndarray:
         """Active + passive scores in original row order: the latent model

@@ -17,6 +17,7 @@ from photon_ml_tpu.algorithm.factored_random_effect import (
     KronFeatures,
     MFOptimizationConfiguration,
     _latent_dataset,
+    build_kron_tiles,
 )
 from photon_ml_tpu.data.game_data import FeatureShard, GameData
 from photon_ml_tpu.data.random_effect import (
@@ -90,13 +91,11 @@ class TestKronFeatures:
             jnp.asarray(rng.standard_normal((b.num_entities, k)).astype(np.float32))
             for b in ds.buckets
         ]
-        feats = KronFeatures(
-            xs=[b.X for b in ds.buckets],
-            pidxs=[b.proj_indices for b in ds.buckets],
-            latents=latents,
-            d_global=d,
-            k=k,
+        tiles = build_kron_tiles(
+            [np.asarray(b.proj_indices) for b in ds.buckets],
+            [np.asarray(b.proj_valid) for b in ds.buckets],
         )
+        feats = KronFeatures.build([b.X for b in ds.buckets], latents, tiles, d, k)
         M = self._explicit(ds, latents, d, k)
         w = rng.standard_normal(d * k).astype(np.float32)
         c = rng.standard_normal(M.shape[0]).astype(np.float32)
