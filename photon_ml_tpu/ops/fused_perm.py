@@ -697,10 +697,10 @@ def from_coo(
     """
     from photon_ml_tpu.ops.sparse_perm import (
         build_column_split,
+        capped_assemble,
         make_row_block_k,
         prepare_cold_entries,
         resolve_layout,
-        split_spill_entries,
     )
 
     if (pin_k or pin_kp) and (
@@ -728,7 +728,6 @@ def from_coo(
             1,
         )
         KP = max(_next_pow2(int(col_counts.max()) if nnz else 1), 1)
-        spill = (None, None, None)
         cap, t = None, 1
         # pinned paddings promise shape stability across sibling shards: the
         # layout planner must not replace the flat layout behind them
@@ -739,21 +738,10 @@ def from_coo(
                 row_block_k=make_row_block_k(rows, cols, n, d, pow2=True),
             )
         laying.set_attrs(blocks=t)
-        if t == 1 and cap is not None:
-            rows, cols, vals, sr, sc, sv = split_spill_entries(
-                rows, cols, vals, col_counts, cap
-            )
-            spill = (sr, sc, sv)
-            row_counts = np.bincount(rows, minlength=n)
-            col_counts = np.minimum(col_counts, cap)
-            KP = cap
     if t > 1:
-        import functools
-
         return build_column_split(
-            functools.partial(from_coo, payload_dtype=payload_dtype),
-            rows, cols, vals, n, d, t, cap,
-            hot_matrix, hot_ids, plan_cache,
+            assemble, _next_pow2, rows, cols, vals, col_counts, n, d, t, cap,
+            hot_matrix, hot_ids, plan_cache, payload_dtype=payload_dtype,
         )
     for name, pin, needed in (("pin_k", pin_k, K), ("pin_kp", pin_kp, KP)):
         if not pin:
@@ -762,12 +750,10 @@ def from_coo(
             raise ValueError(f"{name}={pin} must be a power of two")
         if pin < needed:
             raise ValueError(f"{name}={pin} below required group size {needed}")
-    K = max(K, pin_k)
-    KP = max(KP, pin_kp)
-    return assemble(
-        rows, cols, vals, n, d, K, KP, hot_matrix, hot_ids, plan_cache,
-        size_floor=size_floor, row_counts=row_counts, col_counts=col_counts,
-        spill=spill, payload_dtype=payload_dtype,
+    return capped_assemble(
+        assemble, rows, cols, vals, n, d, max(K, pin_k), max(KP, pin_kp), cap,
+        row_counts, col_counts, hot_matrix, hot_ids, plan_cache,
+        size_floor=size_floor, payload_dtype=payload_dtype,
     )
 
 
@@ -787,10 +773,12 @@ def assemble(
     col_counts: Optional[np.ndarray] = None,
     spill=(None, None, None),
     payload_dtype: str = "float32",
+    corder: Optional[np.ndarray] = None,
 ) -> FusedBenesFeatures:
     """Route + lay out prepared cold entries with pinned power-of-two
     paddings — the fused twin of ``sparse_perm._assemble`` (the grid builder
-    stacks identically-shaped tiles built through this)."""
+    stacks identically-shaped tiles built through this; ``corder`` as
+    there)."""
     assert K & (K - 1) == 0 and KP & (KP - 1) == 0, "group sizes must be pow2"
     size_floor = max(size_floor, MIN_FUSED_SIZE)
     for name, group in (("K", K), ("KP", KP)):
@@ -804,8 +792,9 @@ def assemble(
 
     from photon_ml_tpu.ops.sparse_perm import _hot_arrays, _spill_arrays, route_layout
 
-    ell_pos, _, plan, plan_inv, S = route_layout(
-        rows, cols, n, d, K, KP, plan_cache, size_floor, row_counts, col_counts
+    ell_pos, _, _, plan, plan_inv, S = route_layout(
+        rows, cols, n, d, K, KP, plan_cache, size_floor, row_counts,
+        col_counts, corder,
     )
 
     with span("route/place"):

@@ -26,6 +26,7 @@ reference ``host_apply`` used by tests. Device execution lives in
 from __future__ import annotations
 
 import ctypes
+import os
 from dataclasses import dataclass
 from pathlib import Path
 from typing import List, Optional, Sequence, Union
@@ -45,6 +46,13 @@ _NUMPY_COLOR_MAX_EDGES = 1 << 18
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
+
+_LAYOUT_SRC = _NATIVE_DIR / "routedlayout.cpp"
+_layout_lib: Optional[ctypes.CDLL] = None
+_layout_tried = False
+# index dtypes the native inverse takes: a routed plan holds int32, one read
+# back from the plan cache int8
+_INVERT_DTYPES = {np.dtype(np.int8): "i8", np.dtype(np.int32): "i32"}
 
 
 def _load_native():
@@ -68,6 +76,44 @@ def _load_native():
         ]
     _lib = lib
     return _lib
+
+
+def _load_layout():
+    """The routed layout's native passes (native/routedlayout.cpp: the slot
+    permutation, the stage inverses), or None on a host without g++."""
+    global _layout_lib, _layout_tried
+    if _layout_tried:
+        return _layout_lib
+    _layout_tried = True
+    from photon_ml_tpu.utils.nativelib import build_and_load
+
+    lib = build_and_load(_LAYOUT_SRC)
+    if lib is not None:
+        lib.fill_slot_perm.restype = ctypes.c_int
+        lib.fill_slot_perm.argtypes = [
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p,
+        ]
+        for suffix in _INVERT_DTYPES.values():
+            lanes = getattr(lib, f"invert_lanes_{suffix}")
+            lanes.restype = ctypes.c_int
+            lanes.argtypes = [
+                ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
+            ]
+            sub = getattr(lib, f"invert_sublanes_{suffix}")
+            sub.restype = ctypes.c_int
+            sub.argtypes = [
+                ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_int,
+            ]
+    _layout_lib = lib
+    return _layout_lib
+
+
+def inverse_engine() -> str:
+    """What :meth:`PermPlan.invert` runs on this host: ``"native"`` (one
+    threaded pass a stage) or ``"numpy"`` (the fancy-index fallback)."""
+    return "numpy" if _load_layout() is None else "native"
 
 
 def _euler_color_numpy(src: np.ndarray, dst: np.ndarray, deg: int,
@@ -206,30 +252,18 @@ class PermPlan:
     stages: List[Stage]
 
     def invert(self) -> "PermPlan":
-        """Plan for the inverse permutation (stages reversed + inverted)."""
+        """Plan for the inverse permutation (stages reversed + inverted):
+        each stage in one native pass where :func:`inverse_engine` says so,
+        else by numpy."""
+        lib = _load_layout()
         inv_stages: List[Stage] = []
         for st in reversed(self.stages):
             if isinstance(st, LaneShuffle):
-                rows = st.idx.shape[0]
-                inv = np.empty_like(st.idx)
-                r = np.arange(rows)[:, None]
-                inv[r, st.idx] = np.broadcast_to(
-                    np.arange(LANES, dtype=st.idx.dtype), st.idx.shape
-                )
-                inv_stages.append(LaneShuffle(idx=inv))
+                inv_stages.append(LaneShuffle(idx=_invert_stage(st.idx, 0, lib)))
             elif isinstance(st, SublaneShuffle):
-                total, R = st.idx.shape[0], st.rows
-                blk = st.idx.reshape(total // R, R, LANES)
-                inv = np.empty_like(blk)
-                g = np.arange(total // R)[:, None, None]
-                c = np.arange(LANES)[None, None, :]
-                i = np.broadcast_to(
-                    np.arange(R, dtype=st.idx.dtype)[None, :, None], blk.shape
-                )
-                inv[g, blk, c] = i
-                inv_stages.append(
-                    SublaneShuffle(idx=inv.reshape(total, LANES), rows=R)
-                )
+                inv_stages.append(SublaneShuffle(
+                    idx=_invert_stage(st.idx, st.rows, lib), rows=st.rows
+                ))
             elif isinstance(st, Enter):
                 inv_stages.append(Leave(blocks=st.blocks, rows=st.rows))
             elif isinstance(st, Leave):
@@ -237,6 +271,57 @@ class PermPlan:
             else:  # pragma: no cover
                 raise TypeError(st)
         return PermPlan(size=self.size, stages=inv_stages)
+
+
+def _invert_stage(idx: np.ndarray, rows: int, lib) -> np.ndarray:
+    """The inverse of one shuffle stage's ``[total_rows, 128]`` indices, of
+    the same dtype: a lane shuffle's where ``rows`` is 0, else a sublane
+    shuffle's over blocks of ``rows`` rows."""
+    if idx.ndim != 2 or idx.shape[1] != LANES:
+        raise ValueError(f"stage indices of shape {idx.shape}, not [rows, {LANES}]")
+    suffix = _INVERT_DTYPES.get(idx.dtype)
+    if lib is None or suffix is None:
+        return (
+            _invert_lanes_numpy(idx) if not rows
+            else _invert_sublanes_numpy(idx, rows)
+        )
+    idx = np.ascontiguousarray(idx)
+    inv = np.empty_like(idx)
+    threads = ctypes.c_int(max(1, min(os.cpu_count() or 1, 16)))
+    if not rows:
+        rc = getattr(lib, f"invert_lanes_{suffix}")(
+            idx.shape[0], idx.ctypes.data, inv.ctypes.data, threads
+        )
+    else:
+        rc = getattr(lib, f"invert_sublanes_{suffix}")(
+            idx.shape[0], rows, idx.ctypes.data, inv.ctypes.data, threads
+        )
+    if rc != 0:
+        raise ValueError(
+            f"stage indices are not a permutation within each "
+            f"{'row' if not rows else f'block of {rows} rows'} (rc={rc})"
+        )
+    return inv
+
+
+def _invert_lanes_numpy(idx: np.ndarray) -> np.ndarray:
+    """``inv[r, idx[r, c]] = c`` by a fancy-index scatter."""
+    inv = np.empty_like(idx)
+    r = np.arange(idx.shape[0])[:, None]
+    inv[r, idx] = np.broadcast_to(np.arange(LANES, dtype=idx.dtype), idx.shape)
+    return inv
+
+
+def _invert_sublanes_numpy(idx: np.ndarray, R: int) -> np.ndarray:
+    """``inv[g*R + idx[g*R + i, c], c] = i`` by a fancy-index scatter."""
+    total = idx.shape[0]
+    blk = idx.reshape(total // R, R, LANES)
+    inv = np.empty_like(blk)
+    g = np.arange(total // R)[:, None, None]
+    c = np.arange(LANES)[None, None, :]
+    i = np.broadcast_to(np.arange(R, dtype=idx.dtype)[None, :, None], blk.shape)
+    inv[g, blk, c] = i
+    return inv.reshape(total, LANES)
 
 
 def valid_size(n: int) -> int:

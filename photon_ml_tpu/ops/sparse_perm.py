@@ -404,21 +404,29 @@ def make_row_block_k(rows, cols, n: int, d: int, pow2: bool = False):
 
     def row_block_k(t: int) -> int:
         if t not in cache:
-            d_b = -(-d // t)
-            key = rows * t + (cols // d_b)
-            # unique, not bincount: memory stays O(nnz) (a bincount over
-            # n*t bins would transiently allocate ~13 GB at n=1e8, t=16)
-            if key.size:
-                _, counts = np.unique(key, return_counts=True)
-                k = int(counts.max())
-            else:
-                k = 1
+            k = max_block_row_count(rows, cols, n, -(-d // t), t)
             if pow2:
                 k = 1 << max(int(k) - 1, 0).bit_length()
             cache[t] = max(k, 1)
         return cache[t]
 
     return row_block_k
+
+
+def max_block_row_count(rows, cols, n: int, width: int, t: int) -> int:
+    """The most entries any row holds within one of ``t`` column blocks of
+    ``width`` columns (1 with no entries). Counted in one pass where the
+    (row, block) bins are no more than four to an entry, and by a sort
+    where a bincount over ``n * t`` bins would outgrow O(nnz) memory (~13
+    GB at n=1e8, t=16)."""
+    if not rows.size:
+        return 1
+    key = rows * t + cols // width
+    if n * t <= 4 * key.size:
+        counts = np.bincount(key)
+    else:
+        _, counts = np.unique(key, return_counts=True)
+    return int(counts.max())
 
 
 def resolve_kp_cap(
@@ -445,10 +453,12 @@ def resolve_kp_cap(
 
 
 def build_column_split(
-    builder,
+    assembler,
+    group,
     rows: np.ndarray,
     cols: np.ndarray,
     vals: np.ndarray,
+    col_counts: np.ndarray,
     n: int,
     d: int,
     t: int,
@@ -456,11 +466,17 @@ def build_column_split(
     hot_matrix: Optional[np.ndarray],
     hot_ids: Optional[np.ndarray],
     plan_cache: Optional[str],
+    **assembler_kw,
 ) -> ColumnSplitFeatures:
-    """Partition COLD entries into ``t`` column blocks and build each with
-    ``builder`` (a from_coo-compatible callable); the hot side stays global.
-    Shared by the stage-by-stage and fused engines. The cut is host layout
-    work: a ``route/layout`` span a block."""
+    """Partition COLD entries into ``t`` column blocks and lay out each with
+    ``assembler`` (:func:`_assemble` or ``fused_perm.assemble``, its slot
+    groups ``group`` of a block's largest degrees); the hot side stays
+    global. Shared by the stage-by-stage and fused engines. The entries come
+    coalesced, row-major and cold from :func:`prepare_cold_entries`
+    (``col_counts`` their column degrees), so a block is a cut of them and
+    goes straight to :func:`capped_assemble`: nothing is coalesced or
+    counted twice. The cut is host layout work: a ``route/layout`` span a
+    block."""
     d_b = -(-d // t)
     bounds = [min(b * d_b, d) for b in range(t + 1)]
     with span("route/layout", nnz=int(rows.size), blocks=t):
@@ -471,15 +487,18 @@ def build_column_split(
         with span("route/layout", nnz=int(rows.size), blocks=t):
             m = blk_of == b
             cut = (rows[m], cols[m] - bounds[b], vals[m])
-        if width <= 0 or not cut[0].size:
+            empty = width <= 0 or not cut[0].size
+            if not empty:
+                row_counts = np.bincount(cut[0], minlength=n)
+                counts = col_counts[bounds[b]: bounds[b + 1]]
+        if empty:
             blocks.append(_ZeroColumnsBlock(num_rows_=n, num_cols_=max(width, 0)))
             continue
-        blocks.append(
-            builder(
-                *cut, (n, width), plan_cache=plan_cache, max_hot_cols=0,
-                kp_cap=cap, col_split=1,
-            )
-        )
+        blocks.append(capped_assemble(
+            assembler, *cut, n, width, group(int(row_counts.max())),
+            group(int(counts.max())), cap, row_counts, counts, None, None,
+            plan_cache, **assembler_kw,
+        ))
     hot_side = (None, None) if hot_ids is None else upload(
         "features", lambda: _hot_arrays(hot_matrix, hot_ids)
     )
@@ -490,6 +509,30 @@ def build_column_split(
         col_bounds=tuple(bounds),
         num_rows_=int(n),
         num_cols_=int(d),
+    )
+
+
+def capped_assemble(assembler, rows, cols, vals, n, d, K, KP, cap, row_counts,
+                    col_counts, hot_matrix, hot_ids, plan_cache, **assembler_kw):
+    """``assembler`` of prepared cold entries, every column first capped at
+    ``cap`` routed entries where ``cap`` is below ``KP`` (a ``route/layout``
+    span; :func:`split_spill_entries`): the spill goes to its own side, the
+    counts are the ones given less what spilled, and the kept entries' (col,
+    row) order goes on to :func:`build_slot_perm`."""
+    spill, corder = (None, None, None), None
+    if cap is not None and cap < KP:
+        with span("route/layout", nnz=int(rows.size), blocks=1):
+            rows, cols, vals, sr, sc, sv, corder = split_spill_entries(
+                rows, cols, vals, col_counts, cap
+            )
+            spill = (sr, sc, sv)
+            row_counts = row_counts - np.bincount(sr, minlength=row_counts.size)
+            col_counts = np.minimum(col_counts, cap)
+        KP = cap
+    return assembler(
+        rows, cols, vals, n, d, K, KP, hot_matrix, hot_ids, plan_cache,
+        row_counts=row_counts, col_counts=col_counts, spill=spill,
+        corder=corder, **assembler_kw,
     )
 
 
@@ -592,24 +635,14 @@ def from_coo(
                 row_block_k=make_row_block_k(rows, cols, n, d),
             )
         laying.set_attrs(blocks=t)
-        spill = (None, None, None)
-        if t == 1 and cap is not None:
-            rows, cols, vals, sr, sc, sv = split_spill_entries(
-                rows, cols, vals, col_counts, cap
-            )
-            spill = (sr, sc, sv)
-            row_counts = np.bincount(rows, minlength=n)
-            col_counts = np.minimum(col_counts, cap)
-            KP = cap
     if t > 1:
         return build_column_split(
-            from_coo, rows, cols, vals, n, d, t, cap,
+            _assemble, int, rows, cols, vals, col_counts, n, d, t, cap,
             hot_matrix, hot_ids, plan_cache,
         )
-
-    return _assemble(
-        rows, cols, vals, n, d, K, KP, hot_matrix, hot_ids, plan_cache,
-        row_counts=row_counts, col_counts=col_counts, spill=spill,
+    return capped_assemble(
+        _assemble, rows, cols, vals, n, d, K, KP, cap, row_counts, col_counts,
+        hot_matrix, hot_ids, plan_cache,
     )
 
 
@@ -631,25 +664,29 @@ def prepare_cold_entries(
     rows, cols, vals = coalesce_coo(rows, cols, vals, n, d)
 
     nnz = rows.size
-    if max_nnz_row is not None and nnz:
-        k_orig = int(np.bincount(rows, minlength=n).max())
+    if not nnz:
+        return (rows, cols, vals, None, None, np.zeros(n, np.int64),
+                np.zeros(d, np.int64))
+    row_counts = np.bincount(rows, minlength=n)
+    if max_nnz_row is not None:
+        k_orig = int(row_counts.max())
         if k_orig > int(max_nnz_row):
             raise ValueError(
                 f"row with {k_orig} nnz exceeds max_nnz_row={max_nnz_row}"
             )
 
+    col_counts = np.bincount(cols, minlength=d)
     hot_ids = select_hot_cols(
-        rows, cols, n, d, hot_col_threshold, max_hot_cols
+        rows, cols, n, d, hot_col_threshold, max_hot_cols, col_counts
     )
     hot_matrix = None
     if hot_ids is not None:
         rows, cols, vals, hot_matrix = split_hot_entries(
             rows, cols, vals, n, d, hot_ids
         )
-        nnz = rows.size
-
-    row_counts = np.bincount(rows, minlength=n) if nnz else np.zeros(n, np.int64)
-    col_counts = np.bincount(cols, minlength=d) if nnz else np.zeros(d, np.int64)
+        # every entry of a hot column went to the dense side
+        row_counts = np.bincount(rows, minlength=n)
+        col_counts[hot_ids] = 0
     return rows, cols, vals, hot_matrix, hot_ids, row_counts, col_counts
 
 
@@ -691,21 +728,27 @@ def split_spill_entries(rows, cols, vals, col_counts: np.ndarray, cap: int):
     """Split entries so every column keeps at most ``cap`` routed entries.
 
     Returns ``(cold_rows, cold_cols, cold_vals, spill_rows, spill_cols,
-    spill_vals)``. Kept entries are each column's first ``cap`` in (col,
-    row) order — deterministic for plan-cache stability.
+    spill_vals, cold_corder)``. Kept entries are each column's first ``cap``
+    in (col, row) order — deterministic for plan-cache stability — and
+    ``cold_corder`` is their (col, row) order, the subsequence of the one
+    sorted here, for :func:`build_slot_perm`.
     """
     nnz = rows.size
     corder = lexsort_pairs(cols, rows)
     col_starts = np.zeros(col_counts.size + 1, dtype=np.int64)
     np.cumsum(col_counts, out=col_starts[1:])
     rank = np.arange(nnz, dtype=np.int64) - col_starts[cols[corder]]
-    spill_sorted = rank >= cap
-    spill = np.zeros(nnz, dtype=bool)
-    spill[corder] = spill_sorted
-    keep = ~spill
+    keep_sorted = rank < cap
+    keep = np.zeros(nnz, dtype=bool)
+    kept_in_corder = corder[keep_sorted]
+    keep[kept_in_corder] = True
+    spill = ~keep
+    # an entry's index among the kept, read at the kept in (col, row) order
+    kept_index = np.cumsum(keep, dtype=np.int64) - 1
     return (
         rows[keep], cols[keep], vals[keep],
         rows[spill], cols[spill], vals[spill],
+        kept_index[kept_in_corder],
     )
 
 
@@ -743,9 +786,10 @@ def coalesce_coo(rows, cols, vals, n: int, d: int):
         boundary = np.empty(rows.size, dtype=bool)
         boundary[0] = True
         boundary[1:] = (rows[1:] != rows[:-1]) | (cols[1:] != cols[:-1])
-        seg = np.cumsum(boundary) - 1
-        summed = np.zeros(int(boundary.sum()), dtype=np.float64)
-        np.add.at(summed, seg, vals)
+        seg = np.cumsum(boundary, dtype=np.int64) - 1
+        # the entries of a segment summed in order in float64, as an
+        # unbuffered add would, in one linear pass
+        summed = np.bincount(seg, weights=vals.astype(np.float64))
         rows, cols = rows[boundary], cols[boundary]
         vals = summed.astype(np.float32)
     return rows, cols, vals
@@ -758,8 +802,10 @@ def select_hot_cols(
     d: int,
     hot_col_threshold: Optional[int],
     max_hot_cols: int,
+    col_counts: Optional[np.ndarray] = None,
 ) -> Optional[np.ndarray]:
-    """Pick the hot-column set (sorted ids) or None.
+    """Pick the hot-column set (sorted ids) or None (``col_counts``: the
+    columns' degrees, where the caller holds them).
 
     A column only qualifies when densifying it is actually cheap: degree
     >= n/16 bounds the dense-storage inflation at 16x the entries moved
@@ -770,7 +816,9 @@ def select_hot_cols(
     nnz = rows.size
     if not nnz or max_hot_cols <= 0:
         return None
-    col_counts_all = np.bincount(cols, minlength=d)
+    col_counts_all = (
+        np.bincount(cols, minlength=d) if col_counts is None else col_counts
+    )
     if hot_col_threshold is None:
         thr = max(8, int(4 * np.ceil(nnz / max(d, 1))), n_rows_per_shard // 16)
     else:
@@ -808,36 +856,58 @@ def build_slot_perm(
     S: int,
     row_counts: np.ndarray,
     col_counts: np.ndarray,
+    corder: Optional[np.ndarray] = None,
 ):
-    """(ell_pos, csc_pos, perm) for one routed layout.
+    """(ell_pos, csc_pos, corder, perm) for one routed layout.
 
-    ell_pos[e]: ELL slot of entry e (row-major position row*K + slot).
-    csc_pos[e]: CSC slot of entry e (column-major position col*KP + slot).
-    perm: bijection on [0, S) with perm[q] = p for real entries and pads
-    mapped to pads in ascending order. Shared by the stage-by-stage and
-    fused engines so both route identical networks for one pattern.
+    The entries come row-major (rows ascending, as :func:`coalesce_coo`
+    leaves them). ell_pos[e]: ELL slot of entry e (row-major position
+    row*K + slot), ascending. corder: the entries in (col, row) order —
+    the caller's where it has sorted them already
+    (:func:`split_spill_entries`), which spares a second sort. csc_pos[i]:
+    CSC slot (column-major position col*KP + slot) of entry corder[i],
+    ascending. perm: bijection on [0, S) with perm[q] = p for real entries
+    and pads mapped to pads in ascending order: one walk of
+    ``native/routedlayout.cpp``, numpy's passes on a host without g++.
+    Shared by the stage-by-stage and fused engines so both route identical
+    networks for one pattern.
     """
+    rows = np.asarray(rows, dtype=np.int64)
+    cols = np.asarray(cols, dtype=np.int64)
     nnz = rows.size
     row_starts = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(row_counts, out=row_starts[1:])
-    ell_slot = np.arange(nnz, dtype=np.int64) - row_starts[rows]
-    ell_pos = rows * K + ell_slot
+    ell_pos = rows * K + (np.arange(nnz, dtype=np.int64) - row_starts[rows])
 
-    corder = lexsort_pairs(cols, rows)
+    if corder is None:
+        corder = lexsort_pairs(cols, rows)
     col_starts = np.zeros(d + 1, dtype=np.int64)
     np.cumsum(col_counts, out=col_starts[1:])
-    csc_slot = np.arange(nnz, dtype=np.int64) - col_starts[cols[corder]]
-    csc_pos_sorted = cols[corder] * KP + csc_slot
-    csc_pos = np.empty(nnz, dtype=np.int64)
-    csc_pos[corder] = csc_pos_sorted
+    cols_sorted = cols[corder]
+    csc_pos = cols_sorted * KP + (
+        np.arange(nnz, dtype=np.int64) - col_starts[cols_sorted]
+    )
+    ell_of_csc = ell_pos[corder]
 
-    perm = np.full(S, -1, dtype=np.int64)
-    perm[csc_pos] = ell_pos
-    free_dst = np.flatnonzero(perm < 0)
-    used_src = np.zeros(S, dtype=bool)
-    used_src[ell_pos] = True
-    perm[free_dst] = np.flatnonzero(~used_src)
-    return ell_pos, csc_pos, perm
+    perm = np.empty(S, dtype=np.int64)
+    lib = routing._load_layout()
+    if lib is not None:
+        rc = lib.fill_slot_perm(
+            S, nnz, ell_pos.ctypes.data, csc_pos.ctypes.data,
+            ell_of_csc.ctypes.data, perm.ctypes.data,
+        )
+        if rc != 0:
+            raise ValueError(f"slot positions are not a layout of S={S} (rc={rc})")
+        return ell_pos, csc_pos, corder, perm
+    # pads to pads, ascending: two masks over the S slots and one list of
+    # the free sources, placed through the destinations' mask
+    pad_dst = np.ones(S, dtype=bool)
+    pad_dst[csc_pos] = False
+    pad_src = np.ones(S, dtype=bool)
+    pad_src[ell_pos] = False
+    perm[pad_dst] = np.flatnonzero(pad_src)
+    perm[csc_pos] = ell_of_csc
+    return ell_pos, csc_pos, corder, perm
 
 
 def route_layout(
@@ -851,12 +921,15 @@ def route_layout(
     size_floor: int = 0,
     row_counts: Optional[np.ndarray] = None,
     col_counts: Optional[np.ndarray] = None,
+    corder: Optional[np.ndarray] = None,
 ):
     """Shared routing core for both permutation engines: validate pinned
     paddings, size the network, build slot positions and the (plan,
-    plan_inv) pair. Returns ``(ell_pos, csc_pos, plan, plan_inv, S)``.
+    plan_inv) pair. Returns ``(ell_pos, csc_pos, corder, plan, plan_inv,
+    S)`` (the first three as :func:`build_slot_perm` gives them).
     Spans ``route/slot_perm`` (``slots``), ``route/plan`` and
-    ``route/place`` (the inverse plan)."""
+    ``route/place`` (the inverse plan; ``invert``: ``native`` | ``numpy``,
+    :func:`routing.inverse_engine`)."""
     nnz = rows.size
     S = routing.valid_size(max(n * K, d * KP, size_floor, 1))
     with span("route/slot_perm", slots=S):
@@ -871,13 +944,13 @@ def route_layout(
         assert not nnz or (
             row_counts.max() <= K and col_counts.max() <= KP
         ), "pinned paddings smaller than actual degrees"
-        ell_pos, csc_pos, perm = build_slot_perm(
-            rows, cols, n, d, K, KP, S, row_counts, col_counts
+        ell_pos, csc_pos, corder, perm = build_slot_perm(
+            rows, cols, n, d, K, KP, S, row_counts, col_counts, corder
         )
     plan = _build_plan_cached(perm, plan_cache)
-    with span("route/place"):
+    with span("route/place", invert=routing.inverse_engine()):
         plan_inv = plan.invert()
-    return ell_pos, csc_pos, plan, plan_inv, S
+    return ell_pos, csc_pos, corder, plan, plan_inv, S
 
 
 def _assemble(
@@ -895,24 +968,27 @@ def _assemble(
     row_counts: Optional[np.ndarray] = None,
     col_counts: Optional[np.ndarray] = None,
     spill=(None, None, None),
+    corder: Optional[np.ndarray] = None,
 ) -> BenesSparseFeatures:
     """Route + lay out one (cold-entries, hot-side) pair with pinned paddings.
 
     K/KP/size_floor are caller-pinned so independent shards of one dataset
     can be forced onto identical network shapes (the sharded builder stacks
     them under one compiled program). Callers that already hold the degree
-    bincounts pass them to skip a recount. ``spill`` is an optional
-    (rows, cols, vals) COO side of over-cap entries (see auto_kp_cap).
+    bincounts pass them to skip a recount, and the entries' (col, row)
+    order (``corder``) to skip a sort. ``spill`` is an optional (rows, cols,
+    vals) COO side of over-cap entries (see auto_kp_cap).
     """
-    ell_pos, csc_pos, plan, plan_inv, S = route_layout(
-        rows, cols, n, d, K, KP, plan_cache, size_floor, row_counts, col_counts
+    ell_pos, csc_pos, corder, plan, plan_inv, S = route_layout(
+        rows, cols, n, d, K, KP, plan_cache, size_floor, row_counts,
+        col_counts, corder,
     )
 
     with span("route/place"):
         ell_values = np.zeros((n, K), dtype=np.float32)
         ell_values.reshape(-1)[ell_pos] = vals
         csc_values = np.zeros((d, KP), dtype=np.float32)
-        csc_values.reshape(-1)[csc_pos] = vals
+        csc_values.reshape(-1)[csc_pos] = vals[corder]
 
     def features():
         hm, hc = _hot_arrays(hot_matrix, hot_ids)

@@ -245,7 +245,7 @@ def grid_from_coo(
 
     with span("route/layout", nnz=int(np.size(rows))) as laying:
         (n_loc, d_loc, tiles_cold, tile_hot, h_common, K, KP, col_blocks, k_blk,
-         block_spill, tile_spill) = _plan_tiles(
+         block_spill, tile_spill, tile_corder) = _plan_tiles(
             rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
             max_hot_cols, kp_cap, col_split,
         )
@@ -293,12 +293,13 @@ def grid_from_coo(
                 d_bb = -(-d_loc // col_blocks)
                 S_b = routing.valid_size(max(n_loc * k_blk, d_bb * KP, 1))
                 blocks = []
-                for b, (btr, btc, btv, spill) in enumerate(
+                for b, (btr, btc, btv, spill, corder) in enumerate(
                     block_spill[dd, df]
                 ):
                     blocks.append(assembler(
                         btr, btc, btv, n_loc, d_bb, k_blk, KP, None, None,
-                        plan_cache, size_floor=S_b, spill=spill, **asm_kw,
+                        plan_cache, size_floor=S_b, spill=spill,
+                        corder=corder, **asm_kw,
                     ))
                 hot_side = (None, None) if hot_ids is None else upload(
                     "tile", lambda: _hot_arrays(hm, hot_ids)
@@ -316,7 +317,8 @@ def grid_from_coo(
             S = routing.valid_size(max(n_loc * K, d_loc * KP, 1))
             return assembler(
                 tr, tc, tv, n_loc, d_loc, K, KP, hm, hot_ids,
-                plan_cache, size_floor=S, spill=tile_spill[dd, df], **asm_kw,
+                plan_cache, size_floor=S, spill=tile_spill[dd, df],
+                corder=tile_corder[dd, df], **asm_kw,
             )
         ell = _ell_tile(tr, tc, tv, n_loc, d_loc, K)
         if h_common:
@@ -351,7 +353,9 @@ def grid_from_coo(
         return treedef, pieces
 
     if engine in ("benes", "fused"):
-        routing._load_native()  # once, before any worker asks for it
+        # once, before any worker asks for them
+        routing._load_native()
+        routing._load_layout()
     workers = max(1, min(len(positions), _MAX_TILE_WORKERS, os.cpu_count() or 1))
     with ThreadPoolExecutor(workers, thread_name_prefix="grid-tile") as pool:
         built = list(pool.map(_build_and_upload, positions))
@@ -384,7 +388,9 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
     entries coalesced and cut into tiles, each tile's hot side, the common
     paddings and the column layout with its spills. Returns ``(n_loc,
     d_loc, tiles_cold, tile_hot, h_common, K, KP, col_blocks, k_blk,
-    block_spill, tile_spill)``."""
+    block_spill, tile_spill, tile_corder)``: a capped tile's or block's
+    entries come with their (col, row) order from the cap's sort, or None
+    (``sparse_perm.split_spill_entries``)."""
     rows, cols, vals = coalesce_coo(rows, cols, vals, n, d)
     n_loc = -(-n // n_dd)
     d_loc = -(-d // (n_df * COLUMN_MULTIPLE)) * COLUMN_MULTIPLE
@@ -405,6 +411,7 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
     # all-zero dense column (an exact no-op in every linear map).
     tile_entries = {}
     tile_hot = {}
+    tile_col_counts = {}
     h_common = 0
     for dd in range(n_dd):
         for df in range(n_df):
@@ -412,9 +419,13 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
             tr = rows[lo:hi] - dd * n_loc
             tc = cols[lo:hi] - df * d_loc
             tv = vals[lo:hi]
+            counts = np.bincount(tc, minlength=d_loc)
             hot = select_hot_cols(
-                tr, tc, n_loc, d_loc, hot_col_threshold, max_hot_cols
+                tr, tc, n_loc, d_loc, hot_col_threshold, max_hot_cols, counts
             )
+            if hot is not None:
+                counts[hot] = 0  # every entry of a hot column goes dense
+            tile_col_counts[dd, df] = counts
             tile_entries[dd, df] = (tr, tc, tv)
             tile_hot[dd, df] = hot
             if hot is not None:
@@ -424,7 +435,6 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
     K = 1
     KP = 1
     tiles_cold = {}
-    tile_col_counts = {}
     for key, (tr, tc, tv) in tile_entries.items():
         hot = tile_hot[key]
         hm = None
@@ -443,10 +453,6 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
             hot_full[: hot.size] = hot
             tile_hot[key] = hot_full
         tiles_cold[key] = (tr, tc, tv, hm)
-        tile_col_counts[key] = (
-            np.bincount(tc, minlength=d_loc) if tr.size
-            else np.zeros(d_loc, np.int64)
-        )
         if tr.size:
             K = max(K, int(np.bincount(tr).max()))
             KP = max(KP, int(tile_col_counts[key].max()))
@@ -466,11 +472,13 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
     # column split turns each tile into a ColumnSplitFeatures of
     # identically-shaped sub-blocks.
     tile_spill = {key: (None, None, None) for key in tiles_cold}
+    tile_corder = {key: None for key in tiles_cold}
     col_blocks = 1
     k_blk = K  # per-block pinned ELL width when the columns split
     block_spill: dict = {}
     if engine in ("benes", "fused") and (kp_cap or col_split != 1):
         from photon_ml_tpu.ops.sparse_perm import (
+            max_block_row_count,
             resolve_layout,
             split_spill_entries,
         )
@@ -486,22 +494,15 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
             nnz any tile-local row holds within one column block, over ALL
             tiles (blocks stack across tiles, so the pin is the global
             max). Same refinement as sparse_perm.make_row_block_k: memoized
-            per t (the planner asks once a candidate cap), counted in one
-            pass where the (row, block) bins are no more than a few to an
-            entry and by a sort where they would outgrow O(nnz) memory."""
+            per t (the planner asks once a candidate cap)."""
             if t in block_k:
                 return block_k[t]
             d_bb_t = -(-d_loc // t)
             k_max = 1
             for tr, tc, _tv, _hm in tiles_cold.values():
-                if not tr.size:
-                    continue
-                key2 = tr.astype(np.int64) * t + tc // d_bb_t
-                if n_loc * t <= 4 * key2.size:
-                    cnts = np.bincount(key2)
-                else:
-                    _, cnts = np.unique(key2, return_counts=True)
-                k_max = max(k_max, int(cnts.max()))
+                k_max = max(k_max, max_block_row_count(
+                    tr.astype(np.int64), tc, n_loc, d_bb_t, t
+                ))
             if engine == "fused":
                 k_max = 1 << max(k_max - 1, 0).bit_length()
             block_k[t] = k_max
@@ -532,27 +533,28 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
                         np.bincount(btc, minlength=d_bb) if btr.size
                         else np.zeros(d_bb, np.int64)
                     )
+                    corder = None
                     if cap is not None and btr.size and counts_b.max() > cap:
-                        btr, btc, btv, sr, sc, sv = split_spill_entries(
-                            btr, btc, btv, counts_b, cap
+                        btr, btc, btv, sr, sc, sv, corder = (
+                            split_spill_entries(btr, btc, btv, counts_b, cap)
                         )
                     else:
                         sr = np.zeros(0, np.int64)
                         sc = np.zeros(0, np.int64)
                         sv = np.zeros(0, np.float32)
-                    blocks.append((btr, btc, btv, sr, sc, sv))
+                    blocks.append((btr, btc, btv, sr, sc, sv, corder))
                     m_max = max(m_max, sr.size)
                 tile_blocks[key] = blocks
             for key, blocks in tile_blocks.items():
                 block_spill[key] = []
-                for b, (btr, btc, btv, sr, sc, sv) in enumerate(blocks):
+                for btr, btc, btv, sr, sc, sv, corder in blocks:
                     pad = m_max - sr.size
                     spill = (
                         (np.pad(sr, (0, pad)), np.pad(sc, (0, pad)),
                          np.pad(sv, (0, pad)))
                         if m_max else (None, None, None)
                     )
-                    block_spill[key].append((btr, btc, btv, spill))
+                    block_spill[key].append((btr, btc, btv, spill, corder))
             if cap is not None:
                 KP = cap
         elif cap is not None:
@@ -560,8 +562,8 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
             for key, (tr, tc, tv, hm) in tiles_cold.items():
                 counts = tile_col_counts[key]
                 if tr.size and counts.max() > cap:
-                    tr, tc, tv, sr, sc, sv = split_spill_entries(
-                        tr, tc, tv, counts, cap
+                    tr, tc, tv, sr, sc, sv, tile_corder[key] = (
+                        split_spill_entries(tr, tc, tv, counts, cap)
                     )
                     tiles_cold[key] = (tr, tc, tv, hm)
                 else:
@@ -584,7 +586,7 @@ def _plan_tiles(rows, cols, vals, n, d, n_dd, n_df, engine, hot_col_threshold,
             else:
                 tile_spill = {key: (None, None, None) for key in tiles_cold}
     return (n_loc, d_loc, tiles_cold, tile_hot, h_common, K, KP, col_blocks,
-            k_blk, block_spill, tile_spill)
+            k_blk, block_spill, tile_spill, tile_corder)
 
 
 def _tile_slots(tile) -> int:
