@@ -29,6 +29,7 @@ over the mesh's entity axis with zero training-time communication.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -38,7 +39,7 @@ from photon_ml_tpu.utils.nativesort import lexsort_pairs
 from flax import struct
 
 from photon_ml_tpu.projector import ProjectorType, RandomProjectionMatrix
-from photon_ml_tpu.telemetry.span import span, upload
+from photon_ml_tpu.telemetry.span import get_tracer, span, upload
 
 
 @dataclasses.dataclass(frozen=True)
@@ -256,11 +257,78 @@ def _expand_nnz(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Flatten the CSR slices of ``act_rows`` into (sample_index, flat_index)
     pairs: sample_index points back into act_rows, flat_index into fc/fv."""
-    cnt = row_end[act_rows] - row_start[act_rows]
+    starts = row_start[act_rows]
+    cnt = row_end[act_rows] - starts
+    if cnt.size and (cnt == cnt[0]).all():  # rows of one width, as a dense shard's
+        width = int(cnt[0])
+        rep = np.repeat(np.arange(len(act_rows), dtype=np.int64), width)
+        return rep, (starts[:, None] + np.arange(width, dtype=np.int64)).reshape(-1)
     total = int(cnt.sum())
     rep = np.repeat(np.arange(len(act_rows), dtype=np.int64), cnt)
-    within = np.arange(total, dtype=np.int64) - np.repeat(np.cumsum(cnt) - cnt, cnt)
-    return rep, row_start[act_rows][rep] + within
+    # a nonzero's flat index: its row's start, plus its rank within the row
+    return rep, np.repeat(starts - (np.cumsum(cnt) - cnt), cnt) + np.arange(total, dtype=np.int64)
+
+
+def _dense_span(keys: np.ndarray, span: int, count: int) -> bool:
+    """Whether keys in ``[0, span)`` are better found through a table of
+    ``span`` slots than by sorting: the table is at most four times
+    ``count`` long and every key lies in it."""
+    return 0 < span <= 4 * count and (
+        keys.size == 0 or (int(keys.min()) >= 0 and int(keys.max()) < span)
+    )
+
+
+def _unique_keys(keys: np.ndarray, span: int) -> np.ndarray:
+    """``np.unique(keys)``, for keys expected in ``[0, span)``: a pass over
+    a table of flags where that table is short, else a sort."""
+    if not _dense_span(keys, span, keys.size):
+        return np.unique(keys)
+    seen = np.zeros(span, dtype=bool)
+    seen[keys] = True
+    return np.flatnonzero(seen)
+
+
+class _KeyIndex:
+    """Where query keys sit in ``ukeys`` (sorted, unique, expected in
+    ``[0, span)``): ``(found, index)``, the index meaningful where found.
+    A table of ``span`` slots where it is short, else a binary search."""
+
+    def __init__(self, ukeys: np.ndarray, span: int):
+        self.ukeys, self.span = ukeys, span
+        self.table = None
+        if _dense_span(ukeys, span, ukeys.size) and ukeys.size < (1 << 31):
+            self.table = np.full(span, -1, dtype=np.int32)
+            self.table[ukeys] = np.arange(ukeys.size, dtype=np.int32)
+
+    def __call__(self, q: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        if self.table is not None and (
+            q.size == 0 or (int(q.min()) >= 0 and int(q.max()) < self.span)
+        ):
+            at = self.table[q].astype(np.int64)
+            return at >= 0, at
+        ukeys = self.ukeys
+        ii = np.searchsorted(ukeys, q)
+        ii_c = np.minimum(ii, max(len(ukeys) - 1, 0))
+        found = (
+            (ii < len(ukeys)) & (ukeys[ii_c] == q)
+            if len(ukeys)
+            else np.zeros(len(q), dtype=bool)
+        )
+        return found, ii_c
+
+
+class _BucketGroups:
+    """Positions grouped by their bucket (``keys``: small non-negative ints
+    below ``nb``), each group in its positions' order: one stable sort in
+    place of a mask a bucket."""
+
+    def __init__(self, keys: np.ndarray, nb: int):
+        small = keys.astype(np.int16 if nb < (1 << 15) else np.int64)
+        self.order = np.argsort(small, kind="stable")
+        self.bounds = np.searchsorted(small[self.order], np.arange(nb + 1))
+
+    def of(self, b: int) -> np.ndarray:
+        return self.order[self.bounds[b]:self.bounds[b + 1]]
 
 
 def _local_dense(
@@ -440,7 +508,10 @@ def build_random_effect_dataset(
     global feature space. Rows with entities are ALL consumed: up to the active
     cap into solver blocks, the remainder into passive (score-only) rows.
     The host work is the span ``re/build_dataset`` (``entities``,
-    ``buckets``); the buckets' upload after it is a ``data/upload``.
+    ``buckets``; while the tracer is on, ``real_cells`` and
+    ``padded_cells`` of the ``[E, S, D]`` blocks: an active row's real local
+    columns, against every cell the blocks hold); the buckets' upload after
+    it is a ``data/upload``.
     """
     with span("re/build_dataset") as building:
         host = _pack_random_effect_dataset(
@@ -448,6 +519,14 @@ def build_random_effect_dataset(
             labels, config, offsets, weights,
         )
         building.set_attrs(entities=host.num_entities, buckets=len(host.buckets))
+        if get_tracer().enabled:
+            building.set_attrs(
+                real_cells=sum(
+                    int(((b.weights > 0).sum(1) * b.proj_valid.sum(1)).sum())
+                    for b in host.buckets
+                ),
+                padded_cells=sum(int(b.X.size) for b in host.buckets),
+            )
     buckets, passive, row_gather = upload("re_bucket", lambda: jax.tree.map(
         jnp.asarray, (host.buckets, host.passive, host.row_gather)
     ))
@@ -476,14 +555,23 @@ def _pack_random_effect_dataset(
     n_ent = len(uniq)
     counts = np.bincount(codes, minlength=n_ent)
 
-    # CSR-ify the COO features once (row-sorted)
+    # CSR-ify the COO features once (row-sorted; row-major input, as a
+    # dense shard's is, is taken as it stands)
     feature_rows = np.asarray(feature_rows, dtype=np.int64)
     feature_cols = np.asarray(feature_cols, dtype=np.int64)
     feature_vals = np.asarray(feature_vals, dtype=np.float32)
-    forder = lexsort_pairs(feature_rows)
-    fr, fc, fv = feature_rows[forder], feature_cols[forder], feature_vals[forder]
-    row_start = np.searchsorted(fr, np.arange(n))
-    row_end = np.searchsorted(fr, np.arange(n) + 1)
+    if np.all(feature_rows[1:] >= feature_rows[:-1]):
+        fr, fc, fv = feature_rows, feature_cols, feature_vals
+    else:
+        forder = lexsort_pairs(feature_rows)
+        fr, fc, fv = feature_rows[forder], feature_cols[forder], feature_vals[forder]
+    if fr.size == 0 or (fr[0] >= 0 and fr[-1] < n):
+        per_row = np.bincount(fr, minlength=n)
+        row_end = np.cumsum(per_row)
+        row_start = row_end - per_row
+    else:
+        row_start = np.searchsorted(fr, np.arange(n))
+        row_end = np.searchsorted(fr, np.arange(n) + 1)
 
     # ---- active/passive split, all entities at once -----------------------
     # Group rows by entity (random order within an entity when capping) and
@@ -546,7 +634,8 @@ def _pack_random_effect_dataset(
         # observed (entity, col) pairs from ACTIVE data only (reference
         # IndexMapProjectorRDD.scala:164); np.unique returns them sorted by
         # entity then column — exactly the flat local-col layout.
-        ukeys = np.unique(nz_e * G1 + nz_c)
+        nz_keys = nz_e * G1 + nz_c
+        ukeys = _unique_keys(nz_keys, n_ent * G1)
         ecol = ukeys // G1
         ucol = ukeys % G1
         dlocs = np.bincount(ecol, minlength=n_ent)
@@ -565,7 +654,7 @@ def _pack_random_effect_dataset(
                 ukeys,
                 ecol,
                 n_ent,
-                nz_keys=nz_e * G1 + nz_c,
+                nz_keys=nz_keys,
                 nz_v=nz_v,
                 y_nz=labels[act][rep_a],
                 w_nz=weights[act][rep_a],
@@ -605,15 +694,9 @@ def _pack_random_effect_dataset(
 
     # Resolve every active nonzero's local column once (INDEX_MAP only).
     if rproj is None and not identity:
-        qk = nz_e * G1 + nz_c
-        ii = np.searchsorted(ukeys, qk)
-        ii_c = np.minimum(ii, max(len(ukeys) - 1, 0))
-        nz_match = (
-            (ii < len(ukeys)) & (ukeys[ii_c] == qk)
-            if len(ukeys)
-            else np.zeros(len(qk), dtype=bool)
-        )
-        nz_j = ii_c - dstart[nz_e]  # local column per active nonzero
+        find = _KeyIndex(ukeys, n_ent * G1)
+        nz_match, nz_at = find(nz_keys)
+        nz_j = nz_at - dstart[nz_e]  # local column per active nonzero
     elif identity:
         nz_match = np.ones(len(nz_c), dtype=bool)
         nz_j = nz_c
@@ -630,40 +713,72 @@ def _pack_random_effect_dataset(
     host_actives: List[Tuple[np.ndarray, np.ndarray]] = []
     host_passive_pos: List[Optional[np.ndarray]] = []
 
+    # Cost-sorted dispatch: entity rows within a bucket are ordered by
+    # DESCENDING active sample count (stable), so lockstep lanes carry
+    # similar per-iteration work and the adaptive driver's live tiles keep
+    # heavy (slow-converging) entities co-scheduled. Every bucket's blocks
+    # of a kind are views of one buffer, filled by one scatter over all the
+    # rows (or nonzeros, or local columns) at once; passive rows are
+    # grouped by bucket once, stably, so a bucket's keep their order.
+    ent_order = np.lexsort((-acounts, bucket_of))
+    ent_bounds = np.searchsorted(bucket_of[ent_order], np.arange(nb + 1))
+    new_e = np.empty(n_ent, dtype=np.int64)  # entity code -> row within bucket
+    new_e[ent_order] = np.arange(n_ent, dtype=np.int64) - ent_bounds[bucket_of[ent_order]]
+    n_b = np.diff(ent_bounds)
+    s_b = np.ones(nb, dtype=np.int64)
+    d_b = np.ones(nb, dtype=np.int64)
+    for b in np.flatnonzero(n_b):
+        ents = ent_order[ent_bounds[b]:ent_bounds[b + 1]]
+        s_b[b] = acounts[ents].max()
+        d_b[b] = rproj.projected_dim if rproj else max(int(np.maximum(dlocs[ents], 1).max()), 1)
+    cells_at = np.concatenate([[0], np.cumsum(n_b * s_b)])   # [E, S] blocks
+    cols_at = np.concatenate([[0], np.cumsum(n_b * d_b)])    # [E, D] blocks
+    x_at = np.concatenate([[0], np.cumsum(n_b * s_b * d_b)])  # [E, S, D] blocks
+
+    bk = bucket_of[e_act_g]
+    cell = cells_at[bk] + new_e[e_act_g] * s_b[bk] + s_act_g  # active row -> its cell
+    lab_all = np.zeros(cells_at[-1], dtype=np.float32)
+    off_all = np.zeros(cells_at[-1], dtype=np.float32)
+    wt_all = np.zeros(cells_at[-1], dtype=np.float32)
+    pos_all = np.zeros(cells_at[-1], dtype=np.int32)
+    lab_all[cell] = labels[act]
+    off_all[cell] = offsets[act]
+    wt_all[cell] = weights[act]
+    pos_all[cell] = act
+
+    pidx_all = np.zeros(cols_at[-1], dtype=np.int32)
+    pval_all = np.zeros(cols_at[-1], dtype=bool)
+    if rproj is None and not identity:
+        kb = bucket_of[ecol]
+        slot = cols_at[kb] + new_e[ecol] * d_b[kb] + (np.arange(len(ukeys)) - dstart[ecol])
+        pidx_all[slot] = ucol
+        pval_all[slot] = True
+
+    x_all = np.zeros(x_at[-1], dtype=np.float32)
+    if rproj is None:
+        # an active row's first cell of X, then its nonzeros' columns
+        row_x = x_at[bk] + (cell - cells_at[bk]) * d_b[bk]
+        if nz_match.all():
+            x_all[row_x[rep_a] + nz_j] = nz_v
+        else:
+            x_all[(row_x[rep_a] + nz_j)[nz_match]] = nz_v[nz_match]
+    else:
+        row_groups = _BucketGroups(bk, nb)
+    pas_groups = _BucketGroups(bucket_of[e_pas_g], nb)
+
     for b in range(nb):
-        ent_m = bucket_of == b
-        E = int(ent_m.sum())
+        E = int(n_b[b])
         if E == 0:
             continue
         bi = len(buckets)
-        # Cost-sorted dispatch: entity rows within the bucket are ordered by
-        # DESCENDING active sample count (stable), so lockstep lanes carry
-        # similar per-iteration work and the adaptive driver's compacted
-        # prefixes keep heavy (slow-converging) entities co-scheduled.
-        codes_b = np.nonzero(ent_m)[0]
-        order_b = np.argsort(-acounts[codes_b], kind="stable")
-        new_e = np.zeros(n_ent, dtype=np.int64)  # entity code -> row within bucket
-        new_e[codes_b[order_b]] = np.arange(E, dtype=np.int64)
-        S = int(acounts[ent_m].max())
-        D = int(
-            rproj.projected_dim
-            if rproj
-            else max(int(np.maximum(dlocs[ent_m], 1).max()), 1)
-        )
-
-        lab = np.zeros((E, S), dtype=np.float32)
-        off = np.zeros((E, S), dtype=np.float32)
-        wt = np.zeros((E, S), dtype=np.float32)
-        pos = np.zeros((E, S), dtype=np.int32)
-        rm = ent_m[e_act_g]
-        er, sr = new_e[e_act_g[rm]], s_act_g[rm]
-        lab[er, sr] = labels[act[rm]]
-        off[er, sr] = offsets[act[rm]]
-        wt[er, sr] = weights[act[rm]]
-        pos[er, sr] = act[rm]
-
-        pidx = np.zeros((E, D), dtype=np.int32)
-        pval = np.zeros((E, D), dtype=bool)
+        ents = ent_order[ent_bounds[b]:ent_bounds[b + 1]]
+        S, D = int(s_b[b]), int(d_b[b])
+        lab = lab_all[cells_at[b]:cells_at[b + 1]].reshape(E, S)
+        off = off_all[cells_at[b]:cells_at[b + 1]].reshape(E, S)
+        wt = wt_all[cells_at[b]:cells_at[b + 1]].reshape(E, S)
+        pos = pos_all[cells_at[b]:cells_at[b + 1]].reshape(E, S)
+        pidx = pidx_all[cols_at[b]:cols_at[b + 1]].reshape(E, D)
+        pval = pval_all[cols_at[b]:cols_at[b + 1]].reshape(E, D)
         if rproj is not None:
             # projected-space coordinates are all live; back-projection goes
             # through the shared matrix, not pidx
@@ -671,20 +786,12 @@ def _pack_random_effect_dataset(
         elif identity:
             pidx[:, :] = np.arange(global_dim, dtype=np.int32)[None, :]
             pval[:, :] = True
-        else:
-            km = ent_m[ecol]
-            jj = np.arange(len(ukeys), dtype=np.int64) - dstart[ecol]
-            pidx[new_e[ecol[km]], jj[km]] = ucol[km]
-            pval[new_e[ecol[km]], jj[km]] = True
-
-        X = np.zeros((E, S, D), dtype=np.float32)
+        X = x_all[x_at[b]:x_at[b + 1]].reshape(E, S, D)
         if rproj is not None:
-            X[er, sr] = _project_rows(act[rm])
-        else:
-            zm = ent_m[nz_e] & nz_match
-            X[new_e[nz_e[zm]], s_act_g[rep_a[zm]], nz_j[zm]] = nz_v[zm]
+            r = row_groups.of(b)
+            X[new_e[e_act_g[r]], s_act_g[r]] = _project_rows(act[r])
 
-        pm = ent_m[e_pas_g]
+        pm = pas_groups.of(b)
         pas_b = pas[pm]
         n_pas = len(pas_b)
         pX = np.zeros((n_pas, D), dtype=np.float32)
@@ -698,21 +805,12 @@ def _pack_random_effect_dataset(
                 if identity:
                     pX[rep_p, pc] = pv_
                 else:
-                    qk = pe * G1 + pc
-                    ii = np.searchsorted(ukeys, qk)
-                    ii_c = np.minimum(ii, max(len(ukeys) - 1, 0))
-                    match = (
-                        (ii < len(ukeys)) & (ukeys[ii_c] == qk)
-                        if len(ukeys)
-                        else np.zeros(len(qk), dtype=bool)
-                    )
-                    jcol = ii_c - dstart[pe]
+                    match, at = find(pe * G1 + pc)
+                    jcol = at - dstart[pe]
                     pX[rep_p[match], jcol[match]] = pv_[match]
 
-        ids_b = uniq[codes_b[order_b]].tolist()
-        entity_to_loc.update(
-            (eid, (bi, e)) for e, eid in enumerate(ids_b)
-        )
+        ids_b = uniq[ents].tolist()
+        entity_to_loc.update(zip(ids_b, zip(itertools.repeat(bi, E), range(E))))
 
         buckets.append(
             ReBucket(

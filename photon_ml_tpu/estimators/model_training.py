@@ -30,7 +30,7 @@ from photon_ml_tpu.losses.pointwise import loss_for_task
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.models.glm import GeneralizedLinearModel
 from photon_ml_tpu.opt.config import GlmOptimizationConfiguration, OptimizerConfig
-from photon_ml_tpu.opt.lbfgs import HISTORY_LAYOUT
+from photon_ml_tpu.opt.lbfgs import history_layout
 from photon_ml_tpu.opt.solve import solve, solver_kind
 from photon_ml_tpu.opt.state import SolveResult
 from photon_ml_tpu.ops.data import LabeledData
@@ -195,7 +195,7 @@ def train_glm(
         solver = _solve_program(objective, configuration.optimizer_config, use_l1)
         # what a traced run's glm/solve spans say of the curvature history
         kind = solver_kind(configuration, 1.0 if use_l1 else 0.0)
-        layout = "none" if kind == "tron" else HISTORY_LAYOUT
+        layout = "none" if kind == "tron" else history_layout(w.shape[-1])
         on_mesh = mesh_attrs(getattr(data.features, "mesh", None))
         # the objective's functions are the same objects at every call, so
         # this wrapper finds the program an earlier call's wrapper compiled

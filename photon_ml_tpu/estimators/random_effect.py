@@ -15,13 +15,16 @@ When ``configuration.adaptive.enabled`` the per-bucket solve instead runs in
 chunks of K outer iterations (full solver state — L-BFGS memory, OWL-QN
 orthant state, TRON trust radius — carried across chunks, so the per-lane
 trajectory is IDENTICAL to one-shot), pulls the converged mask after each
-chunk, and dispatches the next chunk on the live lanes only. State and data
-stay at the bucket's full width in entity order: a round hands the ONE chunk
-program of the bucket shape an index vector (live lanes first) and a tile
-count, and the program loops over that many tiles of ``T`` lanes (gather,
-vmapped chunk, scatter back). The number of live lanes is an operand, never a
-shape, so a bucket shape compiles one init, one chunk and one extract program
-for the life of the process (``solver_trace_counts``).
+chunk, and dispatches the next chunk on the live tiles only. State and data
+stay at the bucket's full width in entity order, cut into tiles of ``T``
+consecutive lanes: a round hands the ONE chunk program of the bucket shape
+the indices of the tiles that hold a live lane and their count, and the
+program loops over that many tiles (slice, vmapped chunk, write back). A
+tile is a slice and never a gather: on the chip a narrow state's entity axis
+lies minor, in lanes, and a gather along it would lay the whole state out
+again, padded (docs/SCALING.md, "Adaptive random-effect solving"). The number of live tiles is an
+operand, never a shape, so a bucket shape compiles one init, one chunk and
+one extract program for the life of the process (``solver_trace_counts``).
 """
 
 from __future__ import annotations
@@ -54,6 +57,7 @@ from photon_ml_tpu.opt.solve import (
 from photon_ml_tpu.opt.state import SolveResult
 from photon_ml_tpu.opt.tracking import SolverStats
 from photon_ml_tpu.telemetry import note_jit_trace, span
+from photon_ml_tpu.telemetry.span import current_span
 from photon_ml_tpu.types import ConvergenceReason, TaskType
 
 _NOT_CONVERGED = ConvergenceReason.NOT_CONVERGED.value
@@ -92,6 +96,22 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (int(n) - 1).bit_length())
 
 
+def _compiled_init(progs: "_RePrograms", w0, data, l2, l1) -> Tuple[Callable, int]:
+    """(the init program for these arguments' shapes, the bytes of the
+    solver state it lays out on the device): compiled ahead of its first
+    call and kept with the programs, the bytes read from the compiled
+    program's memory analysis, once a bucket shape. A traced
+    ``re/solve_bucket`` span reports them as its ``state_bytes``."""
+    key = tuple(
+        (a.shape, a.dtype, getattr(a, "sharding", None))
+        for a in jax.tree.leaves((w0, data, l2, l1))
+    )
+    if key not in progs.inits:
+        compiled = progs.init.lower(w0, data, l2, l1).compile()
+        progs.inits[key] = compiled, int(compiled.memory_analysis().output_size_in_bytes)
+    return progs.inits[key]
+
+
 # A bucket is worked on in tiles of a sixteenth of its (power-of-two) width.
 _TILES_PER_BUCKET = 16
 
@@ -100,10 +120,11 @@ def _tile_lanes(num_entities: int, min_lanes: int) -> int:
     """Lanes a tile of the adaptive chunk program holds: a function of the
     bucket's shape alone, so the host's bookkeeping and the traced program
     agree on it. ``min_lanes`` is its floor (a tile under that is launch
-    overhead); a bucket of one tile is a lockstep dispatch."""
-    return max(
+    overhead), the bucket's width its ceiling; a bucket of one tile is a
+    lockstep dispatch."""
+    return min(num_entities, max(
         _next_pow2(min_lanes), _next_pow2(num_entities) // _TILES_PER_BUCKET
-    )
+    ))
 
 
 def _is_multi_device(x) -> bool:
@@ -134,9 +155,10 @@ class _RePrograms(NamedTuple):
     min_lanes: int
     oneshot: Callable    # (w0, data, pv, l2, l1) -> (SolveResult, w_masked, var|None)
     init: Callable       # (w0, data, l2, l1) -> batched solver state
-    chunk: Callable      # (state, data, l2, live_idx, n_tiles) -> state with the
-                         # lanes of the first n_tiles tiles advanced by <= K iters
+    chunk: Callable      # (state, data, l2, live_tiles, n_tiles) -> state with the
+                         # lanes of tiles live_tiles[:n_tiles] advanced by <= K iters
     extract: Callable    # (state, data, pv, l2) -> (SolveResult, w_masked, var|None)
+    inits: Dict[tuple, Tuple[Callable, int]]  # _compiled_init's, by bucket shape
 
 
 @functools.lru_cache(maxsize=None)
@@ -195,15 +217,33 @@ def _re_programs(
         _note_trace("re_init", kind)
         return jax.vmap(init_one, in_axes=(0, 0, None, None))(w0, data, l2, l1)
 
-    def _chunk(state, data, l2, live_idx, n_tiles):
+    def _chunk(state, data, l2, live_tiles, n_tiles):
         _note_trace("re_chunk", kind)
-        T = _tile_lanes(state.it.shape[0], min_lanes)
+        E = state.it.shape[0]
+        T = _tile_lanes(E, min_lanes)
+        lane = jnp.arange(T)
 
         def tile(t, state):
-            idx = jax.lax.dynamic_slice_in_dim(live_idx, t * T, T)
-            lanes, lane_data = jax.tree.map(lambda a: a[idx], (state, data))
-            lanes = jax.vmap(chunk_one, in_axes=(0, 0, None))(lanes, lane_data, l2)
-            return jax.tree.map(lambda a, new: a.at[idx].set(new), state, lanes)
+            # tile k holds lanes [k T, (k + 1) T); the last one, where T does
+            # not divide E, is the slice that ends at E, and its lanes of the
+            # tile before are written back as they were
+            k = live_tiles[t]
+            start = jnp.minimum(k * T, E - T)
+            own = lane + start >= k * T
+
+            def cut(a):
+                return jax.lax.dynamic_slice_in_dim(a, start, T)
+
+            lanes, lane_data = jax.tree.map(cut, (state, data))
+            new = jax.vmap(chunk_one, in_axes=(0, 0, None))(lanes, lane_data, l2)
+
+            def put(a, new, old):
+                keep = own.reshape((T,) + (1,) * (new.ndim - 1))
+                return jax.lax.dynamic_update_slice_in_dim(
+                    a, jnp.where(keep, new, old), start, 0
+                )
+
+            return jax.tree.map(put, state, new, lanes)
 
         return jax.lax.fori_loop(0, n_tiles, tile, state)
 
@@ -221,6 +261,7 @@ def _re_programs(
         init=jax.jit(_init),
         chunk=jax.jit(_chunk, donate_argnums=(0,)),
         extract=jax.jit(_extract),
+        inits={},
     )
 
 
@@ -242,12 +283,14 @@ def _solve_bucket_adaptive(
     data = _bucket_data(bucket)
     retrace0 = _TRACE_COUNTS[("re_chunk", progs.kind)]
 
-    state = progs.init(w0, data, l2, l1)
-    # live lanes first, each group in entity order; round 0 takes every lane
-    # (a lane converged at init never advances, and no mask is pulled for it)
-    order = np.arange(E, dtype=np.int32)
-    n_tiles = -(-E // T)
-    pad = n_tiles * T - E
+    init, state_bytes = _compiled_init(progs, w0, data, l2, l1)
+    current_span().set_attrs(state_bytes=state_bytes)
+    state = init(w0, data, l2, l1)
+    # round 0 takes every tile (a lane converged at init never advances, and
+    # no mask is pulled for it); later rounds the tiles that hold a live lane
+    n_all = -(-E // T)
+    live_tiles = np.arange(n_all, dtype=np.int32)
+    n_tiles = n_all
     its = np.zeros(E, dtype=np.int64)
     executed = 0
     widths: List[int] = []
@@ -255,11 +298,13 @@ def _solve_bucket_adaptive(
     # converged-at-init case where the first chunk advances nothing.
     max_rounds = -(-max_iterations // K) + 1
 
+    def per_tile(lanes: np.ndarray, fill) -> np.ndarray:
+        """``lanes`` [E] as [n_all, T], each row a tile's own lanes."""
+        return np.concatenate(
+            [lanes, np.full(n_all * T - E, fill, lanes.dtype)]
+        ).reshape(n_all, T)
+
     for round_index in range(max_rounds):
-        # the slots past E repeat the last lane in the order: a done lane
-        # once any is done, else lane E-1, which shares their tile; either
-        # way every copy computes what its original does
-        live_idx = np.concatenate([order, np.full(pad, order[-1], np.int32)])
         with span(
             "re/adaptive_round",
             bucket=bucket_index,
@@ -267,7 +312,7 @@ def _solve_bucket_adaptive(
             width=n_tiles * T,
             tiles=n_tiles,
         ):
-            state = progs.chunk(state, data, l2, live_idx, np.int32(n_tiles))
+            state = progs.chunk(state, data, l2, live_tiles, np.int32(n_tiles))
             widths.append(n_tiles * T)
             its_before = its
             # the host's wait for the device, once a round: the two pulls
@@ -276,14 +321,16 @@ def _solve_bucket_adaptive(
                 its = np.asarray(jax.device_get(state.it)).astype(np.int64)
                 reasons = np.asarray(jax.device_get(state.reason))
         # a tile's while_loop runs until its slowest lane stops
-        advance = (its - its_before)[live_idx[: n_tiles * T]]
-        executed += T * int(advance.reshape(n_tiles, T).max(axis=1).sum())
+        advance = per_tile(its - its_before, 0).max(axis=1)
+        executed += T * int(advance[live_tiles[:n_tiles]].sum())
         done = (reasons != _NOT_CONVERGED) | (its >= max_iterations)
-        n_live = E - int(np.sum(done))
-        if n_live == 0:
+        live = np.flatnonzero(~per_tile(done, True).all(axis=1))
+        if live.size == 0:
             break
-        order = np.argsort(done, kind="stable").astype(np.int32)
-        n_tiles = -(-n_live // T)
+        # a new operand each round: the last one may still be in flight
+        n_tiles = int(live.size)
+        live_tiles = np.zeros(n_all, dtype=np.int32)
+        live_tiles[:n_tiles] = live
 
     res, w, var = progs.extract(state, data, bucket.proj_valid, l2)
     reasons = np.asarray(jax.device_get(res.reason))

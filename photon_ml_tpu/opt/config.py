@@ -117,10 +117,10 @@ class AdaptiveSolveConfig:
 
     The driver runs the vmap'd per-entity solve in chunks of ``chunk_iters``
     outer iterations, pulls the per-lane converged mask after each chunk,
-    and hands the next chunk the live lanes as an index vector and a count
-    of tiles: the one chunk program of a bucket shape loops over that many
-    tiles of the bucket's lanes. A bucket shape therefore compiles one
-    chunk program, whatever its rounds' live counts.
+    and hands the next chunk the tiles that hold a live lane as an index
+    vector and a count: the one chunk program of a bucket shape loops over
+    that many tiles, each a slice of consecutive lanes. A bucket shape
+    therefore compiles one chunk program, whatever its rounds' live counts.
     ``enabled=False`` restores the one-shot lockstep dispatch exactly.
     """
 
@@ -149,8 +149,8 @@ class GlmOptimizationConfiguration:
     regularization: RegularizationContext = RegularizationContext()
     regularization_weight: float = 0.0
     down_sampling_rate: float = 1.0
-    # Convergence-adaptive random-effect solving (chunked rounds over
-    # tiles of the live lanes); only consulted by train_random_effects.
+    # Convergence-adaptive random-effect solving (chunked rounds over the
+    # tiles that hold a live lane); only consulted by train_random_effects.
     adaptive: AdaptiveSolveConfig = AdaptiveSolveConfig()
 
     def __post_init__(self) -> None:

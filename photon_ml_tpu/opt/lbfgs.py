@@ -32,26 +32,38 @@ from photon_ml_tpu.opt.state import (
 from photon_ml_tpu.types import ConvergenceReason
 
 
-# What a ``glm/solve`` span of an L-BFGS or OWL-QN solve reports as its
-# ``history_layout`` (TRON keeps no history and reports "none").
-HISTORY_LAYOUT = "tiled"
 _LANES = 128
 
 
-def history_zeros(m: int, dim: int, dtype) -> jax.Array:
-    """An empty s/y ring buffer for a solve of static width ``dim``:
-    ``[m, ceil(dim/128), 128]``, rows zero-padded to a multiple of 128 (zeros
-    add nothing to a dot product).
+def history_layout(dim: int) -> str:
+    """How ``history_zeros`` lays out the s/y buffers of a solve of width
+    ``dim``: ``rows`` (``[m, d]``) under one row of lanes, ``tiled``
+    (``[m, ceil(d/128), 128]``) from there up. What a ``glm/solve`` span of
+    an L-BFGS or OWL-QN solve reports as its ``history_layout`` (TRON keeps
+    no history and reports "none")."""
+    return "rows" if dim < _LANES else "tiled"
 
-    As ``[m, d]`` float32 the TPU tiled the buffer (8, 128): eight rows
-    interleaved in one tile and ten rows padded to sixteen, so reading one
-    row moved eight and the buffer took 1.6x its data. With a row to itself a
-    row is contiguous, nothing pads, and its flattening to a vector is a
-    bitcast. The vmapped per-entity solves (16 or 32 coefficients) get the
-    same layout: on the chip they run faster on it than on ``[m, d]``, at
-    eight times the bytes of a 16-wide history (PERF.md section 6, PR 35).
-    The shape is known here and to the three helpers below, through which
-    ``two_loop_direction`` and ``update_history`` go."""
+
+def history_zeros(m: int, dim: int, dtype) -> jax.Array:
+    """An empty s/y ring buffer for a solve of static width ``dim``; the
+    shape alone decides the layout (``history_layout``).
+
+    From 128 coefficients up, ``[m, ceil(dim/128), 128]``, rows zero-padded
+    to a multiple of 128 (zeros add nothing to a dot product). As ``[m, d]``
+    float32 the TPU tiled a wide buffer (8, 128): eight rows interleaved in
+    one tile and ten rows padded to sixteen, so reading one row moved eight
+    and the buffer took 1.6x its data. With a row to itself a row is
+    contiguous, nothing pads, and its flattening to a vector is a bitcast.
+
+    Under 128 coefficients (the vmapped per-entity solves), ``[m, d]``:
+    vmapped to ``[E, m, d]`` the chip lays the entity axis minor, in lanes,
+    so nothing pads (640 bytes an entity at ``m = 10``, ``d = 16``), where
+    ``[E, m, 1, 128]`` padded every row to 128 lanes (5,120 bytes, eight
+    times the data; ``tests/test_tpu_compile.py``). The shape is known here
+    and to the three helpers below, through which ``two_loop_direction``
+    and ``update_history`` go."""
+    if history_layout(dim) == "rows":
+        return jnp.zeros((m, dim), dtype=dtype)
     return jnp.zeros((m, -(-dim // _LANES), _LANES), dtype=dtype)
 
 
@@ -80,7 +92,8 @@ class _LbfgsState(NamedTuple):
     continues exactly where the previous one stopped).
 
     ``s_hist`` / ``y_hist`` are what ``history_zeros`` makes for ``d``:
-    ``[m, ceil(d/128), 128]``, so that a row is contiguous in HBM."""
+    ``[m, d]`` under 128 coefficients, else ``[m, ceil(d/128), 128]``, so
+    that a row is contiguous in HBM."""
 
     w: jax.Array          # [d]
     f: jax.Array
@@ -101,13 +114,21 @@ class _LbfgsState(NamedTuple):
 def two_loop_direction(
     g: jax.Array, s_hist: jax.Array, y_hist: jax.Array, rho: jax.Array, count: jax.Array
 ) -> jax.Array:
-    """Two-loop recursion over a masked ring buffer.
+    """Two-loop recursion over a masked history of the last ``m`` pairs.
 
-    History slots are ordered oldest→newest modulo m; slot i is valid iff
-    i < count. Invalid slots have rho=0 so their updates are algebraic no-ops
-    (alpha = rho*(s.q) = 0), which keeps the loop branch-free.
+    A ``tiled`` history is a ring: slots are ordered oldest→newest modulo m.
+    A ``rows`` history (``history_layout``) keeps its newest pair in row 0
+    and the older ones below it, so the recursion reads row ``i`` at its
+    ``i``-th step whatever ``count`` is: vmapped over entities whose counts
+    differ, a read is a slice where a ring's per-lane slot was a gather,
+    which the chip answered by laying every lane's buffer out again, padded
+    to 128 lanes. Either way slot i is valid iff it holds one of the last
+    ``count`` pairs; invalid slots have rho=0 so their updates are algebraic
+    no-ops (alpha = rho*(s.q) = 0), which keeps the loop branch-free, and
+    the two orders do the same arithmetic on the same pairs.
     """
     m = rho.shape[0]
+    newest_first = s_hist.ndim == 2
 
     # History may be stored bf16 (config.history_dtype); rows are cast to
     # the working dtype on read so every dot/axpy accumulates full precision.
@@ -127,7 +148,7 @@ def two_loop_direction(
 
     def bwd(i, carry):
         q, alphas = carry
-        idx = jnp.mod(count - 1 - i, m)  # newest first
+        idx = i if newest_first else jnp.mod(count - 1 - i, m)  # newest first
         valid = i < count
         r = jnp.where(valid, rho[idx], 0.0)
         a = r * jnp.dot(s_row(idx), q)
@@ -135,10 +156,16 @@ def two_loop_direction(
         alphas = alphas.at[idx].set(a)
         return q, alphas
 
-    q, alphas = jax.lax.fori_loop(0, m, bwd, (g, jnp.zeros_like(rho)))
+    # A history of a row of lanes or less (the vmapped per-entity solves)
+    # has its recursion written out: each of its 2m steps is then a fused
+    # operation or two, where a loop step was about ten launches, every
+    # iteration of every tile. A wide one (the fixed effect's) keeps the
+    # loop, whose steps stream their rows.
+    unroll = _row_width(s_hist) <= _LANES
+    q, alphas = jax.lax.fori_loop(0, m, bwd, (g, jnp.zeros_like(rho)), unroll=unroll)
 
     # initial Hessian scaling gamma = (s.y)/(y.y) of the newest valid pair
-    newest = jnp.mod(count - 1, m)
+    newest = 0 if newest_first else jnp.mod(count - 1, m)
     have = count > 0
     s_new = s_row(newest)
     y_new = y_row(newest)
@@ -148,13 +175,14 @@ def two_loop_direction(
     r_vec = gamma * q
 
     def fwd(i, r_vec):
-        idx = jnp.mod(count - m + i, m)  # oldest first among the last m
+        # oldest first among the last m
+        idx = m - 1 - i if newest_first else jnp.mod(count - m + i, m)
         valid = i >= (m - jnp.minimum(count, m))
         r = jnp.where(valid, rho[idx], 0.0)
         beta = r * jnp.dot(y_row(idx), r_vec)
         return r_vec + jnp.where(valid, (alphas[idx] - beta), 0.0) * s_row(idx)
 
-    r_vec = jax.lax.fori_loop(0, m, fwd, r_vec)
+    r_vec = jax.lax.fori_loop(0, m, fwd, r_vec, unroll=unroll)
     return -r_vec[:dim]
 
 
@@ -167,25 +195,36 @@ def resolve_history_dtype(config: OptimizerConfig, working_dtype) -> jnp.dtype:
 def update_history(
     s_hist, y_hist, rho, count, s_vec, y_vec
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Curvature-guarded ring-buffer insert (skip when s.y too small),
-    casting the pair to the buffers' storage dtype — shared by L-BFGS and
-    OWL-QN so their history handling cannot diverge. The guard selects on
-    the row, not on the buffer: a rejected pair rewrites one row with
-    itself, an accepted one is an in-place dynamic-update-slice."""
+    """Curvature-guarded insert (skip when s.y too small), casting the pair
+    to the buffers' storage dtype — shared by L-BFGS and OWL-QN so their
+    history handling cannot diverge. Into a ``tiled`` history's ring the
+    guard selects on the row, not on the buffer: a rejected pair rewrites
+    one row with itself, an accepted one is an in-place
+    dynamic-update-slice. A ``rows`` history (newest first, see
+    ``two_loop_direction``) shifts down by a row and takes the pair as row
+    0: ``m`` narrow rows moved, and no slot that differs from lane to lane."""
     m = rho.shape[0]
     sy = jnp.dot(s_vec, y_vec)
     good_pair = sy > 1e-10 * jnp.maximum(jnp.dot(y_vec, y_vec), 1e-30)
-    slot = jnp.mod(count, m)
+    inv_sy = 1.0 / jnp.maximum(sy, 1e-30)
 
-    def put(hist, vec):
-        row = jnp.where(good_pair, _as_history_row(vec, hist), hist[slot])
-        return hist.at[slot].set(row)
+    if s_hist.ndim == 2:
+        def shift(hist, row):
+            return jnp.where(good_pair, jnp.concatenate([row[None], hist[:-1]]), hist)
 
-    s_hist = put(s_hist, s_vec)
-    y_hist = put(y_hist, y_vec)
-    rho = jnp.where(
-        good_pair, rho.at[slot].set(1.0 / jnp.maximum(sy, 1e-30)), rho
-    )
+        s_hist = shift(s_hist, _as_history_row(s_vec, s_hist))
+        y_hist = shift(y_hist, _as_history_row(y_vec, y_hist))
+        rho = shift(rho, inv_sy.astype(rho.dtype))
+    else:
+        slot = jnp.mod(count, m)
+
+        def put(hist, vec):
+            row = jnp.where(good_pair, _as_history_row(vec, hist), hist[slot])
+            return hist.at[slot].set(row)
+
+        s_hist = put(s_hist, s_vec)
+        y_hist = put(y_hist, y_vec)
+        rho = jnp.where(good_pair, rho.at[slot].set(inv_sy), rho)
     count = jnp.where(good_pair, count + 1, count)
     return s_hist, y_hist, rho, count
 

@@ -358,6 +358,14 @@ def span(name: str, device_sync: bool = False, **attrs):
     return _LiveSpan(t, name, device_sync and t.device_sync, attrs)
 
 
+def current_span():
+    """The innermost span open in this thread or task, for attrs that are
+    known only deep inside its block; the no-op span where none is open or
+    the tracer is off."""
+    live = _CURRENT.get()
+    return live if live is not None and _TRACER.enabled else NOOP_SPAN
+
+
 def upload(what: str, put):
     """``put()``, which hands host arrays to a device and returns what it
     made there, inside a span ``data/upload`` (``what``: ``features``,

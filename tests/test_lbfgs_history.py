@@ -1,6 +1,7 @@
 """The L-BFGS / OWL-QN curvature history as ``opt/lbfgs.py`` lays it out
-(``[m, ceil(d/128), 128]``, a row padded to a multiple of 128) against a
-float64 NumPy two-loop written here, which shares nothing with ``opt/``.
+(``[m, d]`` under 128 coefficients, else ``[m, ceil(d/128), 128]``, a row
+padded to a multiple of 128) against a float64 NumPy two-loop written here,
+which shares nothing with ``opt/``.
 
 Not in ``test_optimizers.py`` because that module is marked ``slow`` as a
 whole and the tier-1 run deselects it; these cases are small and count there.
@@ -21,7 +22,7 @@ from photon_ml_tpu.opt.lbfgs import (
 
 M = 6
 # history width -> the shape of one row
-ROW_SHAPES = {16: (1, 128), 1024: (8, 128), 1152: (9, 128), 1300: (11, 128)}
+ROW_SHAPES = {16: (16,), 1024: (8, 128), 1152: (9, 128), 1300: (11, 128)}
 # pairs inserted before the direction is taken
 COUNTS = {"empty": 0, "one": 1, "m-1": M - 1, "m": M, "wrapped": M + 7}
 
@@ -142,3 +143,30 @@ def test_history_matches_float64_two_loop(rng, case, d, hdtype):
         state = after
 
     _assert_direction(direction(jnp.asarray(g), *state), ring, g)
+
+
+@pytest.mark.parametrize("d", sorted(ROW_SHAPES))
+def test_a_recursion_over_a_row_of_lanes_is_written_out(d):
+    """A history whose row is one row of lanes or less (the vmapped
+    per-entity solves: 16 coefficients, or 16 padded to 128) has its
+    recursion written out, with no loop left for the chip to run step by
+    step; a wider one (the fixed effect's) keeps its two loops."""
+    hist = jax.ShapeDtypeStruct((M,) + ROW_SHAPES[d], jnp.float32)
+    vec = jax.ShapeDtypeStruct((d,), jnp.float32)
+    rho = jax.ShapeDtypeStruct((M,), jnp.float32)
+    count = jax.ShapeDtypeStruct((), jnp.int32)
+    text = jax.jit(two_loop_direction).lower(vec, hist, hist, rho, count).as_text()
+    loops = text.count("stablehlo.while")
+    assert loops == (0 if int(np.prod(ROW_SHAPES[d])) <= 128 else 2)
+
+
+def test_a_narrow_tiled_history_is_written_out_too():
+    """The one-row ring ``[m, 1, 128]`` of a 16-wide solve is written out
+    as the ``[m, 16]`` rows are, so the two layouts do the same arithmetic
+    (``tests/test_members_reference.py`` holds them to one trajectory)."""
+    hist = jax.ShapeDtypeStruct((M, 1, 128), jnp.float32)
+    vec = jax.ShapeDtypeStruct((16,), jnp.float32)
+    rho = jax.ShapeDtypeStruct((M,), jnp.float32)
+    count = jax.ShapeDtypeStruct((), jnp.int32)
+    text = jax.jit(two_loop_direction).lower(vec, hist, hist, rho, count).as_text()
+    assert "stablehlo.while" not in text

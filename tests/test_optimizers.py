@@ -445,9 +445,9 @@ def test_chunked_resume_matches_oneshot(rng, name, width):
     """init -> chunk(K) ... -> finalize must follow the EXACT trajectory of
     the uninterrupted solve: the chunk boundary only caps the while_loop's
     trip count, it never perturbs the carried state (L-BFGS history ring,
-    TRON trust radius, OWL-QN pseudo-gradient bookkeeping). At 1,100
-    coefficients a row of the history that crosses the boundary spans nine
-    tiles and is padded to 1,152 (``opt/lbfgs.py:history_zeros``)."""
+    TRON trust radius, OWL-QN pseudo-gradient bookkeeping). At 6 coefficients
+    the history is ``[m, 6]``; at 1,100 a row of it that crosses the boundary
+    spans nine tiles and is padded to 1,152 (``opt/lbfgs.py:history_zeros``)."""
     from photon_ml_tpu.opt import solve, solve_chunk, solve_finalize, solve_init
 
     if name == "tron":
@@ -473,7 +473,8 @@ def test_chunked_resume_matches_oneshot(rng, name, width):
     ref = solve(obj, w0, data, configuration)
     state = solve_init(obj, w0, data, configuration)
     if name != "tron":
-        assert state.s_hist.shape[1:] == (-(-width // 128), 128)
+        rows = (width,) if width < 128 else (-(-width // 128), 128)
+        assert state.s_hist.shape[1:] == rows
     for _ in range(40):  # 40 chunks x 3 iters covers max_iterations=100
         state = solve_chunk(obj, state, data, configuration, num_iters=3)
     res = solve_finalize(state, configuration)

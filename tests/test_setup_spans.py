@@ -120,7 +120,14 @@ def test_a_random_effect_build_is_host_work_then_one_upload():
     mine = [s for s in spans if s.parent_id == outer.span_id and not s.name.startswith("jit/")]
     assert [s.name for s in mine] == ["re/build_dataset", "data/upload"]
     host, uploaded = mine
-    assert host.attrs == {"entities": ds.num_entities, "buckets": len(ds.buckets)}
+    blocks = [(np.asarray(b.weights) > 0, np.asarray(b.proj_valid), b.X.size) for b in ds.buckets]
+    assert host.attrs == {
+        "entities": ds.num_entities, "buckets": len(ds.buckets),
+        # an active row's real local columns, against every cell of the blocks
+        "real_cells": sum(int((w.sum(1) * v.sum(1)).sum()) for w, v, _ in blocks),
+        "padded_cells": sum(int(size) for _, _, size in blocks),
+    }
+    assert 0 < host.attrs["real_cells"] <= host.attrs["padded_cells"]
     assert host.start_s + host.duration_s <= uploaded.start_s
     assert uploaded.attrs["what"] == "re_bucket"
     assert uploaded.attrs["bytes"] == _device_bytes((ds.buckets, ds.passive, ds.row_gather))

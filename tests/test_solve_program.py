@@ -259,13 +259,14 @@ def test_variances_and_tracking_reuse_their_programs(clean_slate, watch):
 
 @pytest.mark.parametrize(
     "solver,d,layout",
-    [("lbfgs", 40, "tiled"), ("lbfgs", 1100, "tiled"), ("owlqn", 40, "tiled"),
+    [("lbfgs", 40, "rows"), ("lbfgs", 1100, "tiled"), ("owlqn", 40, "rows"),
      ("owlqn", 1100, "tiled"), ("tron", 1100, "none")],
 )
 def test_a_traced_solve_says_how_its_history_lies(solver, d, layout, clean_slate, watch):
     """``glm/solve`` carries ``history_layout`` beside the solver's counts:
-    ``tiled`` (a row of the history to itself, whatever the width) for L-BFGS
-    and OWL-QN, ``none`` for TRON."""
+    for L-BFGS and OWL-QN ``rows`` (``[m, d]``) under 128 coefficients and
+    ``tiled`` (a row of the history to itself) from there up, ``none`` for
+    TRON."""
     w = watch(solver)
     _fit(_data("ell", d=d), solver)
     (solved,) = [s for s in w.tracer.spans() if s.name == "glm/solve"]

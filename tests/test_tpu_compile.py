@@ -225,12 +225,9 @@ def test_random_effect_kernel_compiles(v5e, batched):
     assert compile_for_tpu(jax.vmap(fn) if batched else fn, v5e, *args) == 1
 
 
-def test_adaptive_chunk_program_compiles_at_the_cell_width(v5e):
-    """The adaptive random-effect driver's one chunk program of a bucket
-    shape, at the benchmark's per-user bucket: a loop over a run-time count
-    of tiles that gathers 1,024 of the 16,384 lanes, runs the vmapped L-BFGS
-    chunk on them and scatters them back."""
-    E, S, D = 16384, 100, 16
+def _re_chunk_case(E, S, D=16):
+    """The adaptive driver's programs of one L-BFGS bucket shape (cell 1's
+    solver settings) and the shapes of their operands."""
     cfg = GlmOptimizationConfiguration(
         optimizer_config=OptimizerConfig.lbfgs(max_iterations=20),
         regularization_weight=1.0,
@@ -243,11 +240,51 @@ def test_adaptive_chunk_program_compiles_at_the_cell_width(v5e):
     )
     state = jax.eval_shape(progs.init, f32((E, D)), data, f32(()), f32(()))
     T = random_effect._tile_lanes(E, cfg.adaptive.min_lanes)
-    assert T == 1024
-    live_idx = jax.ShapeDtypeStruct((E,), jnp.int32)
+    live_tiles = jax.ShapeDtypeStruct((-(-E // T),), jnp.int32)
     n_tiles = jax.ShapeDtypeStruct((), jnp.int32)
-    # no Mosaic kernel: gathers, scatters and the solver's XLA loops
-    assert compile_for_tpu(progs.chunk, v5e, state, data, f32(()), live_idx, n_tiles) == 0
+    return progs, data, state, T, (f32(()), live_tiles, n_tiles)
+
+
+def test_adaptive_chunk_program_compiles_at_the_cell_width(v5e):
+    """The adaptive random-effect driver's one chunk program of a bucket
+    shape, at the benchmark's per-user bucket: a loop over a run-time count
+    of live tiles, each a slice of 1,024 of the 16,384 lanes, the vmapped
+    L-BFGS chunk run on it and the slice written back."""
+    progs, data, state, T, rest = _re_chunk_case(16384, 100)
+    assert T == 1024
+    # no Mosaic kernel: slices and the solver's XLA loops
+    assert compile_for_tpu(progs.chunk, v5e, state, data, *rest) == 0
+
+
+def test_member_solver_state_fits_two_kilobytes_an_entity(v5e):
+    """A bucket of 2^20 one-row members at d = 16, m = 10, compiled for the
+    chip. The solver state as the init program lays it out is its
+    ``[E, 10, 16]`` s and y histories with the entity axis minor, in lanes,
+    and the vectors beside them: 1,596 bytes an entity, where a history of
+    ``[E, 10, 1, 128]`` took 10,556 (every row padded to 128 lanes). The
+    chunk program takes the state as it lies and works a tile of 65,536
+    lanes at a time, and what it holds beside its arguments is the tile's
+    (2.9 KB a tile lane). Two ways of holding the bucket's whole width went
+    before this layout could: a gather of live lanes along the minor axis
+    (24 KB an entity: the whole state laid out again, padded), and a ring
+    buffer read at a slot that differs from lane to lane (25 KB a tile lane:
+    each lane's buffer laid out again, padded, at every chunk)."""
+    E = 1 << 20
+    progs, data, state, T, rest = _re_chunk_case(E, 1)
+    struct = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), tree
+    )
+    f32 = jax.ShapeDtypeStruct((), jnp.float32, sharding=v5e)
+    init = progs.init.lower(
+        struct(jax.ShapeDtypeStruct((E, 16), jnp.float32)), struct(data), f32, f32
+    ).compile()
+    state_bytes = init.memory_analysis().output_size_in_bytes
+    assert state_bytes < 2048 * E
+    chunk = progs.chunk.lower(struct(state), struct(data), *struct(rest)).compile()
+    held = chunk.memory_analysis()
+    data_bytes = held.argument_size_in_bytes - state_bytes
+    assert data_bytes < 128 * E  # one row of 16 features, its label, offset, weight
+    assert held.temp_size_in_bytes < 4096 * T
 
 
 @pytest.mark.parametrize("d", [40_000_000, 40_000_001], ids=["cell_width", "padded_row"])
