@@ -30,7 +30,7 @@ from photon_ml_tpu.losses.pointwise import loss_for_task
 from photon_ml_tpu.models.coefficients import Coefficients
 from photon_ml_tpu.models.glm import GeneralizedLinearModel
 from photon_ml_tpu.opt.config import GlmOptimizationConfiguration, OptimizerConfig
-from photon_ml_tpu.opt.lbfgs import history_layout
+from photon_ml_tpu.opt.lbfgs import direction_form, history_layout
 from photon_ml_tpu.opt.solve import solve, solver_kind
 from photon_ml_tpu.opt.state import SolveResult
 from photon_ml_tpu.ops.data import LabeledData
@@ -221,7 +221,9 @@ def train_glm(
                         evaluations=int(result.evaluations),
                         hessian_vecs=int(result.hessian_vecs),
                         rejected_steps=int(result.rejected_steps),
+                        descent_restarts=int(result.descent_restarts),
                         history_layout=layout,
+                        direction=direction_form(layout),
                     )
             if warm_start:
                 w = result.w

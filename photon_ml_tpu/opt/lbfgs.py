@@ -44,6 +44,15 @@ def history_layout(dim: int) -> str:
     return "rows" if dim < _LANES else "tiled"
 
 
+def direction_form(layout: str) -> str:
+    """How the quasi-Newton direction of a solve whose history lies as
+    ``layout`` (``history_layout``, or "none" for TRON) is formed: ``gram``
+    from the carried Gram matrix (``gram_zeros``), ``recursion`` by the
+    two-loop over the rows, ``none`` without a history. What a ``glm/solve``
+    span reports as its ``direction``."""
+    return {"none": "none", "rows": "recursion", "tiled": "gram"}[layout]
+
+
 def history_zeros(m: int, dim: int, dtype) -> jax.Array:
     """An empty s/y ring buffer for a solve of static width ``dim``; the
     shape alone decides the layout (``history_layout``).
@@ -59,30 +68,42 @@ def history_zeros(m: int, dim: int, dtype) -> jax.Array:
     vmapped to ``[E, m, d]`` the chip lays the entity axis minor, in lanes,
     so nothing pads (640 bytes an entity at ``m = 10``, ``d = 16``), where
     ``[E, m, 1, 128]`` padded every row to 128 lanes (5,120 bytes, eight
-    times the data; ``tests/test_tpu_compile.py``). The shape is known here
-    and to the three helpers below, through which ``two_loop_direction``
-    and ``update_history`` go."""
+    times the data; ``tests/test_tpu_compile.py``). The buffers' rank is
+    what ``gram_zeros``, ``two_loop_direction`` and ``update_history`` go
+    by."""
     if history_layout(dim) == "rows":
         return jnp.zeros((m, dim), dtype=dtype)
     return jnp.zeros((m, -(-dim // _LANES), _LANES), dtype=dtype)
 
 
-def _row_width(hist: jax.Array) -> int:
-    """Elements in one row of a history buffer, its padding included."""
-    return math.prod(hist.shape[1:])
+def gram_zeros(s_hist: jax.Array, v: jax.Array) -> jax.Array:
+    """The inner products a solve carries beside its empty history
+    ``s_hist`` (``history_zeros``), ``v`` being the vector whose direction
+    its first iteration takes: ``g`` for L-BFGS, the pseudo-gradient for
+    OWL-QN.
+
+    A ``tiled`` history carries the symmetric ``[2m+1, 2m+1]`` Gram matrix
+    of the basis ``[S; Y; v]`` (index ``i`` is row ``i`` of ``s_hist``,
+    ``m + i`` row ``i`` of ``y_hist``, ``2m`` the vector), in the working
+    dtype, which ``update_history`` keeps one row and column at a time.
+    ``two_loop_direction`` then runs the recursion on ``2m+1`` coefficients
+    and reads the history once (the vector-free L-BFGS of Chen, Wang and
+    Zhou, "Large-scale L-BFGS using MapReduce", NIPS 2014). Before any pair
+    only ``v.v`` is set. A ``rows`` history (the vmapped per-entity solves)
+    carries ``[0, 0]``: its recursion reads its narrow rows, and a lane's
+    state does not grow."""
+    if s_hist.ndim == 2:
+        return jnp.zeros((0, 0), v.dtype)
+    k = 2 * s_hist.shape[0] + 1
+    return jnp.zeros((k, k), v.dtype).at[-1, -1].set(jnp.sum(v * v))
 
 
-def _history_row(hist: jax.Array, idx, dtype) -> jax.Array:
-    """Row ``idx`` of a history buffer as a flat vector of the working dtype,
-    its zero padding (if any) included: a bitcast of the row's tiles, where
-    a ``[:d]`` slice would write the row out first."""
-    return hist[idx].reshape(-1).astype(dtype)
-
-
-def _as_history_row(vec: jax.Array, hist: jax.Array) -> jax.Array:
-    """``vec`` [dim] in the storage dtype and row shape of ``hist``."""
-    pad = _row_width(hist) - vec.shape[-1]
-    return jnp.pad(vec.astype(hist.dtype), (0, pad)).reshape(hist.shape[1:])
+def _as_history_row(vec: jax.Array, hist: jax.Array, dtype=None) -> jax.Array:
+    """``vec`` [dim] zero-padded and shaped as a row of ``hist``, in ``dtype``
+    (the buffer's own storage dtype by default)."""
+    pad = math.prod(hist.shape[1:]) - vec.shape[-1]
+    dtype = hist.dtype if dtype is None else dtype
+    return jnp.pad(vec.astype(dtype), (0, pad)).reshape(hist.shape[1:])
 
 
 class _LbfgsState(NamedTuple):
@@ -93,7 +114,8 @@ class _LbfgsState(NamedTuple):
 
     ``s_hist`` / ``y_hist`` are what ``history_zeros`` makes for ``d``:
     ``[m, d]`` under 128 coefficients, else ``[m, ceil(d/128), 128]``, so
-    that a row is contiguous in HBM."""
+    that a row is contiguous in HBM; ``gram`` is what ``gram_zeros`` makes
+    beside them, with ``v = g``."""
 
     w: jax.Array          # [d]
     f: jax.Array
@@ -102,8 +124,10 @@ class _LbfgsState(NamedTuple):
     y_hist: jax.Array     # gradient-diff ring buffer (history_zeros)
     rho: jax.Array        # [m] 1/(s.y)
     count: jax.Array      # int32 number of valid history pairs
+    gram: jax.Array       # [2m+1, 2m+1] products of [S; Y; g] ([0, 0] for rows)
     it: jax.Array         # int32 outer iteration
     evals: jax.Array      # int32 objective.value_and_grad calls so far
+    descent_restarts: jax.Array  # int32 iterations that fell back to -g
     reason: jax.Array     # int32 ConvergenceReason
     history: jax.Array    # [max_iter+1] objective values
     w_hist: jax.Array     # [max_iter+1, d] coefficients (or [0] when off)
@@ -111,79 +135,115 @@ class _LbfgsState(NamedTuple):
     abs_g_tol: jax.Array  # scalar, derived from ||g0|| at init
 
 
-def two_loop_direction(
-    g: jax.Array, s_hist: jax.Array, y_hist: jax.Array, rho: jax.Array, count: jax.Array
-) -> jax.Array:
-    """Two-loop recursion over a masked history of the last ``m`` pairs.
+def _tile_dots(rows: jax.Array, x: jax.Array) -> jax.Array:
+    """``rows[..., T, 128] . x[T, 128]`` over the last two axes, a vector-unit
+    multiply-reduce in the working dtype (never a contraction that the chip
+    could run as bfloat16 passes). Summed over the tiles first and the lanes
+    last, so that the TPU compiler fuses every such sum over the same rows
+    into one pass that reads each row once."""
+    return jnp.sum(jnp.sum(rows * x, axis=-2), axis=-1)
 
-    A ``tiled`` history is a ring: slots are ordered oldest→newest modulo m.
-    A ``rows`` history (``history_layout``) keeps its newest pair in row 0
+
+def _recursion(g, s_hist, y_hist, rho, count) -> jax.Array:
+    """The two-loop recursion over a ``rows`` history: newest pair in row 0
     and the older ones below it, so the recursion reads row ``i`` at its
-    ``i``-th step whatever ``count`` is: vmapped over entities whose counts
+    ``i``-th step whatever ``count`` is (vmapped over entities whose counts
     differ, a read is a slice where a ring's per-lane slot was a gather,
     which the chip answered by laying every lane's buffer out again, padded
-    to 128 lanes. Either way slot i is valid iff it holds one of the last
-    ``count`` pairs; invalid slots have rho=0 so their updates are algebraic
-    no-ops (alpha = rho*(s.q) = 0), which keeps the loop branch-free, and
-    the two orders do the same arithmetic on the same pairs.
-    """
+    to 128 lanes). Row ``i`` is valid iff ``i < count``; invalid rows have
+    rho=0, so their updates are algebraic no-ops (alpha = rho*(s.q) = 0).
+    Its 2m steps are written out: each is then a fused operation or two,
+    where a loop step was about ten launches, every iteration of every
+    tile."""
     m = rho.shape[0]
-    newest_first = s_hist.ndim == 2
-
-    # History may be stored bf16 (config.history_dtype); rows are cast to
-    # the working dtype on read so every dot/axpy accumulates full precision.
     wd = g.dtype
-    # The recursion runs at the rows' padded width (g padded once, the
-    # direction cut once): the padding is zero in every row, so it stays
-    # zero in q and r and adds nothing to a dot product. No-ops where d is
-    # a multiple of 128.
-    dim = g.shape[-1]
-    g = jnp.pad(g, (0, _row_width(s_hist) - dim))
-
-    def s_row(idx):
-        return _history_row(s_hist, idx, wd)
-
-    def y_row(idx):
-        return _history_row(y_hist, idx, wd)
 
     def bwd(i, carry):
         q, alphas = carry
-        idx = i if newest_first else jnp.mod(count - 1 - i, m)  # newest first
-        valid = i < count
-        r = jnp.where(valid, rho[idx], 0.0)
-        a = r * jnp.dot(s_row(idx), q)
-        q = q - a * y_row(idx)
-        alphas = alphas.at[idx].set(a)
-        return q, alphas
+        r = jnp.where(i < count, rho[i], 0.0)
+        a = r * jnp.dot(s_hist[i].astype(wd), q)
+        return q - a * y_hist[i].astype(wd), alphas.at[i].set(a)
 
-    # A history of a row of lanes or less (the vmapped per-entity solves)
-    # has its recursion written out: each of its 2m steps is then a fused
-    # operation or two, where a loop step was about ten launches, every
-    # iteration of every tile. A wide one (the fixed effect's) keeps the
-    # loop, whose steps stream their rows.
-    unroll = _row_width(s_hist) <= _LANES
-    q, alphas = jax.lax.fori_loop(0, m, bwd, (g, jnp.zeros_like(rho)), unroll=unroll)
+    q, alphas = jax.lax.fori_loop(0, m, bwd, (g, jnp.zeros_like(rho)), unroll=True)
 
     # initial Hessian scaling gamma = (s.y)/(y.y) of the newest valid pair
-    newest = 0 if newest_first else jnp.mod(count - 1, m)
-    have = count > 0
-    s_new = s_row(newest)
-    y_new = y_row(newest)
+    s_new = s_hist[0].astype(wd)
+    y_new = y_hist[0].astype(wd)
     sy = jnp.dot(s_new, y_new)
     yy = jnp.dot(y_new, y_new)
-    gamma = jnp.where(have & (yy > 0), sy / jnp.maximum(yy, 1e-30), 1.0)
-    r_vec = gamma * q
+    gamma = jnp.where((count > 0) & (yy > 0), sy / jnp.maximum(yy, 1e-30), 1.0)
 
     def fwd(i, r_vec):
-        # oldest first among the last m
-        idx = m - 1 - i if newest_first else jnp.mod(count - m + i, m)
-        valid = i >= (m - jnp.minimum(count, m))
+        idx = m - 1 - i  # oldest first among the last m
+        valid = idx < count
         r = jnp.where(valid, rho[idx], 0.0)
-        beta = r * jnp.dot(y_row(idx), r_vec)
-        return r_vec + jnp.where(valid, (alphas[idx] - beta), 0.0) * s_row(idx)
+        beta = r * jnp.dot(y_hist[idx].astype(wd), r_vec)
+        return r_vec + jnp.where(valid, (alphas[idx] - beta), 0.0) * s_hist[idx].astype(wd)
 
-    r_vec = jax.lax.fori_loop(0, m, fwd, r_vec, unroll=unroll)
-    return -r_vec[:dim]
+    return -jax.lax.fori_loop(0, m, fwd, gamma * q, unroll=True)
+
+
+def _gram_coefficients(gram, rho, count):
+    """The two-loop recursion run on the coefficients of ``q`` over the basis
+    ``[S; Y; v]`` (``gram_zeros``), from ``q = v``: each inner product it
+    needs is a row of the Gram matrix against the coefficients. The ring's
+    slots are taken newest first (slot ``(count - 1 - i) mod m`` at step
+    ``i``), and the last ``count`` of them are valid; an invalid slot has
+    rho=0 and adds nothing. Returns the coefficients of ``H v`` on the basis
+    as it is stored, and ``v . H v``."""
+    m = rho.shape[0]
+    slots = jnp.mod(count - 1 - jnp.arange(m), m)
+    basis = jnp.concatenate([slots, m + slots, jnp.array([2 * m])])
+    ordered = gram[basis][:, basis]  # newest first
+    r = jnp.where(jnp.arange(m) < count, rho[slots], 0.0)
+
+    c = jnp.zeros((2 * m + 1,), gram.dtype).at[2 * m].set(1.0)
+    alphas = []
+    for i in range(m):
+        a = r[i] * jnp.sum(ordered[i] * c)
+        c = c.at[m + i].add(-a)
+        alphas.append(a)
+    sy, yy = ordered[0, m], ordered[m, m]
+    c = c * jnp.where((count > 0) & (yy > 0), sy / jnp.maximum(yy, 1e-30), 1.0)
+    for i in reversed(range(m)):
+        beta = r[i] * jnp.sum(ordered[m + i] * c)
+        c = c.at[i].add(alphas[i] - beta)
+    return jnp.zeros_like(c).at[basis].set(c), jnp.sum(ordered[2 * m] * c)
+
+
+def two_loop_direction(
+    v: jax.Array,
+    s_hist: jax.Array,
+    y_hist: jax.Array,
+    rho: jax.Array,
+    count: jax.Array,
+    gram: jax.Array,
+) -> Tuple[jax.Array, jax.Array]:
+    """The quasi-Newton direction ``d = -H v`` over a masked history of the
+    last ``m`` pairs, and ``d . v``.
+
+    A ``tiled`` history takes it from the carried Gram matrix: the recursion
+    on ``2m+1`` coefficients, then one pass ``d = -(sum_i a_i s_i + sum_i
+    b_i y_i + c v)`` that reads each row once (2m + 2 vectors of traffic,
+    where the row recursion moved about 10m). ``d . v`` comes from the
+    coefficients. A ``rows`` history runs the two-loop recursion over its
+    rows (``_recursion``). History may be stored bf16
+    (config.history_dtype); rows are cast to the working dtype on read."""
+    if s_hist.ndim == 2:
+        d = _recursion(v, s_hist, y_hist, rho, count)
+        return d, jnp.dot(d, v)
+    m, dim, wd = rho.shape[0], v.shape[-1], v.dtype
+    coef, vhv = _gram_coefficients(gram, rho, count)
+    # written out as one sum of 2m+1 scaled rows, so that it is one pass
+    # (two reductions over the rows' axis were two, and a third to add
+    # them); the padding is zero in every row and in v, so it stays zero
+    hv = coef[2 * m] * _as_history_row(v, s_hist, wd)
+    for i in range(m):
+        hv = hv + coef[i] * s_hist[i].astype(wd) + coef[m + i] * y_hist[i].astype(wd)
+    if math.prod(s_hist.shape[1:]) > dim:
+        # cut once the sum is written: a cut fused into the sum split it
+        hv = jax.lax.optimization_barrier(hv)
+    return -hv.reshape(-1)[:dim], -vhv
 
 
 def resolve_history_dtype(config: OptimizerConfig, working_dtype) -> jnp.dtype:
@@ -192,41 +252,104 @@ def resolve_history_dtype(config: OptimizerConfig, working_dtype) -> jnp.dtype:
     return jnp.dtype(config.history_dtype) if config.history_dtype else working_dtype
 
 
-def update_history(
-    s_hist, y_hist, rho, count, s_vec, y_vec
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Curvature-guarded insert (skip when s.y too small), casting the pair
-    to the buffers' storage dtype — shared by L-BFGS and OWL-QN so their
-    history handling cannot diverge. Into a ``tiled`` history's ring the
-    guard selects on the row, not on the buffer: a rejected pair rewrites
-    one row with itself, an accepted one is an in-place
-    dynamic-update-slice. A ``rows`` history (newest first, see
-    ``two_loop_direction``) shifts down by a row and takes the pair as row
-    0: ``m`` narrow rows moved, and no slot that differs from lane to lane."""
-    m = rho.shape[0]
-    sy = jnp.dot(s_vec, y_vec)
-    good_pair = sy > 1e-10 * jnp.maximum(jnp.dot(y_vec, y_vec), 1e-30)
-    inv_sy = 1.0 / jnp.maximum(sy, 1e-30)
+def _gram_pass(s_hist, y_hist, count, gram, s_vec, y_vec, v_next):
+    """The end of an iteration's one pass over a ``tiled`` history: the pair
+    as its rows will hold it and ``v_next``, each against every row of ``S``
+    and ``Y`` as they stand and against each other, in one fusion that
+    reads each row and each vector once. Returns the curvature guard, ``1 /
+    (s.y)`` and the Gram matrix with the pair at slot ``count mod m`` if it
+    is accepted, and ``v_next``'s row and column either way."""
+    m, wd = s_hist.shape[0], gram.dtype
+    s_r, y_r = (_as_history_row(x, s_hist).astype(wd) for x in (s_vec, y_vec))
+    v_t = _as_history_row(v_next, s_hist, wd)
+    # S and Y each with two rows more, [S; s; v] and [Y; y; y], padded and
+    # selected inside the fusion that reads them: so the three vectors'
+    # products with each other come out of the same pass (a concatenation
+    # was written out, and products of their own read the vectors again)
+    row = jax.lax.broadcasted_iota(jnp.int32, (m + 2, 1, 1), 0)
+    grown = lambda hist: jnp.pad(hist.astype(wd), ((0, 2), (0, 0), (0, 0)))  # noqa: E731
+    s_ext = jnp.where(row == m, s_r, jnp.where(row == m + 1, v_t, grown(s_hist)))
+    y_ext = jnp.where(row >= m, y_r, grown(y_hist))
 
+    def line(x):
+        """``x`` against ``[S; Y; v_next]`` as they stand, and ``x.s``,
+        ``x.y``."""
+        a, b = _tile_dots(s_ext, x), _tile_dots(y_ext, x)
+        return jnp.concatenate([a[:m], b[:m], a[m + 1 :]]), a[m], b[m]
+
+    s_line, ss, sy = line(s_r)
+    y_line, _, yy = line(y_r)
+    v_old, vs, vy = line(v_t)
+    good_pair = sy > 1e-10 * jnp.maximum(yy, 1e-30)
+
+    slot = jnp.mod(count, m)
+
+    def put_line(mat, i, vals):
+        return mat.at[i, :].set(vals).at[:, i].set(vals)
+
+    def with_pair(vals, with_s, with_y):
+        return vals.at[slot].set(with_s).at[m + slot].set(with_y)
+
+    paired = put_line(gram, slot, with_pair(s_line, ss, sy))
+    paired = put_line(paired, m + slot, with_pair(y_line, sy, yy))
+    gram = put_line(
+        jnp.where(good_pair, paired, gram), 2 * m,
+        jnp.where(good_pair, with_pair(v_old, vs, vy), v_old),
+    )
+    return good_pair, 1.0 / jnp.maximum(sy, 1e-30), gram
+
+
+def update_history(
+    s_hist, y_hist, rho, count, gram, s_vec, y_vec, v_next
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Curvature-guarded insert (skip when s.y too small), casting the pair
+    to the buffers' storage dtype, and the Gram matrix brought up to the
+    history and to ``v_next``, the vector the next direction is taken from
+    — shared by L-BFGS and OWL-QN so their history handling cannot diverge.
+    The guard and ``rho = 1 / (s.y)`` are of the pair as the rows hold it,
+    so that a bfloat16 history's update keeps the secant equation of the
+    pair it stores.
+
+    Into a ``tiled`` history's ring the pair goes at slot ``count mod m``.
+    One pass reads ``S`` and ``Y`` once and the pair and ``v_next`` once,
+    for ``s``, ``y`` and ``v_next`` against every row and each other (the
+    guard's ``s.y`` and ``y.y`` among them); the slot's row and column of
+    the Gram matrix then take the new pair's products and the last row and
+    column ``v_next``'s. A rejected pair leaves every row and the pairs'
+    products as they were, and changes only ``v_next``'s. The guard selects
+    on the row, not on the buffer: a rejected pair rewrites one row with
+    itself, an accepted one is an in-place dynamic-update-slice.
+
+    A ``rows`` history (newest first, see ``_recursion``) shifts down by a
+    row and takes the pair as row 0: ``m`` narrow rows moved, and no slot
+    that differs from lane to lane. It carries no Gram matrix."""
+    m, wd = rho.shape[0], rho.dtype
     if s_hist.ndim == 2:
+        s_row, y_row = _as_history_row(s_vec, s_hist), _as_history_row(y_vec, y_hist)
+        s_r, y_r = s_row.astype(wd), y_row.astype(wd)
+        sy = jnp.dot(s_r, y_r)
+        good_pair = sy > 1e-10 * jnp.maximum(jnp.dot(y_r, y_r), 1e-30)
+        inv_sy = 1.0 / jnp.maximum(sy, 1e-30)
+
         def shift(hist, row):
             return jnp.where(good_pair, jnp.concatenate([row[None], hist[:-1]]), hist)
 
-        s_hist = shift(s_hist, _as_history_row(s_vec, s_hist))
-        y_hist = shift(y_hist, _as_history_row(y_vec, y_hist))
-        rho = shift(rho, inv_sy.astype(rho.dtype))
-    else:
-        slot = jnp.mod(count, m)
+        s_hist = shift(s_hist, s_row)
+        y_hist = shift(y_hist, y_row)
+        rho = shift(rho, inv_sy)
+        return s_hist, y_hist, rho, jnp.where(good_pair, count + 1, count), gram
 
-        def put(hist, vec):
-            row = jnp.where(good_pair, _as_history_row(vec, hist), hist[slot])
-            return hist.at[slot].set(row)
+    good_pair, inv_sy, gram = _gram_pass(s_hist, y_hist, count, gram, s_vec, y_vec, v_next)
+    slot = jnp.mod(count, m)
 
-        s_hist = put(s_hist, s_vec)
-        y_hist = put(y_hist, y_vec)
-        rho = jnp.where(good_pair, rho.at[slot].set(inv_sy), rho)
-    count = jnp.where(good_pair, count + 1, count)
-    return s_hist, y_hist, rho, count
+    def put(hist, vec):
+        row = jnp.where(good_pair, _as_history_row(vec, hist), hist[slot])
+        return hist.at[slot].set(row)
+
+    s_hist = put(s_hist, s_vec)
+    y_hist = put(y_hist, y_vec)
+    rho = jnp.where(good_pair, rho.at[slot].set(inv_sy), rho)
+    return s_hist, y_hist, rho, jnp.where(good_pair, count + 1, count), gram
 
 
 def _project_box(w: jax.Array, lower, upper) -> jax.Array:
@@ -271,16 +394,19 @@ def lbfgs_init(
         if config.track_coefficients
         else jnp.zeros((0,), dtype=dtype)
     )
+    s_hist = history_zeros(m, dim, hdtype)
     return _LbfgsState(
         w=w0,
         f=f0,
         g=g0,
-        s_hist=history_zeros(m, dim, hdtype),
+        s_hist=s_hist,
         y_hist=history_zeros(m, dim, hdtype),
         rho=jnp.zeros((m,), dtype=dtype),
         count=jnp.int32(0),
+        gram=gram_zeros(s_hist, g0),
         it=jnp.int32(0),
         evals=jnp.int32(1),
+        descent_restarts=jnp.int32(0),
         reason=jnp.int32(ConvergenceReason.NOT_CONVERGED.value),
         history=history0,
         w_hist=w_hist0,
@@ -317,8 +443,7 @@ def lbfgs_chunk(
         return c
 
     def body(s: _LbfgsState) -> _LbfgsState:
-        d = two_loop_direction(s.g, s.s_hist, s.y_hist, s.rho, s.count)
-        dphi0 = jnp.dot(d, s.g)
+        d, dphi0 = two_loop_direction(s.g, s.s_hist, s.y_hist, s.rho, s.count, s.gram)
         # Safeguard: if not a descent direction (can happen after box
         # projection perturbs the quasi-Newton pairs), restart with -g.
         bad = dphi0 >= 0
@@ -352,8 +477,8 @@ def lbfgs_chunk(
 
         s_vec = w_new - s.w
         y_vec = g_new - s.g
-        s_hist, y_hist, rho, count = update_history(
-            s.s_hist, s.y_hist, s.rho, s.count, s_vec, y_vec
+        s_hist, y_hist, rho, count, gram = update_history(
+            s.s_hist, s.y_hist, s.rho, s.count, s.gram, s_vec, y_vec, g_new
         )
 
         it = s.it + 1
@@ -390,8 +515,10 @@ def lbfgs_chunk(
             y_hist=y_hist,
             rho=rho,
             count=count,
+            gram=gram,
             it=it,
             evals=evals,
+            descent_restarts=s.descent_restarts + bad.astype(jnp.int32),
             reason=reason,
             history=s.history.at[it].set(f_new),
             w_hist=(
@@ -425,6 +552,7 @@ def lbfgs_finalize(
         evaluations=state.evals,
         hessian_vecs=jnp.zeros_like(state.it),
         rejected_steps=jnp.zeros_like(state.it),
+        descent_restarts=state.descent_restarts,
         reason=reason,
         value_history=state.history,
         w_history=state.w_hist if config.track_coefficients else None,

@@ -46,6 +46,17 @@ def strong_wolfe_search(
     satisfy Wolfe within ``max_iters`` evaluations it returns the best
     sufficient-decrease point seen (success=False if none found; the t=0
     point with its g0 is the last resort so the caller can detect a null step).
+
+    The search also stops, with the same fallback, when a trial whose slope
+    is still steeper than ``C2 * dphi0`` becomes the bracket's upper end (its
+    value is not below the bracket's lower end, or fails sufficient
+    decrease). On a convex objective, which a GLM's is, that can only be
+    rounding: the value at such a step lies at least ``C2 |dphi0| t`` below
+    ``f0``. Every slope left inside the bracket is steeper still, so no
+    point in it can meet the curvature condition, and the bisections that
+    remain could only read rounding. A search that starts at the rounding
+    floor of a converged solve would otherwise spend all ``max_iters`` of
+    its evaluations there, as many or as few as rounding draws.
     """
 
     class _S(NamedTuple):
@@ -67,6 +78,8 @@ def strong_wolfe_search(
         f_acc: jax.Array
         g_acc: jax.Array
         success: jax.Array
+        # no point left in the bracket can meet the curvature condition
+        hopeless: jax.Array
 
     zero = jnp.zeros_like(t_init)
     init = _S(
@@ -86,15 +99,18 @@ def strong_wolfe_search(
         f_acc=f0,
         g_acc=g0,
         success=jnp.bool_(False),
+        hopeless=jnp.bool_(False),
     )
 
     def cond(s: _S):
-        return (s.stage != 2) & (s.i < max_iters)
+        return (s.stage != 2) & (s.i < max_iters) & ~s.hopeless
 
     def body(s: _S) -> _S:
         f_t, g_t, d_t = eval_step(s.t)
         armijo_fail = (f_t > f0 + C1 * s.t * dphi0) | ((s.i > 0) & (f_t >= s.f_lo) & (s.stage == 0))
         wolfe_ok = (~armijo_fail) & (jnp.abs(d_t) <= -C2 * dphi0)
+        # in the zoom, t becomes the upper end unless it improves on the lower
+        shrink_hi = armijo_fail | (f_t >= s.f_lo)
 
         # track best sufficient-decrease point as a fallback
         suff = f_t <= f0 + C1 * s.t * dphi0
@@ -120,7 +136,6 @@ def strong_wolfe_search(
             return stage, t_next, t_lo, f_lo, d_lo, t_hi, f_hi
 
         def zoom_step():
-            shrink_hi = armijo_fail | (f_t >= s.f_lo)
             stage = jnp.where(wolfe_ok, 2, jnp.int32(1))
             # if new lo, possibly swap hi to old lo when derivative points past
             swap = (~shrink_hi) & (d_t * (s.t_hi - s.t_lo) >= 0)
@@ -144,6 +159,10 @@ def strong_wolfe_search(
         f_hi = jnp.where(in_zoom, z[6], b[6])
 
         accepted = stage == 2
+        # t became the bracket's upper end while its slope is still steeper
+        # than the curvature condition allows (see the docstring)
+        upper = jnp.where(in_zoom, shrink_hi, armijo_fail)
+        hopeless = (~accepted) & upper & (d_t < C2 * dphi0)
         return _S(
             stage=stage,
             i=s.i + 1,
@@ -161,6 +180,7 @@ def strong_wolfe_search(
             f_acc=jnp.where(accepted, f_t, s.f_acc),
             g_acc=jnp.where(accepted, g_t, s.g_acc),
             success=s.success | accepted,
+            hopeless=hopeless,
         )
 
     o = jax.lax.while_loop(cond, body, init)

@@ -30,6 +30,7 @@ from photon_ml_tpu.losses.objective import GlmObjective
 from photon_ml_tpu.opt.config import OptimizerConfig
 from photon_ml_tpu.opt.lbfgs import (
     _project_box,
+    gram_zeros,
     history_zeros,
     resolve_box,
     resolve_history_dtype,
@@ -62,9 +63,10 @@ def _project_orthant(w: jax.Array, xi: jax.Array) -> jax.Array:
 
 class _OwlqnState(NamedTuple):
     """Resumable OWL-QN loop state (see _LbfgsState, also for the shape of
-    ``s_hist`` / ``y_hist``): carries the L1 weight and the init-derived
-    tolerances so chunked execution — ``owlqn_chunk`` every K iterations —
-    follows the one-shot trajectory exactly."""
+    ``s_hist`` / ``y_hist``, and of ``gram``, whose ``v`` here is the
+    pseudo-gradient): carries the L1 weight and the init-derived tolerances
+    so chunked execution — ``owlqn_chunk`` every K iterations — follows the
+    one-shot trajectory exactly."""
 
     w: jax.Array
     f: jax.Array          # smooth f (no L1)
@@ -74,6 +76,7 @@ class _OwlqnState(NamedTuple):
     y_hist: jax.Array
     rho: jax.Array
     count: jax.Array
+    gram: jax.Array       # [2m+1, 2m+1] products of [S; Y; pg] ([0, 0] for rows)
     it: jax.Array
     evals: jax.Array      # int32 objective.value_and_grad calls so far
     reason: jax.Array
@@ -111,15 +114,17 @@ def owlqn_init(
         if config.track_coefficients
         else jnp.zeros((0,), dtype=dtype)
     )
+    s_hist = history_zeros(m, dim, hdtype)
     return _OwlqnState(
         w=w0,
         f=f0,
         g=g0,
         F=F0,
-        s_hist=history_zeros(m, dim, hdtype),
+        s_hist=s_hist,
         y_hist=history_zeros(m, dim, hdtype),
         rho=jnp.zeros((m,), dtype=dtype),
         count=jnp.int32(0),
+        gram=gram_zeros(s_hist, pg0),
         it=jnp.int32(0),
         evals=jnp.int32(1),
         reason=jnp.int32(ConvergenceReason.NOT_CONVERGED.value),
@@ -159,7 +164,7 @@ def owlqn_chunk(
 
     def body(s: _OwlqnState) -> _OwlqnState:
         pg = pseudo_gradient(s.w, s.g, l1)
-        d = two_loop_direction(pg, s.s_hist, s.y_hist, s.rho, s.count)
+        d, _ = two_loop_direction(pg, s.s_hist, s.y_hist, s.rho, s.count, s.gram)
         # align direction with -pg (zero disagreeing coordinates)
         d = jnp.where(d * pg < 0, d, 0.0)
         # orthant to search in: sign(w), or sign(-pg) where w = 0
@@ -233,14 +238,16 @@ def owlqn_chunk(
             evals = evals + clipped.astype(jnp.int32)
             w_new = w_proj
 
+        # the next iteration's pseudo-gradient, for the convergence test and
+        # as the Gram matrix's v
+        pg_new = pseudo_gradient(w_new, g_new, l1)
         s_vec = w_new - s.w
         y_vec = g_new - s.g
-        s_hist, y_hist, rho, count = update_history(
-            s.s_hist, s.y_hist, s.rho, s.count, s_vec, y_vec
+        s_hist, y_hist, rho, count, gram = update_history(
+            s.s_hist, s.y_hist, s.rho, s.count, s.gram, s_vec, y_vec, pg_new
         )
 
         it = s.it + 1
-        pg_new = pseudo_gradient(w_new, g_new, l1)
         g_conv = gradient_converged(jnp.linalg.norm(pg_new), s.abs_g_tol)
         f_conv = ls.ok & function_values_converged(s.F, F_new, s.abs_f_tol)
         no_step = ~ls.ok
@@ -271,6 +278,7 @@ def owlqn_chunk(
             y_hist=y_hist,
             rho=rho,
             count=count,
+            gram=gram,
             it=it,
             evals=evals,
             reason=reason,
@@ -306,6 +314,7 @@ def owlqn_finalize(
         evaluations=state.evals,
         hessian_vecs=jnp.zeros_like(state.it),
         rejected_steps=jnp.zeros_like(state.it),
+        descent_restarts=jnp.zeros_like(state.it),
         reason=reason,
         value_history=state.history,
         w_history=state.w_hist if config.track_coefficients else None,

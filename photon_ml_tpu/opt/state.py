@@ -43,6 +43,10 @@ class SolveResult:
     # the loop carry; 0 from L-BFGS and OWL-QN, which make neither
     hessian_vecs: Optional[jax.Array] = None
     rejected_steps: Optional[jax.Array] = None
+    # int32 L-BFGS iterations whose quasi-Newton direction was not a descent
+    # direction and fell back to -g, counted in the loop carry; 0 from
+    # OWL-QN and TRON. A rise says the direction lost accuracy.
+    descent_restarts: Optional[jax.Array] = None
 
     def converged(self) -> jax.Array:
         return self.reason != ConvergenceReason.NOT_CONVERGED.value

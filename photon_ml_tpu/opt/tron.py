@@ -294,6 +294,7 @@ def tron_finalize(
         evaluations=state.evals,
         hessian_vecs=state.hvs,
         rejected_steps=state.failures,
+        descent_restarts=jnp.zeros_like(state.it),
         reason=reason,
         value_history=state.history,
         w_history=state.w_hist if config.track_coefficients else None,

@@ -41,6 +41,7 @@ import numpy as np
 from photon_ml_tpu.losses.objective import GlmObjective
 from photon_ml_tpu.opt.config import GlmOptimizationConfiguration, OptimizerType
 from photon_ml_tpu.opt.lbfgs import (
+    gram_zeros,
     history_zeros,
     resolve_history_dtype,
     two_loop_direction,
@@ -189,14 +190,13 @@ class StreamPrograms:
             return f_reg, g_reg, jnp.linalg.norm(g_reg)
 
         @jax.jit
-        def direction(g, s_hist, y_hist, rho, count):
+        def direction(g, s_hist, y_hist, rho, count, gram):
             _note_trace("stream_direction")
-            d = two_loop_direction(g, s_hist, y_hist, rho, count)
-            dphi0 = jnp.dot(d, g)
+            d, dphi0 = two_loop_direction(g, s_hist, y_hist, rho, count, gram)
             bad = dphi0 >= 0
             d = jnp.where(bad, -g, d)
             dphi0 = jnp.where(bad, -jnp.dot(g, g), dphi0)
-            return d, dphi0, jnp.linalg.norm(d)
+            return d, dphi0, jnp.linalg.norm(d), bad
 
         @jax.jit
         def step(w, d, t):
@@ -206,11 +206,11 @@ class StreamPrograms:
         # the buffers are donated so the new pair is written into its row in
         # place (the solver loop rebinds both at once)
         @partial(jax.jit, donate_argnums=(0, 1))
-        def hist_update(s_hist, y_hist, rho, count, w_old, w_new, g_old, g_new):
+        def hist_update(s_hist, y_hist, rho, count, gram, w_old, w_new, g_old, g_new):
             _note_trace("stream_history")
             s = (w_new - w_old).astype(s_hist.dtype)
             y = (g_new - g_old).astype(y_hist.dtype)
-            return update_history(s_hist, y_hist, rho, count, s, y)
+            return update_history(s_hist, y_hist, rho, count, gram, s, y, g_new)
 
         @partial(jax.jit, donate_argnums=(2, 3))
         def acc_vg_probe(w, data, f_acc, g_acc):
@@ -344,6 +344,8 @@ def solve_streaming(
     y_hist = history_zeros(m, dim, hdtype)
     rho = jnp.zeros((m,), dtype=w.dtype)
     count = jnp.int32(0)
+    gram = gram_zeros(s_hist, g)
+    restarts = 0
 
     history = [float(f)]
     reason = ConvergenceReason.MAX_ITERATIONS
@@ -352,8 +354,9 @@ def solve_streaming(
 
     it = 0
     while it < cfg.max_iterations and reason is ConvergenceReason.MAX_ITERATIONS:
-        d, dphi0, d_norm = programs.direction(g, s_hist, y_hist, rho, count)
+        d, dphi0, d_norm, bad = programs.direction(g, s_hist, y_hist, rho, count, gram)
         dphi0_f = float(dphi0)
+        restarts += int(bad)
         # Breeze's firstStepSize heuristic, then the quasi-Newton step t=1
         t = 1.0 / max(float(d_norm), 1e-12) if int(count) == 0 else 1.0
         f_host = float(f)
@@ -372,8 +375,8 @@ def solve_streaming(
             break
 
         w_new, f_new, g_new, g_new_norm = accepted
-        s_hist, y_hist, rho, count = programs.hist_update(
-            s_hist, y_hist, rho, count, w, w_new, g, g_new
+        s_hist, y_hist, rho, count, gram = programs.hist_update(
+            s_hist, y_hist, rho, count, gram, w, w_new, g, g_new
         )
         it += 1
         info.iterations = it
@@ -394,6 +397,7 @@ def solve_streaming(
         evaluations=jnp.int32(evaluations),
         hessian_vecs=jnp.int32(0),
         rejected_steps=jnp.int32(0),
+        descent_restarts=jnp.int32(restarts),
         reason=jnp.int32(reason.value),
         value_history=jnp.asarray(value_history),
     )

@@ -258,19 +258,25 @@ def test_variances_and_tracking_reuse_their_programs(clean_slate, watch):
 
 
 @pytest.mark.parametrize(
-    "solver,d,layout",
-    [("lbfgs", 40, "rows"), ("lbfgs", 1100, "tiled"), ("owlqn", 40, "rows"),
-     ("owlqn", 1100, "tiled"), ("tron", 1100, "none")],
+    "solver,d,layout,direction",
+    [("lbfgs", 40, "rows", "recursion"), ("lbfgs", 1100, "tiled", "gram"),
+     ("owlqn", 40, "rows", "recursion"), ("owlqn", 1100, "tiled", "gram"),
+     ("tron", 1100, "none", "none")],
 )
-def test_a_traced_solve_says_how_its_history_lies(solver, d, layout, clean_slate, watch):
-    """``glm/solve`` carries ``history_layout`` beside the solver's counts:
-    for L-BFGS and OWL-QN ``rows`` (``[m, d]``) under 128 coefficients and
-    ``tiled`` (a row of the history to itself) from there up, ``none`` for
-    TRON."""
+def test_a_traced_solve_says_how_its_history_lies(solver, d, layout, direction, clean_slate, watch):
+    """``glm/solve`` carries ``history_layout`` and ``direction`` beside the
+    solver's counts: for L-BFGS and OWL-QN ``rows`` (``[m, d]``) under 128
+    coefficients, whose direction is the two-loop ``recursion`` over its
+    rows, and ``tiled`` (a row of the history to itself) from there up,
+    whose direction comes from the carried ``gram`` matrix; ``none`` for
+    TRON. ``descent_restarts`` is L-BFGS's count of directions that fell
+    back to ``-g``: none on this smooth problem, and 0 from the others."""
     w = watch(solver)
     _fit(_data("ell", d=d), solver)
     (solved,) = [s for s in w.tracer.spans() if s.name == "glm/solve"]
     assert solved.attrs["history_layout"] == layout
+    assert solved.attrs["direction"] == direction
+    assert solved.attrs["descent_restarts"] == 0
     assert solved.attrs["evaluations"] >= solved.attrs["iterations"] + 1
 
 
