@@ -20,7 +20,8 @@ unchanged over vec(B). The maps run over an item-tiled slot layout
 ``proj_indices``: every real (entity, local column) slot sorted by its global
 column and cut into tiles of ``TILE`` slots that all belong to one column.
 An evaluation then reads each block once in a dense pass, moves one scalar a
-slot between the two orders (a gather of 128-lane rows, :func:`_take`), and
+slot between the two orders (a fixed permutation, routed once with the
+layout and run by the routed maps' kernels, ``ops/fused_perm.py``), and
 sums a tile's slots against the latents laid into tile order once per solve;
 the [d, k] gradient takes one row a tile (about ``slots / TILE + d`` rows),
 not one a slot.
@@ -39,7 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, List, Optional
+from typing import Callable, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -56,12 +57,16 @@ from photon_ml_tpu.estimators.random_effect import (
 from photon_ml_tpu.losses.objective import make_glm_objective
 from photon_ml_tpu.losses.pointwise import loss_for_task
 from photon_ml_tpu.models.random_effect import RandomEffectModel
+from photon_ml_tpu.ops import routing
 from photon_ml_tpu.ops.data import LabeledData
+from photon_ml_tpu.ops.fused_perm import MIN_FUSED_SIZE, fused_permute, kernels_run
+from photon_ml_tpu.ops.permute_net import DevicePlan, apply_plan, device_plan
+from photon_ml_tpu.ops.sparse_perm import _build_plan_cached
 from photon_ml_tpu.opt.config import GlmOptimizationConfiguration, OptimizerConfig
 from photon_ml_tpu.opt.solve import solve, solver_kind
 from photon_ml_tpu.opt.state import SolveResult
 from photon_ml_tpu.telemetry import note_jit_trace
-from photon_ml_tpu.telemetry.span import get_tracer, span
+from photon_ml_tpu.telemetry.span import get_tracer, span, upload
 from photon_ml_tpu.types import TaskType
 
 
@@ -91,19 +96,35 @@ class KronTiles:
     A slot is one (entity, local column) of a bucket, numbered flat over the
     buckets' ``[E_b, D_b]`` in bucket order. The real slots (``proj_valid``)
     sorted by their global column and cut into tiles of ``TILE``: every tile
-    belongs to one column, a column's last tile padded with dead positions.
-    Dead positions name the slot one past the last and the entity one past
-    the last, which the maps read as zeros."""
+    belongs to one column, a column's last tile padded with dead positions,
+    which name the entity one past the last (a zero latent).
 
-    slot_of: jax.Array       # [tiles, TILE] flat slot of each position
-    entity_of: jax.Array     # [tiles, TILE] flat entity of each position
-    item_of_tile: jax.Array  # [tiles] global column, ascending
-    pos_of_slot: jax.Array   # [slots] position in the flat [tiles * TILE]; dead: one past
+    The maps move one value a slot between the two orders by a routed
+    permutation of ``to_slots.size`` elements, both orders padded to it
+    (:func:`slot_permutation`): ``to_slots`` takes the flat positions to
+    slot order, ``to_positions`` (its inverse) slot order to the positions.
+    A real slot and its position are paired; the dead slots, the dead
+    positions and the padding are paired dead with dead."""
+
+    entity_of: jax.Array      # [tiles, TILE] flat entity of each position
+    item_of_tile: jax.Array   # [tiles] global column, ascending
+    to_slots: DevicePlan      # positions -> slots
+    to_positions: DevicePlan  # slots -> positions
+    slots: int = struct.field(pytree_node=False)
 
 
-def build_kron_tiles(
+class TileLayout(NamedTuple):
+    """The layout on the host, as :func:`tile_layout` lays it."""
+
+    slot_of: np.ndarray       # [positions] flat slot of each; dead: ``slots``
+    entity_of: np.ndarray     # [positions] flat entity of each; dead: one past
+    item_of_tile: np.ndarray  # [tiles] global column, ascending
+    pos_of_slot: np.ndarray   # [slots] position of each; dead: ``positions``
+
+
+def tile_layout(
     pidxs: List[np.ndarray], pvals: List[np.ndarray], tile: int = TILE
-) -> KronTiles:
+) -> TileLayout:
     """The layout of buckets with host ``proj_indices`` / ``proj_valid``
     (``[E_b, D_b]`` each). A stable sort
     keeps a column's slots in slot order, so the layout, and the order every
@@ -136,30 +157,68 @@ def build_kron_tiles(
     entity_of[pos] = entities
     pos_of_slot = np.full(slot_base, n_pos, np.int32)
     pos_of_slot[slots] = pos
-    return KronTiles(
-        slot_of=jnp.asarray(slot_of.reshape(-1, tile)),
-        entity_of=jnp.asarray(entity_of.reshape(-1, tile)),
-        item_of_tile=jnp.asarray(np.repeat(columns, tiles_of).astype(np.int32)),
-        pos_of_slot=jnp.asarray(pos_of_slot),
+    return TileLayout(
+        slot_of=slot_of, entity_of=entity_of,
+        item_of_tile=np.repeat(columns, tiles_of).astype(np.int32),
+        pos_of_slot=pos_of_slot,
     )
 
 
-def _flat_with_zero(parts: List[jax.Array]) -> jax.Array:
-    """The arrays flattened end to end, and one zero after them: what a dead
-    position of the layout reads."""
-    flat = [p.reshape(-1) for p in parts]
-    return jnp.concatenate(flat + [jnp.zeros((1,), flat[0].dtype)])
+def slot_permutation(layout: TileLayout) -> np.ndarray:
+    """``perm`` with ``perm[s]`` the position slot ``s`` reads (the gather
+    convention of ``routing.build_plan``), over ``S = routing.valid_size``
+    of the larger count (at least one recursion level, which the fused
+    kernels need). A real slot reads its position; every other index of
+    ``[0, S)`` reads a position no real slot names, both in ascending order:
+    the permutation, and so the plan cache's key, is a function of the
+    layout alone."""
+    n_slots, n_pos = layout.pos_of_slot.size, layout.slot_of.size
+    S = routing.valid_size(max(n_slots, n_pos, MIN_FUSED_SIZE))
+    real = np.flatnonzero(layout.pos_of_slot < n_pos)
+    perm = np.empty(S, np.int64)
+    perm[real] = layout.pos_of_slot[real]
+    free_dst = np.ones(S, bool)
+    free_dst[real] = False
+    free_src = np.ones(S, bool)
+    free_src[perm[real]] = False
+    perm[free_dst] = np.flatnonzero(free_src)
+    return perm
 
 
-def _take(flat: jax.Array, idx: jax.Array) -> jax.Array:
-    """``flat[idx]`` as a gather of whole 128-lane rows and a one-hot select
-    of the lane, which adds zeros and so is exact. On a TPU v5e a gather of
-    single elements takes about 7 ns an element at a million of them, and
-    this form 2 - 3 ms for the million."""
-    lanes = 128
-    rows = jnp.pad(flat, (0, -flat.shape[0] % lanes)).reshape(-1, lanes)
-    hit = (idx % lanes)[..., None] == jnp.arange(lanes)
-    return jnp.sum(jnp.where(hit, rows[idx // lanes], 0), axis=-1)
+def build_kron_tiles(
+    pidxs: List[np.ndarray], pvals: List[np.ndarray], tile: int = TILE
+) -> KronTiles:
+    """:func:`tile_layout` and its two routed plans, on the device. The
+    plan is read from the plan cache where it holds one (span
+    ``route/plan``, ``what="kron"``), its inverse made in one pass
+    (``route/place``, ``what="kron"``)."""
+    layout = tile_layout(pidxs, pvals, tile)
+    plan = _build_plan_cached(slot_permutation(layout), None, what="kron")
+    with span("route/place", what="kron", invert=routing.inverse_engine()):
+        inverse = plan.invert()
+    return KronTiles(
+        **upload("kron", lambda: dict(
+            entity_of=jnp.asarray(layout.entity_of.reshape(-1, tile)),
+            item_of_tile=jnp.asarray(layout.item_of_tile),
+            to_slots=device_plan(plan),
+            to_positions=device_plan(inverse),
+        )),
+        slots=int(layout.pos_of_slot.size),
+    )
+
+
+def move_executor(arrays) -> str:
+    """How the maps run their moves: ``"routed"``, the plan's fused
+    kernels, where they run (a TPU, or the tests' interpreter) and no array
+    of ``arrays`` is split over devices; else ``"xla"``, the same plan
+    stage by stage in XLA, which GSPMD can partition and a Pallas call it
+    cannot."""
+    split = any(
+        isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer)
+        and len(a.sharding.device_set) > 1
+        for a in arrays
+    )
+    return "routed" if kernels_run() and not split else "xla"
 
 
 @struct.dataclass
@@ -176,7 +235,9 @@ class KronFeatures:
     latents laid into it, ``[tiles, k, TILE]`` (zero at dead positions):
     make the features with :meth:`build`, once per solve. They are
     elementwise products and sums in float32 (no matmul, so no
-    reduced-precision pass), in an order fixed by the layout.
+    reduced-precision pass), in an order fixed by the layout, and moves of
+    values by the layout's plans, run as ``moves`` says
+    (:func:`move_executor`).
     """
 
     xs: List[jax.Array]        # per bucket [E, S, D] local features
@@ -185,15 +246,19 @@ class KronFeatures:
     vs: jax.Array              # [tiles, k, TILE]
     d_global: int = struct.field(pytree_node=False)
     k: int = struct.field(pytree_node=False)
+    moves: str = struct.field(pytree_node=False)
 
     @classmethod
-    def build(cls, xs, latents, tiles: KronTiles, d_global: int, k: int):
+    def build(cls, xs, latents, tiles: KronTiles, d_global: int, k: int,
+              moves: Optional[str] = None):
         """The maps over ``tiles``, the latents gathered into tile order here
-        (they are constant for a solve: no evaluation gathers them again)."""
+        (they are constant for a solve: no evaluation gathers them again).
+        ``moves`` defaults to :func:`move_executor` of ``xs``."""
         # one zero row after the entities: what a dead position reads
         v = jnp.concatenate(list(latents) + [jnp.zeros((1, k), latents[0].dtype)])
         vs = jnp.transpose(v[tiles.entity_of], (0, 2, 1))
-        return cls(xs=xs, latents=latents, tiles=tiles, vs=vs, d_global=d_global, k=k)
+        return cls(xs=xs, latents=latents, tiles=tiles, vs=vs, d_global=d_global,
+                   k=k, moves=moves or move_executor(xs))
 
     @property
     def num_rows(self) -> int:
@@ -207,7 +272,9 @@ class KronFeatures:
         B = w.reshape(self.d_global, self.k)
         # u[slot] = B[item] . v[entity], a tile at a time: one row of B a tile
         u_tiles = jnp.sum(self.vs * B[self.tiles.item_of_tile][:, :, None], axis=1)
-        u_all = _take(_flat_with_zero([u_tiles]), self.tiles.pos_of_slot)
+        # a dead position holds 0 (its latent is the zero row), and so does
+        # every dead slot, which reads one
+        u_all = self._to_slots(u_tiles.reshape(-1))
         outs, start = [], 0
         for x in self.xs:
             e_n, _, d_n = x.shape
@@ -230,7 +297,9 @@ class KronFeatures:
             cb = c[start : start + e_n * s_n].reshape(e_n, s_n)
             start += e_n * s_n
             parts.append(jnp.sum(cb[:, :, None] * (x * x if squared else x), axis=1))
-        a_tiles = _take(_flat_with_zero(parts), self.tiles.slot_of)
+        # a dead slot holds 0 (x is 0 at a local column that is not real),
+        # and so does every dead position, which reads one
+        a_tiles = self._to_positions(jnp.concatenate([p.reshape(-1) for p in parts]))
         vs = self.vs * self.vs if squared else self.vs
         per_tile = jnp.sum(vs * a_tiles[:, None, :], axis=-1)  # [tiles, k]
         grad = jax.ops.segment_sum(
@@ -238,6 +307,23 @@ class KronFeatures:
             indices_are_sorted=True,
         )
         return grad.reshape(-1)
+
+    def _to_slots(self, u: jax.Array) -> jax.Array:
+        """``[positions]`` values in slot order, ``[slots]``."""
+        return self._permute(self.tiles.to_slots, u)[: self.tiles.slots]
+
+    def _to_positions(self, a: jax.Array) -> jax.Array:
+        """``[slots]`` values in tile order, ``[tiles, TILE]``."""
+        shape = self.tiles.entity_of.shape
+        return self._permute(self.tiles.to_positions, a)[: shape[0] * shape[1]].reshape(shape)
+
+    def _permute(self, plan: DevicePlan, flat: jax.Array) -> jax.Array:
+        """``flat`` padded with zeros to the plan's size and moved by it:
+        exact, a permutation adds nothing."""
+        x = jnp.pad(flat, (0, plan.size - flat.shape[0]))
+        if self.moves == "routed":
+            return fused_permute(plan, x)
+        return apply_plan(plan, x, kernels=False)
 
     def row_norms_sq(self) -> jax.Array:
         outs = []
@@ -350,12 +436,13 @@ def _latent_dataset(
 
 @functools.lru_cache(maxsize=None)
 def _matrix_solve_program(
-    task: TaskType, optimizer_config: OptimizerConfig, use_l1: bool
+    task: TaskType, optimizer_config: OptimizerConfig, use_l1: bool, moves: str
 ) -> Callable[..., SolveResult]:
     """The jitted step (b) of one static key: ``(B0, xs, latents, labels,
     offsets, weights, l2, l1, tiles) -> SolveResult`` over vec(B), the
     per-bucket blocks as lists and ``tiles`` the coordinate's
-    :class:`KronTiles`. Kept for the process in this cache of its own: ``train_glm``'s ``_solve_program`` keeps ONE program (a kept
+    :class:`KronTiles`, its moves run as ``moves`` says
+    (:func:`move_executor`). Kept for the process in this cache of its own: ``train_glm``'s ``_solve_program`` keeps ONE program (a kept
     program keeps its device reservation), and in a GAME fit that one is the
     fixed effect's — a matrix solve routed through it would evict it at
     every update. Everything that varies between calls is an argument, the
@@ -368,7 +455,7 @@ def _matrix_solve_program(
     def mf_matrix_solve(B0, xs, latents, labels, offsets, weights, l2, l1, tiles):
         note_jit_trace("mf_matrix_solve", kind)  # fires only on a (re)trace
         d_global, k = B0.shape
-        feats = KronFeatures.build(xs, latents, tiles, d_global, k)
+        feats = KronFeatures.build(xs, latents, tiles, d_global, k, moves)
         data = LabeledData(
             features=feats,
             labels=jnp.concatenate([a.reshape(-1) for a in labels]),
@@ -528,17 +615,21 @@ class FactoredRandomEffectCoordinate(Coordinate):
     ) -> jax.Array:
         cfg = self.matrix_configuration
         use_l1 = cfg.l1_weight > 0
-        solver = _matrix_solve_program(self.task, cfg.optimizer_config, use_l1)
+        xs = [b.X for b in ds.buckets]
+        latents = list(latent_model.coefficients)
+        moves = move_executor(xs + latents)
+        solver = _matrix_solve_program(self.task, cfg.optimizer_config, use_l1, moves)
         tiles = self._layout(ds)
         # not a ``glm/solve`` span: those are the sparse fixed-effect solves,
         # and what reads them counts two maps over that matrix an evaluation
         with span(
-            "mf/solve_matrix", device_sync=True, alternation=alternation
+            "mf/solve_matrix", device_sync=True, alternation=alternation,
+            moves=moves, move_slots=tiles.to_slots.size,
         ) as solving:
             result = solver(
                 B,
-                [b.X for b in ds.buckets],
-                list(latent_model.coefficients),
+                xs,
+                latents,
                 [b.labels for b in ds.buckets],
                 [b.offsets for b in ds.buckets],
                 [b.weights for b in ds.buckets],
@@ -558,19 +649,20 @@ class FactoredRandomEffectCoordinate(Coordinate):
         return result.w.reshape(B.shape)
 
     def _layout(self, ds: RandomEffectDataset) -> KronTiles:
-        """The matrix solve's item-tiled layout of ``ds``'s buckets, built
-        from their index maps at the first solve and kept: an update's
-        buckets change only in their offsets, never in their slots."""
+        """The matrix solve's item-tiled layout of ``ds``'s buckets and its
+        plans, built from their index maps at the first solve and kept: an
+        update's buckets change only in their offsets, never in their
+        slots."""
         slots = sum(int(np.prod(b.proj_indices.shape)) for b in ds.buckets)
         if self._kron_tiles is None:
             self._kron_tiles = build_kron_tiles(
                 [np.asarray(b.proj_indices) for b in ds.buckets],
                 [np.asarray(b.proj_valid) for b in ds.buckets],
             )
-        elif self._kron_tiles.pos_of_slot.shape[0] != slots:
+        elif self._kron_tiles.slots != slots:
             raise ValueError(
                 f"the buckets hold {slots} slots and the kept layout "
-                f"{self._kron_tiles.pos_of_slot.shape[0]}"
+                f"{self._kron_tiles.slots}"
             )
         return self._kron_tiles
 

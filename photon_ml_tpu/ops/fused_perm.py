@@ -501,12 +501,27 @@ def fused_execute(
     (one rounding at network entry; permutes are exact; reductions
     accumulate f32) — ~2x less HBM traffic through the network stages.
     """
+    return _execute(dplan, None, pro, epi, interpret, payload_dtype)
+
+
+def fused_permute(
+    dplan: DevicePlan, x: jax.Array, interpret: Optional[bool] = None
+) -> jax.Array:
+    """``x[perm]`` of a routed plan, ``[S]`` to ``[S]``, in the fused
+    kernels: ``x`` goes straight into the first descend (no prologue) and
+    the last ascend's rows come out as they are (no epilogue). A move of
+    values by a fixed permutation, exact in any dtype."""
+    return _execute(dplan, x, None, None, interpret, x.dtype).reshape(-1)
+
+
+def _execute(dplan: DevicePlan, v, pro, epi, interpret, payload_dtype):
+    """The plan's kernels in order: ``v`` the network's input, or None
+    where the first descend's prologue ``pro`` builds it."""
     if interpret is None:
         interpret = _INTERPRET
     parsed = parse_plan(dplan)
     if not parsed.descents:
         raise ValueError("plan too small for fused execution (no recursion)")
-    v = None
     for j, (ai, B, R) in enumerate(parsed.descents):
         v = _descend_call(
             v, dplan.idx[ai], B, R, pro if j == 0 else None, interpret,
@@ -547,6 +562,12 @@ def unfused_execute(dplan: DevicePlan, pro, epi, payload_dtype=jnp.float32) -> j
 # --------------------------------------------------------------------------
 # The feature-matrix engine built on fused execution.
 # --------------------------------------------------------------------------
+
+
+def kernels_run() -> bool:
+    """Pallas kernels on a TPU (and under the tests' interpreter hook);
+    plain XLA on any other backend."""
+    return _INTERPRET or pallas_available()
 
 
 def _next_pow2(x: int) -> int:
@@ -596,9 +617,7 @@ class FusedBenesFeatures:
         return self.plan.size
 
     def _fused_ok(self) -> bool:
-        """Pallas kernels on a TPU (and under the tests' interpreter hook);
-        plain XLA on any other backend."""
-        return _INTERPRET or pallas_available()
+        return kernels_run()
 
     def _run(self, dplan, pro, epi) -> jax.Array:
         pdt = jnp.dtype(self.payload_dtype)

@@ -153,11 +153,13 @@ def _use_pallas(m: int) -> bool:
     return pallas_available() and m >= 32
 
 
-def apply_plan(dplan: DevicePlan, x: jax.Array) -> jax.Array:
+def apply_plan(dplan: DevicePlan, x: jax.Array, kernels: bool = True) -> jax.Array:
     """Apply the permutation plan to ``x`` (length must equal plan size).
 
     Returns the permuted array of the same length. Safe under jit/vmap-free
-    contexts; all stage shapes are static.
+    contexts; all stage shapes are static. ``kernels=False`` keeps every
+    stage in XLA on a TPU too: for operands split over devices, which GSPMD
+    cannot hand to a Pallas call.
     """
     assert x.shape[-1] == dplan.size, (x.shape, dplan.size)
     v = x.reshape(-1, LANES)
@@ -166,7 +168,7 @@ def apply_plan(dplan: DevicePlan, x: jax.Array) -> jax.Array:
         if kind[0] == "lane":
             idx = dplan.idx[ai]
             ai += 1
-            if _use_pallas(v.shape[0]):
+            if kernels and _use_pallas(v.shape[0]):
                 v = _lane_shuffle_pallas(v, idx)
             else:
                 v = _lane_shuffle_xla(v, idx)
@@ -176,7 +178,7 @@ def apply_plan(dplan: DevicePlan, x: jax.Array) -> jax.Array:
             rows = kind[1]
             if rows == 1:
                 continue  # single-row groups: identity movement
-            if _use_pallas(v.shape[0]):
+            if kernels and _use_pallas(v.shape[0]):
                 v = _sublane_shuffle_pallas(v, idx, rows)
             else:
                 v = _sublane_shuffle_xla(v, idx, rows)

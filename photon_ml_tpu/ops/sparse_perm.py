@@ -1025,12 +1025,13 @@ def from_ell(ell, plan_cache: Optional[str] = None) -> BenesSparseFeatures:
     )
 
 
-def _build_plan_cached(perm: np.ndarray, cache_dir: Optional[str]):
+def _build_plan_cached(perm: np.ndarray, cache_dir: Optional[str], **attrs):
     """The routed plan of ``perm``, read back from the plan cache where it
     holds one, else routed (and written there). Span ``route/plan``:
     ``cached`` says which, ``bytes`` is the plan file's size (0 where the
-    cache is off)."""
-    with span("route/plan", slots=int(perm.shape[0])) as planning:
+    cache is off); ``attrs`` (the factored coordinate's ``what``) go on it
+    as they are."""
+    with span("route/plan", slots=int(perm.shape[0]), **attrs) as planning:
         plan, cached, path = _plan_of(perm, cache_dir)
         planning.set_attrs(
             cached=cached, bytes=path.stat().st_size if path is not None else 0

@@ -369,10 +369,10 @@ def current_span():
 def upload(what: str, put):
     """``put()``, which hands host arrays to a device and returns what it
     made there, inside a span ``data/upload`` (``what``: ``features``,
-    ``plan``, ``re_bucket``, ``rows``, ``tile``) while the tracer is on. The
-    span then waits for those arrays with ``jax.block_until_ready``, not
-    with the span barrier: a host-to-device copy does not follow the
-    compute stream the barrier orders. Its ``bytes`` are what
+    ``plan``, ``kron``, ``re_bucket``, ``rows``, ``tile``) while the tracer
+    is on. The span then waits for those arrays with
+    ``jax.block_until_ready``, not with the span barrier: a host-to-device
+    copy does not follow the compute stream the barrier orders. Its ``bytes`` are what
     landed on the devices, every replica counted. With the tracer off this
     is ``put()``, and nothing waits that did not wait before."""
     if not _TRACER.enabled:

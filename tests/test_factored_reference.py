@@ -331,6 +331,11 @@ def test_mf_spans_nest_under_the_coordinate_and_carry_the_solvers_counts(fitted)
         if s.name == "mf/solve_matrix":
             assert s.attrs["evaluations"] >= s.attrs["iterations"] >= 1
             assert s.attrs["coefficients"] == n_items * K
+            # the CPU runs the layout's moves through XLA's stages
+            assert s.attrs["moves"] == "xla" and s.attrs["move_slots"] >= 2 ** 14
+    # the layout's plan is routed once for the three fits (the cache is off)
+    kron = [s for s in spans if s.name == "route/plan" and s.attrs.get("what") == "kron"]
+    assert len(kron) == 1 and kron[0].attrs["cached"] is False
     # the matrix solve is no ``glm/solve``: those are the fixed effect's, two a fit
     assert len([s for s in spans if s.name == "glm/solve"]) == FITS * OUTER
     # a latent solve's rounds wait under it, and its compiles parent to the mf spans

@@ -24,11 +24,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from photon_ml_tpu.algorithm.factored_random_effect import KronFeatures, KronTiles
 from photon_ml_tpu.estimators import random_effect
 from photon_ml_tpu.losses.objective import make_glm_objective
 from photon_ml_tpu.losses.pointwise import LogisticLoss, SquaredLoss
 from photon_ml_tpu.normalization import NormalizationContext
-from photon_ml_tpu.ops import fused_perm, permute_net, sparse_perm
+from photon_ml_tpu.ops import fused_perm, permute_net, routing, sparse_perm
 from photon_ml_tpu.ops.data import LabeledData
 from photon_ml_tpu.ops.features import DenseFeatures
 from photon_ml_tpu.ops.pallas_kernels import fused_value_grad_single
@@ -170,6 +171,51 @@ def test_linear_maps_compile(v5e, on_tpu, engine, plan, op):
             if k[0] == "lane" or (k[0] == "sublane" and k[1] > 1)
         )
         assert kernels == len(blocks) * stages
+
+
+@pytest.fixture(scope="module")
+def kron_plan():
+    """A routed plan at the factored coordinate's 2^21 slots (cell 4's
+    1,064,960 slots and 1,032,704 tile positions): two recursion levels.
+    Any permutation of that size gives the shapes."""
+    perm = np.random.default_rng(0).permutation(2 ** 21)
+    return permute_net.device_plan(routing.build_plan(perm))
+
+
+def test_a_plain_move_compiles_at_the_factored_layout_s_size(v5e, kron_plan):
+    """``fused_permute``: the plan's 2m + 1 kernels and nothing around them,
+    the payload straight into the first descend."""
+    x = jnp.zeros(kron_plan.size, jnp.float32)
+    assert len(fused_perm.parse_plan(kron_plan).descents) == 2
+    assert compile_for_tpu(fused_perm.fused_permute, v5e, kron_plan, x) == 5
+
+
+def test_the_kronecker_maps_compile_with_routed_moves_at_the_cell_size(v5e, kron_plan):
+    """Cell 4's matrix-solve evaluation (one bucket of [16384, 100, 65], 32
+    latent factors over 4,096 items, 8,068 tiles) with its two moves routed:
+    a plan's five kernels each way, no gather of 128-lane rows."""
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    tiles = KronTiles(
+        entity_of=i32((8068, 128)), item_of_tile=i32((8068,)),
+        to_slots=kron_plan, to_positions=kron_plan, slots=16384 * 65,
+    )
+    xs, latents = [f32((16384, 100, 65))], [f32((16384, 32))]
+
+    def evaluation(xs, latents, tiles, w, c):
+        feats = KronFeatures.build(xs, latents, tiles, 4096, 32, moves="routed")
+        return feats.matvec(w), feats.rmatvec(c)
+
+    lowered = jax.jit(evaluation).lower(
+        *jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            (xs, latents, tiles, f32((4096 * 32,)), f32((16384 * 100,))),
+        )
+    )
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 10
+    assert "1064960x128" not in text and "1032704x128" not in text
+    lowered.compile()
 
 
 def test_lbfgs_solve_over_fused_features_compiles(v5e, on_tpu):
